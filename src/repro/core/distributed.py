@@ -94,6 +94,15 @@ def init_opt_state(run: RunConfig, params) -> dict:
 # ---------------------------------------------------------------------------
 # gradient computation with optional micro-batch accumulation
 # ---------------------------------------------------------------------------
+def split_interleaved(x: jax.Array, n: int) -> jax.Array:
+    """(B, …) → (n, B/n, …) where part j holds samples j, j+n, j+2n, ….
+    The batch axis's data sharding stays on the per-part axis, so a scan
+    over the parts runs on an unsharded leading axis and every part spans
+    every data shard (a contiguous split would put the sharding on the
+    scanned axis, which JAX rejects under explicit mesh axes)."""
+    return jnp.swapaxes(x.reshape((x.shape[0] // n, n) + x.shape[1:]), 0, 1)
+
+
 def grad_with_accum(loss_fn: Callable, params, batch, num_microbatches: int,
                     sample_weights=None):
     """value_and_grad with gradient accumulation over micro-batches.
@@ -108,12 +117,10 @@ def grad_with_accum(loss_fn: Callable, params, batch, num_microbatches: int,
             total_loss, has_aux=True)(params, batch, sample_weights)
         return loss, metrics, grads
 
-    mb = jax.tree.map(
-        lambda x: x.reshape((num_microbatches,
-                             x.shape[0] // num_microbatches) + x.shape[1:]),
-        batch)
+    mb = jax.tree.map(lambda x: split_interleaved(x, num_microbatches),
+                      batch)
     wb = (None if sample_weights is None else
-          sample_weights.reshape(num_microbatches, -1))
+          split_interleaved(sample_weights, num_microbatches))
 
     def acc_body(carry, inp):
         g_acc, l_acc = carry
@@ -157,7 +164,8 @@ def make_softsync_step(run: RunConfig, loss_fn: Callable,
                        engine: str = "sequential"):
     """Round-based n-softsync (DESIGN.md §2).  One call = one round = n
     update events.  The global batch is split into n logical learner groups
-    along the batch axis.
+    along the batch axis, interleaved (group j holds samples j, j+n, …; see
+    :func:`split_interleaved`).
     """
     n = max(1, run.n_softsync)
     if run.protocol == "async":
@@ -170,8 +178,7 @@ def make_softsync_step(run: RunConfig, loss_fn: Callable,
     spec = optim.spec_from_run(run)
 
     def step(params, opt, batch):
-        grouped = jax.tree.map(
-            lambda x: x.reshape((n, x.shape[0] // n) + x.shape[1:]), batch)
+        grouped = jax.tree.map(lambda x: split_interleaved(x, n), batch)
         theta0 = params      # round-start weights: all groups' grads use θ(i)
 
         def event(carry, inp):
@@ -210,7 +217,7 @@ def _make_fused_softsync_step(run: RunConfig, loss_fn: Callable, n: int):
 
     def step(params, opt, batch):
         B = jax.tree.leaves(batch)[0].shape[0]
-        per_sample_w = jnp.repeat(group_w, B // n)           # (B,)
+        per_sample_w = jnp.tile(group_w, B // n)      # sample s: group s % n
         loss, metrics, grads = grad_with_accum(
             loss_fn, params, batch, run.num_microbatches,
             sample_weights=per_sample_w)

@@ -34,6 +34,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import optim
 from repro.config import RunConfig
@@ -42,7 +43,9 @@ from repro.core.protocols import init_ps_state
 from repro.core.simulator import SimResult
 from repro.core.topology import Topology
 from repro.core.trace import ArrivalTrace, PlacementPlan, placement_plan
+from repro.launch import mesh as mesh_lib
 from repro.optim import flatten
+from repro.optim.spec import quantize
 
 # cross-shard pull assembly for the SPMD replay (DESIGN.md §13): one fused
 # all_gather over the "ps" axis, or the equivalent S−1 neighbor-ppermute
@@ -59,6 +62,14 @@ def _unflatten_jit(layout: flatten.TreeLayout) -> Callable:
 def _unstack_tree(tree, c: int):
     """Tree with a leading (c,) axis → list of c pytrees (c is static)."""
     return [jax.tree.map(lambda x: x[i], tree) for i in range(c)]
+
+
+def _rows(ring: jax.Array, idx: jax.Array) -> jax.Array:
+    """``ring[idx]`` for a static-length index vector, as one dynamic slice
+    per row: a TPU compiles a vector-index row gather in time that grows
+    with the row width (minutes at D = 2^26), a dynamic slice in constant
+    time.  Pure data movement, so bitwise the gather."""
+    return jnp.stack([ring[idx[i]] for i in range(idx.shape[0])])
 
 
 @functools.lru_cache(maxsize=32)
@@ -129,9 +140,9 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
       itself is elementwise, so one fused event over the (K, S·Dp) buffer
       computes the same values as the stacked per-shard applies.  Bitwise
       it matches the flat ``apply_event_flat`` reference — the *stock
-      sharded* body phrases the combine einsum on (S, c, Dp) operands,
-      which XLA lowers with different rounding (~1 ulp/event), so sharded
-      fused vs stock agree to fp32 accumulation tolerance only.
+      sharded* body runs the combine on (S, c, Dp) operands, which XLA
+      may fuse with different rounding (~1 ulp/event), so sharded fused
+      vs stock agree to fp32 accumulation tolerance only.
     * ``pallas`` — the ``kernels/replay_ring`` megakernel: one pallas_call
       per event with scalar-prefetched ring rows and in-place aliased
       writes (interpret mode off-TPU).
@@ -201,11 +212,13 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
             assembly; the flat layout is the shard rows concatenated, so
             this is bitwise the stock per-shard gather."""
             if shards == 1:
-                return ring[x["ts"]][..., :D].astype(jnp.float32)
-            view = ring[:, :shards * Dp].reshape(K, shards, Dp)
-            parts = jax.vmap(lambda r, t: r[t],
-                             in_axes=(1, 1), out_axes=1)(view, x["ts"])
-            return parts.reshape(c, shards * Dp)[:, :D].astype(jnp.float32)
+                return _rows(ring, x["ts"])[..., :D].astype(jnp.float32)
+            # slot i, shard j: row ts[i, j], columns [j·Dp, (j+1)·Dp)
+            parts = [jnp.concatenate([
+                jax.lax.dynamic_slice(ring, (x["ts"][i, j], j * Dp),
+                                      (1, Dp))[0]
+                for j in range(shards)]) for i in range(c)]
+            return jnp.stack(parts)[:, :D].astype(jnp.float32)
 
         if whatif:
             def event(aux, carry, x):
@@ -380,7 +393,6 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     Dp = -(-D // S)
     Wl = _spmd_local_width(D, S, ring_impl)
     from repro.kernels import replay_ring       # lazy: import cycle
-    from repro.launch import mesh as mesh_lib
     from repro.launch import sharding as sharding_lib
 
     if assembly not in SPMD_ASSEMBLIES:
@@ -401,7 +413,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
         """(c, D) fp32 pulled weights, assembled from every shard's local
         gather (pallas pad stripped per shard) — the same moveaxis/reshape
         assembly as the single-device fused ``slot_weights_flat``."""
-        mine = rl[x["ts"][:, 0]][:, :Dp]              # (c, Dp) local rows
+        mine = _rows(rl, x["ts"][:, 0])[:, :Dp]       # (c, Dp) local rows
         parts = assemble(mine)                        # (S, c, Dp)
         full = jnp.moveaxis(parts, 0, 1).reshape(c, S * Dp)
         return full[:, :D].astype(jnp.float32)
@@ -435,21 +447,26 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
         vp = flatten.pad_flat(vec, S * Dp)
         return jax.lax.dynamic_slice_in_dim(vp, si * Dp, Dp, vp.ndim - 1)
 
+    # this device's carry block is (1, K, *lanes) / (1, *lanes): lanes is
+    # (Wl,) or, for the Pallas body, the kernel's own (Wl/128, 128) tiling,
+    # so the kernel reads and writes the loop carry in place.  The flat
+    # (K, Wl) views below fold into the kernel's reshapes.
     def unpack_carry(carry):
         ring, s, res = carry
-        return (ring[0],
-                None if s is None else s[0],
-                None if res is None else res[0])
+        return (ring[0].reshape(K, Wl),
+                None if s is None else s[0].reshape(Wl),
+                None if res is None else res[0].reshape(Wl))
 
-    def pack_carry(rl, sl, resl):
-        return (rl[None],
-                None if sl is None else sl[None],
-                None if resl is None else resl[None])
+    def pack_carry(carry, rl, sl, resl):
+        ring, s, res = carry
+        return (rl.reshape(ring.shape),
+                None if sl is None else sl.reshape(s.shape),
+                None if resl is None else resl.reshape(res.shape))
 
     if whatif:
         def event(aux, carry, x):
             rl, sl, resl = unpack_carry(carry)
-            a_l, ws_l = aux[0][0], aux[1][0]
+            a_l, ws_l = aux[0][0].reshape(Wl), aux[1][0].reshape(Wl)
             ts_col = x["ts"][:, 0]
             if ring_impl == "pallas" and K >= 2:
                 idx = jnp.concatenate(
@@ -461,7 +478,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                 rl, sl, resl = optim.apply_event_ring_whatif(
                     spec, rl, sl, resl, a_l, ws_l, ts_col, coef_of(x),
                     x["lrs"], x["prev"], x["slot"])
-            return pack_carry(rl, sl, resl), None
+            return pack_carry(carry, rl, sl, resl), None
     else:
         def event(carry, x):
             rl, sl, resl = unpack_carry(carry)
@@ -488,7 +505,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                 rl, sl, resl = optim.apply_event_ring(
                     spec, rl, sl, resl, gp, cvec, lvec, x["prev"],
                     x["slot"], mode)
-            return pack_carry(rl, sl, resl), None
+            return pack_carry(carry, rl, sl, resl), None
 
     carry_specs = sharding_lib.spmd_carry_specs()
     xs_specs = sharding_lib.spmd_xs_specs(xs_keys)
@@ -506,6 +523,22 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                                      in_specs=(carry_specs, xs_specs),
                                      out_specs=carry_specs)
     return jax.jit(smapped, donate_argnums=0)
+
+
+def _whatif_aux(coeffs: Callable, base: jax.Array, width: int, real: int,
+                D: int):
+    """The what-if kernel's (a, w*) operands as (rows, width) fp32 rows:
+    row r, column j holds flat position ``base[r] + j``.  Padding (column
+    ≥ ``real`` or position ≥ D) gets a = 0, so its gradients are zero and
+    the pad stays inert.  ``base`` is a traced argument, so every layout
+    evaluates ``coeffs`` at run time with the same code — never folded to
+    a compile-time constant, which rounds differently."""
+    col = jnp.arange(width, dtype=jnp.int32)[None, :]
+    pos = base[:, None] + col
+    valid = (col < real) & (pos < D)
+    a, wstar = coeffs(pos)
+    return (jnp.where(valid, a, 0.0).astype(jnp.float32),
+            jnp.where(valid, wstar, 0.0).astype(jnp.float32))
 
 
 def _materialize_batches(trace: ArrivalTrace, batch_fn: Callable):
@@ -623,9 +656,11 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     path (Pallas on TPU, its bitwise jnp twin elsewhere) with a donated
     carry; ``stock`` forces the pre-megakernel chain.
 
-    ``flat_grad = ("quadratic", a, w*)`` (flat (D,) fp32 arrays in the
-    ``optim.flatten`` layout) opts into the **what-if replay**: gradients
-    are computed in-kernel as ``a ⊙ (w_pulled − w*)`` and no data is staged
+    ``flat_grad = ("quadratic", coeffs)`` opts into the **what-if
+    replay**: ``coeffs(pos) -> (a, w*)`` gives the fp32 curvature and
+    target at int32 flat positions of the ``optim.flatten`` layout,
+    gradients are computed in-kernel as ``a ⊙ (w_pulled − w*)``, and no
+    data is staged
     — peak memory O(K·D_ring + D), which is what makes trace-driven studies
     at ``configs/`` big-model D feasible.  Requires a kernel-supported
     optimizer, combine mode, the trivial topology and a non-stock impl
@@ -681,7 +716,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     K = trace.max_staleness + 1
     topo = trace.topology
     S, gs = topo.shards, trace.group_size
-    spec, opt_state = init_ps_state(run, init_params)
+    spec = optim.spec_from_run(run)
     layout = flatten.layout_of(init_params)
     if S > 1 and not spec.kernel_supported:
         raise ValueError(
@@ -695,7 +730,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
 
     place = placement if placement is not None else run.placement
     if place == "spmd":
-        return _replay_spmd(trace, run, spec=spec, opt_state=opt_state,
+        return _replay_spmd(trace, run, spec=spec,
                             layout=layout, grad_fn=grad_fn,
                             init_params=init_params, batch_fn=batch_fn,
                             batches=batches, eval_fn=eval_fn,
@@ -704,6 +739,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     if place != "single":
         raise ValueError(f"unknown placement {place!r}: expected "
                          f"'single' or 'spmd'")
+    _, opt_state = init_ps_state(run, init_params)
 
     impl = optim.resolve_ring_impl(run.ring_impl, spec)
     ef = run.ring_dtype == "bf16"
@@ -714,7 +750,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
         kind = flat_grad[0]
         if kind != "quadratic":
             raise ValueError(f"unknown flat_grad kind {kind!r}; expected "
-                             f"('quadratic', a, wstar)")
+                             f"('quadratic', coeffs)")
     elif grad_fn is None:
         raise ValueError("grad_fn is required outside the what-if replay")
     elif (batch_fn is None) == (batches is None):
@@ -746,7 +782,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
             width = replay_ring.padded_width(width)
         rdt = jnp.bfloat16 if ef else jnp.float32
         flat_pad = flatten.pad_flat(flat0, width)
-        q0 = flat_pad.astype(rdt)
+        q0 = quantize(flat_pad, rdt)
         ring = jnp.tile(q0[None], (K, 1))
         res0 = (flat_pad - q0.astype(jnp.float32)) if ef else None
         s0 = None
@@ -763,8 +799,9 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
 
         aux = None
         if whatif:
-            aux = (flatten.pad_flat(flat_grad[1].astype(jnp.float32), width),
-                   flatten.pad_flat(flat_grad[2].astype(jnp.float32), width))
+            aux = jax.jit(lambda base: tuple(
+                v[0] for v in _whatif_aux(flat_grad[1], base, width, width,
+                                          D)))(jnp.zeros((1,), jnp.int32))
     elif S > 1:
         # per-shard rings: (S, K, Dp), row r of shard s = snapshot ts=r of
         # the shard's slice (the σ_s ≤ σ invariant keeps K a valid bound)
@@ -879,8 +916,8 @@ def _serve_eval(snaps, layout, D: int, serving, serve_batches,
     return ServingResult(trace=serving, request_metric=metric)
 
 
-def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, opt_state,
-                 layout, grad_fn, init_params, batch_fn, batches, eval_fn,
+def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
+                 grad_fn, init_params, batch_fn, batches, eval_fn,
                  eval_every, flat_grad, assembly) -> SimResult:
     """The ``placement="spmd"`` arm of :func:`replay`: resolve the trace's
     :func:`placement_plan` against the visible devices, build the sharded
@@ -912,7 +949,7 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, opt_state,
         kind = flat_grad[0]
         if kind != "quadratic":
             raise ValueError(f"unknown flat_grad kind {kind!r}; expected "
-                             f"('quadratic', a, wstar)")
+                             f"('quadratic', coeffs)")
     elif grad_fn is None:
         raise ValueError("grad_fn is required outside the what-if replay")
     elif (batch_fn is None) == (batches is None):
@@ -935,37 +972,56 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, opt_state,
                                  ring_impl=impl, ring_dtype=run.ring_dtype,
                                  whatif=whatif, assembly=assembly)
 
-    flat0 = flatten.tree_to_flat(init_params)
-    D = flat0.shape[0]
+    # the carry (and the what-if auxiliaries) are built by one program whose
+    # outputs are laid out per "ps" device, so each device computes only
+    # its own (K, Wl) ring slice, state and residue rows.  Inputs already
+    # laid out over those devices (e.g. QuadraticProblem(shards=S)) are
+    # packed in place; nothing of model size ever sits whole on one device.
+    D = layout.total
     Dp = topo.padded_width(D)
     Wl = _spmd_local_width(D, S, impl)
     rdt = jnp.bfloat16 if ef else jnp.float32
-    packed = flatten.pad_flat(flatten.shard_pack(flat0, S, Dp), Wl)  # (S, Wl)
-    q0 = packed.astype(rdt)
-    ring = jnp.tile(q0[:, None, :], (1, K, 1))                   # (S, K, Wl)
-    res0 = (packed - q0.astype(jnp.float32)) if ef else None
-    s0 = None
-    if spec.state_keys:
-        s0 = flatten.pad_flat(
-            flatten.shard_pack(
-                flatten.tree_to_flat(opt_state[spec.state_keys[0]]), S, Dp),
-            Wl)
-    carry = (ring, s0, res0)
+    mesh = mesh_lib.make_sim_mesh(plan.shards, plan.learners)
+    per_ps = NamedSharding(mesh, PartitionSpec("ps"))
 
-    def params_of(carry, done):
-        row = carry[0][:, done % K, :].astype(jnp.float32)       # (S, Wl)
+    # per-device lanes: the Pallas body keeps the kernel's (Wl/128, 128)
+    # tiling so its loop carry needs no relayout (see _make_spmd_scan_fn)
+    lanes = (Wl // 128, 128) if impl == "pallas" else (Wl,)
+
+    def pack(vec):                                           # (S, *lanes)
+        return flatten.pad_flat(flatten.shard_pack(vec, S, Dp),
+                                Wl).reshape((S,) + lanes)
+
+    @functools.partial(jax.jit, out_shardings=per_ps)
+    def build(init_params, base):
+        packed = pack(flatten.tree_to_flat(init_params))
+        q0 = quantize(packed, rdt)
+        ring = jnp.broadcast_to(q0[:, None], (S, K) + lanes)
+        res0 = (packed - q0.astype(jnp.float32)) if ef else None
+        s0 = None
+        if spec.state_keys:
+            state = optim.init_state(spec, init_params)[spec.state_keys[0]]
+            s0 = pack(flatten.tree_to_flat(state))
+        aux = None
+        if whatif:        # shard s, local column j: flat position s·Dp + j
+            aux = tuple(v.reshape((S,) + lanes)
+                        for v in _whatif_aux(flat_grad[1], base, Wl, Dp, D))
+        return (ring, s0, res0), aux
+
+    carry, aux = build(init_params, jnp.arange(S, dtype=jnp.int32) * Dp)
+
+    @functools.partial(jax.jit, out_shardings=per_ps)
+    def flat_params(carry, row_idx):
+        """The weights of ring row ``row_idx`` as the zero-padded (S·Dp,)
+        flat vector, left laid out per "ps" device: an eval never gathers
+        the model onto one device."""
+        row = carry[0][:, row_idx].astype(jnp.float32)
         if ef:
             row = row + carry[2]
-        return _unflatten_jit(layout)(flatten.shard_unpack(row[:, :Dp], D))
+        return row.reshape(S, Wl)[:, :Dp].reshape(-1)
 
-    aux = None
-    if whatif:
-        aux = (flatten.pad_flat(
-                   flatten.shard_pack(flat_grad[1].astype(jnp.float32),
-                                      S, Dp), Wl),
-               flatten.pad_flat(
-                   flatten.shard_pack(flat_grad[2].astype(jnp.float32),
-                                      S, Dp), Wl))
+    def params_of(carry, done):
+        return _unflatten_jit(layout)(flat_params(carry, done % K))
 
     def advance(carry, seg):
         return (scan_fn(carry, seg, aux) if whatif
@@ -1117,7 +1173,7 @@ def replay_batch(traces: Sequence[ArrivalTrace],
         width = replay_ring.padded_width(D) if impl == "pallas" else D
         rdt = jnp.bfloat16 if ef else jnp.float32
         flat_pad = flatten.pad_flat(flat0, width)
-        q0 = flat_pad.astype(rdt)
+        q0 = quantize(flat_pad, rdt)
         ring = jnp.tile(q0[None, None], (B, K, 1))
         res0 = (jnp.tile((flat_pad - q0.astype(jnp.float32))[None], (B, 1))
                 if ef else None)

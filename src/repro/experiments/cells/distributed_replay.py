@@ -12,9 +12,10 @@ having cores for the emulated devices to run on (``cpu_count`` rides in
 the results; a 1-core container timeshares all S devices).  A
 ``placement="single"`` row at S=4 anchors the comparison.
 
-Runs its measurement in a **subprocess** so the 8-device XLA flag applies
-before jax initializes (the dry-run trick, ``launch/dryrun.py``) — the
-parent process may already hold a 1-device jax.  The module is its own
+Runs its measurement in a **subprocess** pinned to the CPU platform, so
+the 8-device XLA flag applies before jax initializes (the dry-run trick,
+``launch/dryrun.py``) — the parent process may already hold a 1-device jax,
+or the chip.  The module is its own
 subprocess entry point (``python -m repro.experiments.cells.\
 distributed_replay --inner <json>``) so the child needs only ``src`` on
 PYTHONPATH.
@@ -95,6 +96,7 @@ def _inner(payload: dict) -> dict:
     ring_bytes = {f"spmd_s{s}": K * (-(-payload["d"] // s)) * 4
                   for s in payload["shards"]}
     return {
+        "platform": jax.default_backend(),
         "devices": jax.device_count(),
         "cpu_count": os.cpu_count(),
         "d": payload["d"],
@@ -118,6 +120,9 @@ def measure(updates: int = 48, d: int = 2_000_000, repeats: int = 3,
     payload = {"devices": devices, "updates": updates, "d": d,
                "repeats": repeats, "shards": list(shards)}
     env = dict(os.environ)
+    # the emulated devices are CPU devices: pin the child to the CPU so it
+    # never contends for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith("--xla_force_host_platform_device_count")]
     env["XLA_FLAGS"] = " ".join(

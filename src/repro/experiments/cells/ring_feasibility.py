@@ -78,13 +78,15 @@ def _try_replay(label: str, d: int, impl: str, problem: str, pargs: tuple,
     code = _CHILD.format(cap=cap, lam=_LAM, impl=impl, problem=problem,
                          pargs=pargs, steps=_STEPS, ring_dtype=ring_dtype)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"      # RLIMIT_AS caps host memory only
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(repo_root(), "src"),
                     env.get("PYTHONPATH", "")) if p)
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, env=env, timeout=600)
     ok = proc.returncode == 0 and "FEASIBLE" in proc.stdout
-    return {"label": label, "d": d, "impl": impl, "problem": problem,
+    return {"label": label, "platform": "cpu", "d": d, "impl": impl,
+            "problem": problem,
             "ring_dtype": ring_dtype, "cap_bytes": cap, "feasible": ok,
             "detail": (proc.stdout.strip() if ok else
                        (proc.stderr.strip().splitlines() or ["killed"])[-1]

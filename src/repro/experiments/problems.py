@@ -30,6 +30,7 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.data.synthetic import TeacherClassification
 
@@ -146,50 +147,63 @@ class QuadraticProblem:
     minibatch data: peak memory is the ring carry alone, which is what
     makes staleness what-if studies feasible at ``configs/`` big-model D
     (pass ``arch="qwen2_1_5b"`` etc. to size D to a registered
-    architecture's parameter count).  ``a`` and ``w*`` are generated
-    on-device from ``iota`` formulas — no (D,) host materialization, and
-    deterministic in (d, seed).  The ``grad_fn``/``batch_fn_for`` twins
-    keep the problem valid on every non-what-if path (stock impl, legacy
-    oracle, sharded traces): the batch is a 1-element dummy the gradient
-    ignores.
+    architecture's parameter count).  ``a`` and ``w*`` are closed-form
+    functions of the flat position (:meth:`coeffs`), evaluated on the
+    device inside whichever program needs them — the problem stores no
+    (D,) array, and each "ps" device of an SPMD replay computes only its
+    own slice.  The ``grad_fn``/``batch_fn_for`` twins keep the problem
+    valid on every non-what-if path (stock impl, legacy oracle, sharded
+    traces): the batch is a 1-element dummy the gradient ignores.
+
+    ``shards`` = S > 1 lays ``init`` out over the "ps" devices of
+    ``placement="spmd"`` replay with S shards, one contiguous block of d/S
+    per device, so at model size no device holds the whole (d,) vector
+    (d must divide evenly).
     """
 
-    def __init__(self, d: int = 4096, arch: str = None, seed: int = 0):
+    def __init__(self, d: int = 4096, arch: str = None, seed: int = 0,
+                 shards: int = 1):
         if arch is not None:
             from repro.configs import get_config
             d = int(get_config(arch).param_count())
         self.d = int(d)
         self._seed = seed
+        self._layout = None
+        if shards > 1:
+            if self.d % shards:
+                raise ValueError(f"d={self.d} does not split into "
+                                 f"{shards} equal shards")
+            from repro.launch.mesh import make_sim_mesh
+            self._layout = NamedSharding(make_sim_mesh(shards, 1),
+                                         PartitionSpec("ps"))
+        self.flat_grad = ("quadratic", self.coeffs)
+        self._loss = jax.jit(self._loss_impl)
 
-        def make(dd=self.d, s=seed):
-            i = jnp.arange(dd, dtype=jnp.float32)
-            # curvatures in [0.5, 1.5): positive definite, non-isotropic
-            a = 0.5 + ((i + 37.0 * s) % 1000.0) / 1000.0
-            wstar = jnp.sin(1e-3 * i + s)
-            return a, wstar
+    def coeffs(self, pos):
+        """(a, w*) at int32 flat positions ``pos``, deterministic in
+        (pos, seed): curvatures in [0.5, 1.5) (positive definite,
+        non-isotropic) and a smooth target."""
+        i = pos.astype(jnp.float32)
+        a = 0.5 + ((i + 37.0 * self._seed) % 1000.0) / 1000.0
+        wstar = jnp.sin(1e-3 * i + self._seed)
+        return a, wstar
 
-        a, wstar = jax.jit(make)()
-        self.flat_grad = ("quadratic", a, wstar)
-        # a / w* enter the jit as ARGUMENTS, never closure constants: XLA
-        # embeds closed-over arrays as program constants (an extra full-D
-        # copy each, plus constant-folded derivatives like -w*), which at
-        # what-if scale is tens of bytes/param of pure waste.
-        self._loss = jax.jit(
-            lambda w, a, ws: 0.5 * jnp.mean(a * (w - ws) ** 2))
+    def _loss_impl(self, w):
+        a, wstar = self.coeffs(jnp.arange(self.d, dtype=jnp.int32))
+        return 0.5 * jnp.mean(a * (w - wstar) ** 2)
 
     @property
     def init(self) -> Dict[str, jax.Array]:
-        # a fresh zeros pytree per access: the engine flattens it and drops
-        # the reference, so w0 never stays live across the replay — at
-        # what-if D every avoided (D,) resident is 4 bytes/param of peak
-        return {"w": jnp.zeros((self.d,), jnp.float32)}
+        # a fresh zeros pytree per access: nothing of size D stays resident
+        # in the problem between replays
+        return {"w": jnp.zeros((self.d,), jnp.float32, device=self._layout)}
 
     @property
     def dataset_size(self) -> int:
         return 1 << 16          # synthetic: epochs-maths placeholder
 
     def grad_fn(self, p, batch):
-        a, wstar = self.flat_grad[1], self.flat_grad[2]
+        a, wstar = self.coeffs(jnp.arange(self.d, dtype=jnp.int32))
         return {"w": a * (p["w"] - wstar)}
 
     def batch_fn_for(self, mu: int, seed: int = 0) -> Callable:
@@ -201,8 +215,7 @@ class QuadraticProblem:
         return np.zeros(np.shape(learner) + (1,), np.float32)
 
     def eval_fn(self, p) -> Dict[str, float]:
-        return {"loss": float(self._loss(p["w"], self.flat_grad[1],
-                                         self.flat_grad[2]))}
+        return {"loss": float(self._loss(p["w"]))}
 
 
 # ---------------------------------------------------------------------------

@@ -37,7 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.optim.spec import UpdateSpec, update_event
+from repro.optim.spec import UpdateSpec, combine_terms, update_event
 from repro.optim import flatten as _flatten
 
 LANES = 128
@@ -51,11 +51,12 @@ def _events(spec: UpdateSpec, mode: str, c: int, coef_ref, lrs_ref, w, s, g_ref)
     """Run the update events on one (rblk, LANES) tile.  ``w``/``s`` are fp32
     tile arrays; gradients are read from ``g_ref`` ((c, rblk, LANES))."""
     if mode == "combine":
-        coef = coef_ref[...].astype(jnp.float32)            # (c, 1)
-        g = jnp.einsum("crl,co->rl", g_ref[...].astype(jnp.float32), coef)
+        g = combine_terms(
+            c, lambda i: coef_ref[i, 0] * g_ref[i].astype(jnp.float32))
         return update_event(spec, w, s, g, lrs_ref[0, 0])
     for i in range(c):                                       # c is static
-        gi = coef_ref[i, 0] * g_ref[i].astype(jnp.float32)
+        gi = combine_terms(
+            1, lambda _: coef_ref[i, 0] * g_ref[i].astype(jnp.float32))
         w, s = update_event(spec, w, s, gi, lrs_ref[i, 0])
     return w, s
 

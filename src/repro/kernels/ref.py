@@ -10,14 +10,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.optim.spec import combine_terms
+
 
 # ---------------------------------------------------------------------------
 # ps_update
 # ---------------------------------------------------------------------------
 def ps_update_ref(w, v, g, coef, *, momentum: float, lr: float):
     """w/v: (D,); g: (c, D); coef: (c,)."""
-    weighted = jnp.einsum("cd,c->d", g.astype(jnp.float32),
-                          coef.astype(jnp.float32))
+    g32, coef = g.astype(jnp.float32), coef.astype(jnp.float32)
+    weighted = combine_terms(g.shape[0], lambda i: coef[i] * g32[i])
     v_new = momentum * v.astype(jnp.float32) + weighted
     w_new = w.astype(jnp.float32) - lr * v_new
     return w_new.astype(w.dtype), v_new.astype(v.dtype)

@@ -2,7 +2,7 @@
 
 The compiled replay engine (``core/engine.py``) executes one update event
 per ``lax.scan`` step against a (K, D) ring of parameter snapshots.  The
-stock body is a chain of XLA ops — ring gather, combine einsum, optimizer
+stock body is a chain of XLA ops — ring gather, combine sum, optimizer
 update, dynamic-update-slice write — each a separate pass over D.  This
 module fuses the whole event into ONE ``pallas_call``:
 
@@ -64,7 +64,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ps_update import DEFAULT_ROW_BLOCK, LANES
-from repro.optim.spec import UpdateSpec, update_event
+from repro.optim.spec import (UpdateSpec, combine_terms, quantize,
+                              update_event)
 
 # trace-time dispatch telemetry: how many times a replay megakernel was
 # built (counted at trace time — once per compiled scan, not per step) and
@@ -97,19 +98,17 @@ def padded_width(width: int) -> int:
 # ---------------------------------------------------------------------------
 def _tile_events(spec: UpdateSpec, mode: str, c: int, coef_ref, lrs_ref,
                  w, s, g_ref):
-    """The update events on one (rb, LANES) tile — same math as
-    ``ps_update._events`` but with the combine contraction phrased exactly
-    like ``optim.apply_event_flat``'s ``einsum("cd,c->d")`` (the stock
-    scan body), so the fp32 megakernel replay is BITWISE-equal to the
-    stock path (the ``crl,co->rl`` einsum lowers with a different
-    accumulation and drifts by 1 ulp)."""
+    """The update events on one (rb, LANES) tile — ``ps_update._events``
+    with the combine contraction phrased through ``optim.combine_terms``
+    like ``optim.apply_event_flat`` (the stock scan body), so the fp32
+    megakernel replay is BITWISE-equal to the stock path."""
     if mode == "combine":
-        gf = g_ref[...].astype(jnp.float32).reshape(c, -1)
-        ghat = jnp.einsum("cd,c->d", gf,
-                          coef_ref[...].astype(jnp.float32).reshape(c))
-        return update_event(spec, w, s, ghat.reshape(w.shape), lrs_ref[0, 0])
+        ghat = combine_terms(
+            c, lambda i: coef_ref[i, 0] * g_ref[i].astype(jnp.float32))
+        return update_event(spec, w, s, ghat, lrs_ref[0, 0])
     for i in range(c):                                    # c is static
-        gi = coef_ref[i, 0] * g_ref[i].astype(jnp.float32)
+        gi = combine_terms(
+            1, lambda _: coef_ref[i, 0] * g_ref[i].astype(jnp.float32))
         w, s = update_event(spec, w, s, gi, lrs_ref[i, 0])
     return w, s
 
@@ -140,7 +139,7 @@ def _apply_kernel(idx_ref, *refs, spec: UpdateSpec, mode: str, c: int,
         w = w + res_ref[...]                     # re-add quantization error
     s = s_ref[...].astype(jnp.float32) if stateful else None
     w, s = _tile_events(spec, mode, c, coef_ref, lrs_ref, w, s, g_ref)
-    q = w.astype(ring_out.dtype)
+    q = quantize(w, ring_out.dtype)
     ring_out[0] = q
     if stateful:
         s_out[...] = s
@@ -189,7 +188,7 @@ def _whatif_kernel(idx_ref, *refs, spec: UpdateSpec, c: int,
             w = w + res_ref[...]
         s = s_ref[...].astype(jnp.float32) if stateful else None
         w2, s2 = update_event(spec, w, s, acc_ref[...], lrs_ref[0, 0])
-        q = w2.astype(ring_out.dtype)
+        q = quantize(w2, ring_out.dtype)
         ring_out[0] = q
         if stateful:
             s_out[...] = s2

@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 
@@ -11,9 +12,9 @@ Usage:
         [--engine sequential|fused] [--json out.json]
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 
-The XLA_FLAGS line above MUST precede any jax import (device count locks at
-first init) — this module is the only place it is set; tests and benches see
-the real single CPU device.
+The platform and XLA_FLAGS lines above MUST precede any jax import (device
+count locks at first init).  The 512 devices are emulated CPU devices, so
+the platform is pinned to the CPU: a dry run never touches an accelerator.
 """
 
 import argparse

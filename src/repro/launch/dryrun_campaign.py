@@ -95,6 +95,7 @@ def run_job(arch, shape, mode, multi, timeout, force=False):
         cmd += ["--q-chunk", "4096", "--kv-chunk", "4096"]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"      # dry runs compile on emulated devices
     t0 = time.time()
     try:
         p = subprocess.run(cmd, capture_output=True, text=True,

@@ -2,21 +2,37 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; tests see 1 CPU).
-
-TPU v5e constants used by the roofline (per chip):
-  peak bf16: 197 TFLOP/s; HBM: 819 GB/s; ICI: ~50 GB/s/link.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Callable, Tuple
+from typing import Callable, Dict, Tuple
 
 import jax
+from jax._src import xla_bridge
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # B/s per chip
-ICI_BW = 50e9                # B/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind`` (Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s;
+# ICI taken as ~50 GB/s per link).  A kind not listed is an error, never a
+# default: a share of the wrong chip's peak is not a measurement.
+CHIP_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 50e9},
+}
+# the chip the production meshes (and the dry-run roofline) are built of
+PRODUCTION_CHIP = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of one chip of ``device_kind``."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                       ) from None
+
 
 SINGLE_POD_SHAPE = (16, 16)
 SINGLE_POD_AXES = ("data", "model")
@@ -32,16 +48,8 @@ _HOST_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
 def _jax_initialized() -> bool:
     """Whether a jax backend has already been created (after which the
-    host-platform device count is locked in).  Probes the private backend
-    cache so the probe itself never initializes; unknown layouts (future
-    jax) conservatively report True — the caller then validates against
-    ``jax.device_count()`` instead of silently editing a dead env var."""
-    xb = getattr(getattr(jax, "_src", None), "xla_bridge", None)
-    for attr in ("_backends", "_backend_cache"):
-        cache = getattr(xb, attr, None)
-        if isinstance(cache, dict):
-            return bool(cache)
-    return True
+    host-platform device count is locked in)."""
+    return xla_bridge.backends_are_initialized()
 
 
 def ensure_host_devices(n: int) -> int:
@@ -91,11 +99,12 @@ def _require_devices(n: int, what: str) -> None:
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.make_mesh landed in 0.4.35; the oldest CI pin predates it
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    from jax.experimental import mesh_utils
-    return jax.sharding.Mesh(mesh_utils.create_device_mesh(shape), axes)
+    # Auto axes: the model code places arrays with sharding constraints and
+    # leaves the rest to the partitioner's propagation.  jax.make_mesh's
+    # default (Explicit) puts shardings into types, and the model's
+    # reshapes and gathers do not state them.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -141,18 +150,8 @@ def make_sim_mesh(ps: int, learners: int) -> jax.sharding.Mesh:
 
 def shard_map(f: Callable, mesh: jax.sharding.Mesh, *, in_specs,
               out_specs) -> Callable:
-    """Version-spanning ``shard_map``: prefers ``jax.shard_map`` (0.6+,
-    ``check_vma`` kwarg), falls back to ``jax.experimental.shard_map``
-    (0.4.x, ``check_rep`` kwarg).  Replication checking is disabled either
-    way: the replay out-specs replicate the ring over the learner axis,
-    which the checker cannot prove through a psum-inside-scan body."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off: the replay
+    out-specs replicate the ring over the learner axis, which the checker
+    cannot prove through a psum-inside-scan body."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -18,7 +18,9 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS
+from repro.launch.mesh import PRODUCTION_CHIP, chip_peaks
+
+_PEAKS = chip_peaks(PRODUCTION_CHIP)
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
@@ -123,15 +125,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS
+        return self.hlo_flops / _PEAKS["bf16_flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / _PEAKS["hbm_bytes_per_s"]
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / _PEAKS["ici_bytes_per_s"]
 
     @property
     def dominant(self) -> str:

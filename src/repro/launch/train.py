@@ -1,15 +1,13 @@
 """Training launcher.
 
-Single-host CPU (examples/tests):
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke \
         --protocol softsync --n 4 --engine fused --steps 100 --batch 8 \
         --seq 128 --ckpt /tmp/run1
 
-Production (TPU pods): the same CLI with --mesh 16x16 / --mesh 2x16x16
-builds the mesh from repro.launch.mesh and places the jit'd step with the
-sharding policy in repro.launch.sharding.  On this CPU container the mesh
-path is exercised by the dry-run (repro.launch.dryrun); real execution runs
-on the default device.
+Training runs on the default device (a TPU chip where one is attached,
+else the CPU).  The production meshes of ``repro.launch.mesh`` and the
+sharding policy of ``repro.launch.sharding`` are exercised by the dry run
+(``repro.launch.dryrun``), which compiles them on emulated CPU devices.
 """
 
 from __future__ import annotations
@@ -24,6 +22,7 @@ import jax
 from repro.config import RunConfig
 from repro.configs import get_config, get_smoke
 from repro.checkpoint.io import load_checkpoint, save_checkpoint
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.loop import train
 
 
@@ -89,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.optim import flatten
-from repro.optim.spec import RoundFold, UpdateSpec, update_event
+from repro.optim.spec import (RoundFold, UpdateSpec, combine_terms,
+                              quantize, update_event)
 
 BACKENDS = ("reference", "jit", "pallas")
 
@@ -37,8 +38,8 @@ def _f32(tree):
 def _combine(grads: Sequence, coef) -> object:
     """Σ_i coef_i·G_i in fp32 — the staleness-weighted sumGradients."""
     return jax.tree.map(
-        lambda *g: sum(coef[i] * g[i].astype(jnp.float32)
-                       for i in range(len(g))), *grads)
+        lambda *g: combine_terms(
+            len(g), lambda i: coef[i] * g[i].astype(jnp.float32)), *grads)
 
 
 # ---------------------------------------------------------------------------
@@ -100,8 +101,9 @@ def apply_update_tree(spec: UpdateSpec, params, state, grads: Sequence,
     if mode != "sequential":
         raise ValueError(f"unknown mode {mode!r}")
     for i in range(c):
-        gi = jax.tree.map(lambda g: coef[i] * g.astype(jnp.float32),
-                          grads[i])
+        gi = jax.tree.map(
+            lambda g: combine_terms(
+                1, lambda _: coef[i] * g.astype(jnp.float32)), grads[i])
         params, state = apply_single(spec, params, state, gi, lrs[i])
     return params, state
 
@@ -132,7 +134,7 @@ def apply_event_flat(spec: UpdateSpec, w, s, g, coef, lrs,
                      mode: str = "combine"):
     """The unified multi-gradient update on flat fp32 buffers — the jit/scan
     friendly twin of the Pallas kernel's per-tile body (``ps_update._events``)
-    with the identical ``update_event`` math and combine einsum.
+    with the identical ``update_event`` math and ``combine_terms`` sum.
 
     ``w``/``s``: (D,) fp32 (``s`` None for sgd); ``g``: (c, D); ``coef``/
     ``lrs``: (c,).  This is what the compiled replay engine's scan executes
@@ -142,12 +144,14 @@ def apply_event_flat(spec: UpdateSpec, w, s, g, coef, lrs,
         raise ValueError(f"{spec.optimizer!r} has no flat event path")
     g32 = g.astype(jnp.float32)
     if mode == "combine":
-        ghat = jnp.einsum("cd,c->d", g32, coef.astype(jnp.float32))
+        coef = coef.astype(jnp.float32)
+        ghat = combine_terms(g.shape[0], lambda i: coef[i] * g32[i])
         return update_event(spec, w, s, ghat, lrs[0])
     if mode != "sequential":
         raise ValueError(f"unknown mode {mode!r}")
     for i in range(g.shape[0]):                     # c is static
-        w, s = update_event(spec, w, s, coef[i] * g32[i], lrs[i])
+        gi = combine_terms(1, lambda _: coef[i] * g32[i])
+        w, s = update_event(spec, w, s, gi, lrs[i])
     return w, s
 
 
@@ -191,7 +195,7 @@ def apply_event_ring(spec: UpdateSpec, ring, s, res, g, coef, lrs,
     if res is not None:
         w = w + res
     w, s = apply_event_flat(spec, w, s, g, coef, lrs, mode)
-    q = w.astype(ring.dtype)
+    q = quantize(w, ring.dtype)
     ring = ring.at[slot].set(q)
     if res is not None:
         res = w - q.astype(jnp.float32)
@@ -220,7 +224,7 @@ def apply_event_ring_whatif(spec: UpdateSpec, ring, s, res, a, wstar, ts,
     if res is not None:
         w = w + res
     w, s = update_event(spec, w, s, ghat, lrs[0])
-    q = w.astype(ring.dtype)
+    q = quantize(w, ring.dtype)
     ring = ring.at[slot].set(q)
     if res is not None:
         res = w - q.astype(jnp.float32)
@@ -278,14 +282,14 @@ def ring_all_gather(x, axis_name: str, size: int):
 
 
 def combine_spmd(g, coef, axis_name: str):
-    """The combine-mode einsum ĝ = Σ_j coef_j·g_j with the slot axis split
+    """The combine-mode sum ĝ = Σ_j coef_j·g_j with the slot axis split
     over ``axis_name``: each learner device reduces its local slot block,
     then one ``psum`` folds the partials.  For a single learner device the
-    psum is the identity and this is bitwise ``apply_event_flat``'s einsum;
-    with L > 1 the partial-sum tree reorders the fp32 reduction (the
-    documented ~1 ulp/event tolerance, DESIGN.md §13)."""
-    part = jnp.einsum("cd,c->d", g.astype(jnp.float32),
-                      coef.astype(jnp.float32))
+    psum is the identity and this is bitwise ``apply_event_flat``'s
+    ``combine_terms``; with L > 1 the partial-sum tree reorders the fp32
+    reduction (the documented ~1 ulp/event tolerance, DESIGN.md §13)."""
+    g32, coef = g.astype(jnp.float32), coef.astype(jnp.float32)
+    part = combine_terms(g.shape[0], lambda i: coef[i] * g32[i])
     return jax.lax.psum(part, axis_name)
 
 

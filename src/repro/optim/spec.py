@@ -96,6 +96,44 @@ def init_state(spec: UpdateSpec, params) -> dict:
 # ---------------------------------------------------------------------------
 # THE applyUpdate rule.  One optimizer event on fp32 arrays.
 # ---------------------------------------------------------------------------
+def combine_terms(c: int, term):
+    """ĝ = Σ_{i<c} term(i), summed left to right — the combine-mode
+    contraction as c elementwise multiply-adds (``term(i)`` is coef_i·G_i;
+    sequential mode passes each slot's term as a one-term sum).  The Pallas
+    kernels and their jnp twins all phrase it through here, so they agree
+    bitwise.  On a TPU it runs on the vector unit in exact fp32: Mosaic
+    rejects the (c, D)·(c,) contraction, and an XLA matmul at default
+    precision would round the gradients to bf16.
+
+    The sum starts from ``0 − (−term(0))``, not the bare product: where a
+    compiler contracts ``a·b + x`` into one fused multiply-add (XLA on the
+    CPU does), each add then holds exactly one product to fuse.  An add of
+    two bare products leaves it a choice, and the interpret-mode kernel and
+    its twin choose differently (1 ulp apart)."""
+    acc = 0.0 - (-term(0))
+    for i in range(1, c):                                   # c is static
+        acc = acc + term(i)
+    return acc
+
+
+def quantize(w, dtype):
+    """fp32 weights ``w`` rounded to the ring dtype, nearest-even.
+
+    For bf16 the rounding is done on the bits, so the bf16 conversion that
+    follows is exact: a compiler allowed excess precision (XLA on the TPU)
+    may drop the rounding of a bare f32 → bf16 → f32 convert pair, which
+    would zero the error-feedback residue ``w − q`` in one program and not
+    in another.  The kernels and their twins all quantize through here."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return w
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        raise ValueError(f"no ring quantization to {dtype}")
+    bits = jax.lax.bitcast_convert_type(w, jnp.int32)
+    lsb = jax.lax.shift_right_logical(bits, 16) & 1
+    bits = (bits + 0x7FFF + lsb) & jnp.int32(-65536)     # keep the top 16
+    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(dtype)
+
+
 def update_event(spec: UpdateSpec, w, s, g, lr):
     """θ' = θ − α·step(g) with the optimizer state folded in.
 

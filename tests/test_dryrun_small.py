@@ -15,15 +15,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCRIPT = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, dataclasses
     import jax
     from repro.config import INPUT_SHAPES, InputShape
     from repro.configs import get_smoke
+    from repro.launch.mesh import make_debug_mesh
     from repro.launch.specs import build_lowerable, make_run_config
     from repro.launch import roofline as rl
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(2, 4)
     cfg = get_smoke("{arch}")
     shape = InputShape("mini_{kind}", {seq}, {batch}, "{kind}")
     run, eng = make_run_config(cfg, shape, mesh, protocol="softsync",

@@ -362,7 +362,7 @@ def _whatif_run(cfg, steps=24, impl=None):
         return replay(trace, cfg, grad_fn=grad_fn, init_params=init,
                       batch_fn=lambda l, i: np.zeros((1,), np.float32))
     return replay(trace, cfg, init_params=init,
-                  flat_grad=("quadratic", a, wstar))
+                  flat_grad=("quadratic", lambda pos: (a[pos], wstar[pos])))
 
 
 def test_whatif_pallas_bitwise_vs_fused():

@@ -247,7 +247,9 @@ def test_fused_equals_sequential_momentum_multiround():
     W = jax.random.normal(key, (6, 3))
     Xg = jax.random.normal(jax.random.PRNGKey(1), (mu, 6))
     Yg = Xg @ W
-    batch = {"x": jnp.tile(Xg, (n, 1)), "y": jnp.tile(Yg, (n, 1))}
+    # groups are interleaved (sample s joins group s % n): repeating each
+    # sample n times gives every group the same data
+    batch = {"x": jnp.repeat(Xg, n, axis=0), "y": jnp.repeat(Yg, n, axis=0)}
 
     def loss(p, b, sample_weights=None):
         per = jnp.mean((b["x"] @ p - b["y"]) ** 2, axis=-1)
