@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro import optim
+from repro import optim, telemetry
 from repro.config import RunConfig
 from repro.core.lr_policies import resolve_trace_lrs
 from repro.core.protocols import init_ps_state
@@ -46,6 +46,15 @@ from repro.core.trace import ArrivalTrace, PlacementPlan, placement_plan
 from repro.launch import mesh as mesh_lib
 from repro.optim import flatten
 from repro.optim.spec import quantize
+
+# names in a profile: the single-device replay scan's scope, and the
+# prefix of the segment loop's host spans (:func:`_run_segments`)
+SCAN_SCOPE = "engine.replay.scan"
+SPAN = "repro.engine.replay"
+# ring rows handed to the what-if kernel: slots (c per event and shard)
+# and the distinct rows among them
+PULL_SLOTS = "replay_ring.pull_slots"
+PULL_ROWS = "replay_ring.pull_rows"
 
 # cross-shard pull assembly for the SPMD replay (DESIGN.md §13): one fused
 # all_gather over the "ps" axis, or the equivalent S−1 neighbor-ppermute
@@ -307,14 +316,17 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     # lifetimes unrolling would overlap, so both stay rolled.
     unroll = 1 if (batched or whatif) else 8
 
+    # the scope names the loop's ops in a profile (SCAN_SCOPE)
     if whatif:
         def run(carry, xs, aux):
-            return jax.lax.scan(functools.partial(event, aux), carry, xs,
-                                unroll=unroll)[0]
+            with jax.named_scope(SCAN_SCOPE):
+                return jax.lax.scan(functools.partial(event, aux), carry,
+                                    xs, unroll=unroll)[0]
         return jax.jit(run, donate_argnums=0)
 
     def run(carry, xs):
-        return jax.lax.scan(event, carry, xs, unroll=unroll)[0]
+        with jax.named_scope(SCAN_SCOPE):
+            return jax.lax.scan(event, carry, xs, unroll=unroll)[0]
 
     if batched:
         axes = {"ts": 0, "prev": None, "slot": None, "lrs": 0, "batch": 0}
@@ -848,21 +860,9 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
         return (scan_fn(carry, seg, aux) if whatif
                 else scan_fn(carry, seg))
 
-    history = []
-    if eval_fn and eval_every:
-        done = 0
-        while done < steps:
-            take = min(eval_every, steps - done)
-            seg = jax.tree.map(lambda a: a[done:done + take], xs)
-            carry = advance(carry, seg)
-            done += take
-            if done % eval_every == 0:
-                history.append({"update": done,
-                                "time": float(trace.event_time[done - 1]),
-                                **eval_fn(params_of(carry, done))})
-    else:
-        carry = advance(carry, xs)
-
+    carry, history = _run_segments(trace, xs, carry, advance, params_of,
+                                   eval_fn, eval_every,
+                                   _pull_counts(trace, K) if whatif else None)
     params = params_of(carry, steps)
     serve_result = None
     if serving is not None:
@@ -871,6 +871,59 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     return SimResult(trace.clock_log(), steps, trace.simulated_time,
                      trace.minibatches, params, history,
                      serving=serve_result)
+
+
+def _pull_counts(trace: ArrivalTrace, K: int):
+    """Cumulative ``(slots, rows)`` the what-if events pull from the ring:
+    entry j counts events [0, j).  An event's slots are its c pulled
+    indices (per PS shard); its rows are the distinct ``ts % K`` among
+    them."""
+    ts = np.asarray(trace.pulled_ts if trace.shard_pulled_ts is None
+                    else trace.shard_pulled_ts)
+    ts = np.sort((ts if ts.ndim == 3 else ts[..., None]) % K, axis=1)
+    rows = (1 + np.count_nonzero(np.diff(ts, axis=1), axis=1)).sum(axis=-1)
+    slots = np.full(trace.steps, ts.shape[1] * ts.shape[2])
+    return tuple(np.concatenate([[0], np.cumsum(v, dtype=np.int64)])
+                 for v in (slots, rows))
+
+
+def _run_segments(trace: ArrivalTrace, xs, carry, advance: Callable,
+                  params_of: Callable, eval_fn: Optional[Callable],
+                  eval_every: int, pulls=None):
+    """Drive the replay scan over the trace's events: in one dispatch, or,
+    with ``eval_fn`` and ``eval_every``, in segments of ``eval_every``
+    events, handing the weights after each full one to ``eval_fn``.
+    Returns ``(carry, history)``.
+
+    Each segment is a step span (``<SPAN>.segment``, numbered from 0)
+    holding the spans ``<SPAN>.inputs`` (the slice of the scan inputs),
+    ``<SPAN>.dispatch`` (the scan call) and ``<SPAN>.handoff`` (the
+    weights to ``eval_fn``).  ``pulls``, from :func:`_pull_counts`, adds
+    each segment's pulled ring slots and rows to the telemetry counters
+    as it is dispatched."""
+    steps = trace.steps
+    segmented = bool(eval_fn and eval_every)
+    bounds = ([(lo, min(lo + eval_every, steps))
+               for lo in range(0, steps, eval_every)] if segmented
+              else [(0, steps)])
+    history = []
+    for index, (lo, hi) in enumerate(bounds):
+        with telemetry.span(f"{SPAN}.segment", step=index):
+            with telemetry.span(f"{SPAN}.inputs"):
+                seg = (xs if (lo, hi) == (0, steps)
+                       else jax.tree.map(lambda a: a[lo:hi], xs))
+            with telemetry.span(f"{SPAN}.dispatch"):
+                if pulls is not None:
+                    telemetry.count(PULL_SLOTS, pulls[0][hi] - pulls[0][lo])
+                    telemetry.count(PULL_ROWS, pulls[1][hi] - pulls[1][lo])
+                carry = advance(carry, seg)
+            if segmented and hi % eval_every == 0:
+                with telemetry.span(f"{SPAN}.handoff"):
+                    history.append(
+                        {"update": hi,
+                         "time": float(trace.event_time[hi - 1]),
+                         **eval_fn(params_of(carry, hi))})
+    return carry, history
 
 
 def _pub_index(serving, steps: int) -> np.ndarray:
@@ -1027,21 +1080,9 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
         return (scan_fn(carry, seg, aux) if whatif
                 else scan_fn(carry, seg))
 
-    history = []
-    if eval_fn and eval_every:
-        done = 0
-        while done < steps:
-            take = min(eval_every, steps - done)
-            seg = jax.tree.map(lambda a: a[done:done + take], xs)
-            carry = advance(carry, seg)
-            done += take
-            if done % eval_every == 0:
-                history.append({"update": done,
-                                "time": float(trace.event_time[done - 1]),
-                                **eval_fn(params_of(carry, done))})
-    else:
-        carry = advance(carry, xs)
-
+    carry, history = _run_segments(trace, xs, carry, advance, params_of,
+                                   eval_fn, eval_every,
+                                   _pull_counts(trace, K) if whatif else None)
     params = params_of(carry, steps)
     return SimResult(trace.clock_log(), steps, trace.simulated_time,
                      trace.minibatches, params, history)

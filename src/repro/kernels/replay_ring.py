@@ -47,10 +47,18 @@ and the elementwise event math guarantees per-shard applies are exactly
 the shard slices of the single-device apply.
 
 Off-accelerator every entry point selects ``interpret=True`` automatically
-(the CPU-CI fallback contract of ``kernels/ops.py``); the module-level
-``pallas_dispatches``/``last_interpret`` counters record which dispatch
-branch built the kernel so tests can assert the fused path is really the
-one exercised.
+(the CPU-CI fallback contract of ``kernels/ops.py``); the
+``replay_ring.pallas_dispatches``/``replay_ring.last_interpret`` counters
+of ``repro.telemetry`` (readable here as the module attributes
+``pallas_dispatches``/``last_interpret``) record which dispatch branch
+built the kernel so tests can assert the fused path is really the one
+exercised.
+
+Names in a profile: the kernels are the ``pallas_call``s
+``replay_ring_whatif`` and ``replay_ring_apply``; the reshapes of the
+ring, state, residue (and a, w*) to the kernel's (rows, 128) tiling and
+back run under the scopes ``replay_ring.to_tiles`` and
+``replay_ring.from_tiles``.
 """
 
 from __future__ import annotations
@@ -63,6 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import telemetry
 from repro.kernels.ps_update import DEFAULT_ROW_BLOCK, LANES
 from repro.optim.spec import (UpdateSpec, combine_terms, quantize,
                               update_event)
@@ -71,8 +80,36 @@ from repro.optim.spec import (UpdateSpec, combine_terms, quantize,
 # built (counted at trace time — once per compiled scan, not per step) and
 # whether the last build ran in interpret mode.  Tests assert on these to
 # pin the CPU-CI fallback branch.
-pallas_dispatches = 0
-last_interpret: Optional[bool] = None
+DISPATCHES = "replay_ring.pallas_dispatches"
+LAST_INTERPRET = "replay_ring.last_interpret"
+
+
+def __getattr__(name: str):
+    """``pallas_dispatches`` and ``last_interpret`` (None before the first
+    build), read from the telemetry counters."""
+    if name == "pallas_dispatches":
+        return telemetry.counters().get(DISPATCHES, 0)
+    if name == "last_interpret":
+        v = telemetry.counters().get(LAST_INTERPRET)
+        return None if v is None else bool(v)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def _count_build(interpret: bool) -> None:
+    telemetry.count(DISPATCHES)
+    telemetry.mark(LAST_INTERPRET, bool(interpret))
+
+
+def _from_tiles(out, K: int, Dp: int, stateful: bool, ef: bool):
+    """The kernel's tiled (ring, state, residue) outputs back to the
+    engine's flat (K, Dp) / (Dp,) layout."""
+    with jax.named_scope("replay_ring.from_tiles"):
+        ring2 = out[0].reshape(K, Dp)
+        k = 1
+        s2 = out[k].reshape(Dp) if stateful else None
+        k += int(stateful)
+        res2 = out[k].reshape(Dp) if ef else None
+    return ring2, s2, res2
 
 
 def default_interpret() -> bool:
@@ -214,13 +251,11 @@ def ring_apply(ring: jax.Array, s: Optional[jax.Array],
     None (sgd); ``res``: (Dp,) fp32 error-feedback residue or None (fp32
     ring); ``g``: (c, Dp) fp32; ``coef``/``lrs``: (c,); ``idx``: (2,)
     int32 [prev, slot].  Returns the updated (ring, s, res)."""
-    global pallas_dispatches, last_interpret
     if not spec.kernel_supported:
         raise ValueError(f"{spec.optimizer!r} has no kernel path")
     if interpret is None:
         interpret = default_interpret()
-    pallas_dispatches += 1
-    last_interpret = bool(interpret)
+    _count_build(interpret)
 
     K, Dp = ring.shape
     c = g.shape[0]
@@ -233,8 +268,11 @@ def ring_apply(ring: jax.Array, s: Optional[jax.Array],
     grid = (rows // row_block,)
     stateful, ef = s is not None, res is not None
 
-    ringt = ring.reshape(K, rows, LANES)
-    gt = g.reshape(c, rows, LANES)
+    with jax.named_scope("replay_ring.to_tiles"):
+        ringt = ring.reshape(K, rows, LANES)
+        gt = g.reshape(c, rows, LANES)
+        st = s.reshape(rows, LANES) if stateful else None
+        rt = res.reshape(rows, LANES) if ef else None
     coef2 = coef.reshape(c, 1).astype(jnp.float32)
     lrs2 = lrs.reshape(c, 1).astype(jnp.float32)
 
@@ -253,14 +291,12 @@ def ring_apply(ring: jax.Array, s: Optional[jax.Array],
     # scalar prefetch counts as input 0, so the ring is input index 3
     aliases = {3: 0}
     if stateful:
-        st = s.reshape(rows, LANES)
         aliases[len(operands) + 1] = len(out_shape)
         operands.append(st)
         in_specs.append(row)
         out_shape.append(jax.ShapeDtypeStruct(st.shape, st.dtype))
         out_specs.append(row)
     if ef:
-        rt = res.reshape(rows, LANES)
         aliases[len(operands) + 1] = len(out_shape)
         operands.append(rt)
         in_specs.append(row)
@@ -279,14 +315,9 @@ def ring_apply(ring: jax.Array, s: Optional[jax.Array],
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="replay_ring_apply",
     )(idx.astype(jnp.int32), *operands)
-
-    ring2 = out[0].reshape(K, Dp)
-    k = 1
-    s2 = out[k].reshape(Dp) if stateful else None
-    k += int(stateful)
-    res2 = out[k].reshape(Dp) if ef else None
-    return ring2, s2, res2
+    return _from_tiles(out, K, Dp, stateful, ef)
 
 
 def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
@@ -303,7 +334,6 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
     (Dp,) fp32 (zero-padded — padded a makes padded gradients zero, so the
     pad stays inert).  Combine mode only; requires K ≥ 2 (the engine falls
     back to the streamed jnp twin for K = 1)."""
-    global pallas_dispatches, last_interpret
     if not spec.kernel_supported:
         raise ValueError(f"{spec.optimizer!r} has no kernel path")
     if ring.shape[0] < 2:
@@ -311,8 +341,7 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
                          "a pulled row); use the jnp twin for K = 1")
     if interpret is None:
         interpret = default_interpret()
-    pallas_dispatches += 1
-    last_interpret = bool(interpret)
+    _count_build(interpret)
 
     K, Dp = ring.shape
     c = idx.shape[0] - 2
@@ -325,7 +354,12 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
     grid = (rows // row_block, c)
     stateful, ef = s is not None, res is not None
 
-    ringt = ring.reshape(K, rows, LANES)
+    with jax.named_scope("replay_ring.to_tiles"):
+        ringt = ring.reshape(K, rows, LANES)
+        at = a.reshape(rows, LANES)
+        wt = wstar.reshape(rows, LANES)
+        st = s.reshape(rows, LANES) if stateful else None
+        rt = res.reshape(rows, LANES) if ef else None
     coef2 = coef.reshape(c, 1).astype(jnp.float32)
     lrs2 = lrs.reshape(c, 1).astype(jnp.float32)
 
@@ -338,22 +372,18 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
     ring_out = pl.BlockSpec((1, row_block, LANES),
                             lambda i, j, idx: (idx[1], i, 0))
 
-    at = a.reshape(rows, LANES)
-    wt = wstar.reshape(rows, LANES)
     operands = [coef2, lrs2, ringt, ringt, at, wt]
     in_specs = [vec, vec, ring_ts, ring_prev, row, row]
     out_shape = [jax.ShapeDtypeStruct(ringt.shape, ringt.dtype)]
     out_specs = [ring_out]
     aliases = {4: 0}          # alias the prev-row ring operand (input idx 4)
     if stateful:
-        st = s.reshape(rows, LANES)
         aliases[len(operands) + 1] = len(out_shape)
         operands.append(st)
         in_specs.append(row)
         out_shape.append(jax.ShapeDtypeStruct(st.shape, st.dtype))
         out_specs.append(row)
     if ef:
-        rt = res.reshape(rows, LANES)
         aliases[len(operands) + 1] = len(out_shape)
         operands.append(rt)
         in_specs.append(row)
@@ -371,11 +401,6 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="replay_ring_whatif",
     )(idx.astype(jnp.int32), *operands)
-
-    ring2 = out[0].reshape(K, Dp)
-    k = 1
-    s2 = out[k].reshape(Dp) if stateful else None
-    k += int(stateful)
-    res2 = out[k].reshape(Dp) if ef else None
-    return ring2, s2, res2
+    return _from_tiles(out, K, Dp, stateful, ef)

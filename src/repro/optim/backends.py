@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.optim import flatten
 from repro.optim.spec import (RoundFold, UpdateSpec, combine_terms,
                               quantize, update_event)
@@ -27,8 +28,15 @@ from repro.optim.spec import (RoundFold, UpdateSpec, combine_terms,
 BACKENDS = ("reference", "jit", "pallas")
 
 # host-side count of fused-kernel dispatches (tests/benchmarks assert the
-# Pallas path really is the one being exercised).
-pallas_dispatches = 0
+# Pallas path really is the one being exercised), a telemetry counter
+DISPATCHES = "optim.backends.pallas_dispatches"
+
+
+def __getattr__(name: str):
+    """``pallas_dispatches``, read from the telemetry counters."""
+    if name == "pallas_dispatches":
+        return telemetry.counters().get(DISPATCHES, 0)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def _f32(tree):
@@ -343,7 +351,6 @@ def apply_update(spec: UpdateSpec, params, state, grads: Sequence,
     ``grads``: sequence of c gradient pytrees.  ``coef``: (c,) combination
     weights.  ``lrs``: (c,) per-event LRs (``combine`` mode reads lrs[0]).
     """
-    global pallas_dispatches
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
     grads = tuple(grads)
@@ -357,7 +364,7 @@ def apply_update(spec: UpdateSpec, params, state, grads: Sequence,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if backend == "pallas":
-        pallas_dispatches += 1
+        telemetry.count(DISPATCHES)
     fn = _jitted(spec, mode, len(grads), backend, bool(interpret))
     return fn(params, state, grads, coef, lrs)
 

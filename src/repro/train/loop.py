@@ -79,5 +79,8 @@ def train(cfg: ModelConfig, run: RunConfig, *, steps: int,
             if log:
                 log(f"step {step+1}: " + " ".join(
                     f"{k}={v:.4f}" for k, v in entry.items() if k != "step"))
+    # the steps are enqueued asynchronously: the clock stops when the
+    # device has finished the last one
+    params, opt = jax.block_until_ready((params, opt))
     wall = time.perf_counter() - t0
     return TrainResult(params, opt, history, steps, wall)

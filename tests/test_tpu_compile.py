@@ -12,14 +12,16 @@ and every test worker imports every test file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import engine
 from repro.kernels import ps_update, replay_ring
-from repro.optim import UpdateSpec
+from repro.optim import UpdateSpec, flatten
 
 D = 1 << 20          # flat parameter width
 K = 4                # ring depth
@@ -96,3 +98,34 @@ def test_ps_apply_combine_compiles(one_chip, optimizer):
                            interpret=False)
     _assert_kernel(fn, f32((D,)), state, f32((C, D)), f32((C,)), f32((C,)))
 
+
+def test_whatif_scan_names_its_kernel_and_relayout(one_chip, monkeypatch):
+    """The what-if replay scan, compiled whole: its kernel instruction is
+    named after the ``pallas_call`` and the ring's reshapes to the
+    kernel's tiling and back carry their scopes in ``op_name``, so a
+    profile can find them."""
+    # off the chip the engine picks interpret mode; compile the chip's path
+    monkeypatch.setattr(replay_ring, "default_interpret", lambda: False)
+    steps = 16
+    W = replay_ring.padded_width(D)
+    layout = flatten.layout_of({"w": jax.ShapeDtypeStruct((D,),
+                                                          jnp.float32)})
+    # uncached: a scan traced for the chip stays out of the
+    # cache the CPU tests use
+    fn = engine._make_scan_fn.__wrapped__(
+        None, UpdateSpec(optimizer="momentum"), "combine", C, K, layout,
+        ring_impl="pallas", ring_dtype="bf16", whatif=True)
+    f32 = functools.partial(_sds, dtype=jnp.float32, sharding=one_chip)
+    i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
+    carry = (_sds((K, W), jnp.bfloat16, one_chip), f32((W,)), f32((W,)))
+    xs = {"ts": i32((steps, C)), "prev": i32((steps,)),
+          "slot": i32((steps,)), "lrs": f32((steps, C))}
+    text = fn.lower(carry, xs, (f32((W,)), f32((W,)))).compile().as_text()
+    assert re.search(r'%replay_ring_whatif[.\d]* = .*custom_call_target='
+                     r'"tpu_custom_call"', text)
+    rows = W // 128
+    for shape, scope in ((f"bf16[{K},{rows},128]", "replay_ring.to_tiles"),
+                         (f"bf16[{K},{W}]", "replay_ring.from_tiles")):
+        assert re.search(re.escape(f"= {shape}") + r"\{.*op_name=\"[^\"]*"
+                         + re.escape(f"{engine.SCAN_SCOPE}/") + r"[^\"]*"
+                         + re.escape(f"/{scope}/"), text), scope
