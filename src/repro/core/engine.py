@@ -43,6 +43,7 @@ from repro.core.protocols import init_ps_state
 from repro.core.simulator import SimResult
 from repro.core.topology import Topology
 from repro.core.trace import ArrivalTrace, PlacementPlan, placement_plan
+from repro.kernels.ps_update import LANES
 from repro.launch import mesh as mesh_lib
 from repro.optim import flatten
 from repro.optim.spec import quantize
@@ -55,6 +56,8 @@ SPAN = "repro.engine.replay"
 # and the distinct rows among them
 PULL_SLOTS = "replay_ring.pull_slots"
 PULL_ROWS = "replay_ring.pull_rows"
+# what-if scans built (traced) with the ring carried in the kernel's tiles
+TILED_CARRY_BUILDS = "engine.replay.tiled_carry_builds"
 
 # cross-shard pull assembly for the SPMD replay (DESIGN.md §13): one fused
 # all_gather over the "ps" axis, or the equivalent S−1 neighbor-ppermute
@@ -71,6 +74,62 @@ def _unflatten_jit(layout: flatten.TreeLayout) -> Callable:
 def _unstack_tree(tree, c: int):
     """Tree with a leading (c,) axis → list of c pytrees (c is static)."""
     return [jax.tree.map(lambda x: x[i], tree) for i in range(c)]
+
+
+def _lanes(width: int, tiled: bool) -> tuple:
+    """The carry shape of one ring row: flat ``(width,)``, or the Pallas
+    kernels' ``(width / 128, 128)`` tiles.  A bf16 (K, width) ring is laid
+    out across K, so reshaping it to the kernel's tiles and back copies the
+    whole ring; carried in the tiles, the kernel updates it in place."""
+    return (width // LANES, LANES) if tiled else (width,)
+
+
+def _flat_views(carry, K: int):
+    """The (K, W) ring and (W,) state / residue of a ``(ring, state,
+    residue)`` carry held in any :func:`_lanes` (with or without a leading
+    device axis of 1): reshapes that fold into the kernels' own."""
+    ring, s, res = carry
+    return (ring.reshape(K, -1), None if s is None else s.reshape(-1),
+            None if res is None else res.reshape(-1))
+
+
+def _carry_like(carry, ring, s, res):
+    """Flat ``(ring, state, residue)`` back in the shapes of ``carry``."""
+    return tuple(None if v is None else v.reshape(c.shape)
+                 for c, v in zip(carry, (ring, s, res)))
+
+
+# the carry of the fused and Pallas bodies, and the weights of one of its
+# ring rows, each made by one program: run op by op, their steps leave
+# model-sized intermediates queued on the device
+@functools.partial(jax.jit, static_argnames=("lanes", "K", "ring_dtype"))
+def _ring_carry(params, state, *, lanes: tuple, K: int, ring_dtype: str):
+    """``(ring, state, residue)``: K copies of the params quantized to the
+    ring dtype, the optimizer state (or None) and, for a bf16 ring, the
+    float32 residue of the quantization, each row zero-padded to the
+    ``lanes`` width."""
+    width = int(np.prod(lanes))
+    flat = flatten.pad_flat(flatten.tree_to_flat(params), width)
+    flat = flat.reshape(lanes)
+    q0 = quantize(flat, jnp.bfloat16 if ring_dtype == "bf16"
+                  else jnp.float32)
+    ring = jnp.broadcast_to(q0[None], (K,) + lanes)
+    res = flat - q0.astype(jnp.float32) if ring_dtype == "bf16" else None
+    if state is not None:
+        state = flatten.pad_flat(flatten.tree_to_flat(state), width)
+        state = state.reshape(lanes)
+    return ring, state, res
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def _ring_row_params(ring, res, i, *, layout: flatten.TreeLayout):
+    """The weights of ring row ``i`` (plus the residue, if any) as the
+    params pytree."""
+    row = jax.lax.dynamic_index_in_dim(ring, i, keepdims=False)
+    row = row.astype(jnp.float32)
+    if res is not None:
+        row = row + res
+    return flatten.flat_to_tree(row.reshape(-1)[:layout.total], layout)
 
 
 def _rows(ring: jax.Array, idx: jax.Array) -> jax.Array:
@@ -162,7 +221,10 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     the gradient stage for the in-kernel/streamed closed-form gradients
     (``g = a ⊙ (w_pulled − w*)``; combine mode, trivial topology): the
     scan fn then takes ``(carry, xs, (a, w*))`` and no minibatches ride
-    the trace at all.
+    the trace at all.  The what-if body takes its carry and (a, w*) in
+    any :func:`_lanes`; ``replay`` hands the Pallas kernel its own tiles,
+    which it then updates in place (counted once per traced scan in
+    ``TILED_CARRY_BUILDS``).
     """
     coef = jnp.full((c,), 1.0 / c, jnp.float32)
     D = layout.total
@@ -231,8 +293,8 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
 
         if whatif:
             def event(aux, carry, x):
-                ring, s, res = carry
-                a, wstar = aux
+                ring, s, res = _flat_views(carry, K)
+                a, wstar = (v.reshape(-1) for v in aux)
                 if ring_impl == "pallas" and K >= 2:
                     idx = jnp.concatenate(
                         [jnp.stack([x["prev"], x["slot"]]), x["ts"]])
@@ -243,7 +305,7 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                     ring, s, res = optim.apply_event_ring_whatif(
                         spec, ring, s, res, a, wstar, x["ts"], coef_of(x),
                         x["lrs"], x["prev"], x["slot"])
-                return (ring, s, res), None
+                return _carry_like(carry, ring, s, res), None
         else:
             def event(carry, x):
                 ring, s, res = carry
@@ -319,6 +381,8 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     # the scope names the loop's ops in a profile (SCAN_SCOPE)
     if whatif:
         def run(carry, xs, aux):
+            if carry[0].ndim == 3:               # the ring in _lanes tiles
+                telemetry.count(TILED_CARRY_BUILDS)
             with jax.named_scope(SCAN_SCOPE):
                 return jax.lax.scan(functools.partial(event, aux), carry,
                                     xs, unroll=unroll)[0]
@@ -459,26 +523,12 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
         vp = flatten.pad_flat(vec, S * Dp)
         return jax.lax.dynamic_slice_in_dim(vp, si * Dp, Dp, vp.ndim - 1)
 
-    # this device's carry block is (1, K, *lanes) / (1, *lanes): lanes is
-    # (Wl,) or, for the Pallas body, the kernel's own (Wl/128, 128) tiling,
-    # so the kernel reads and writes the loop carry in place.  The flat
-    # (K, Wl) views below fold into the kernel's reshapes.
-    def unpack_carry(carry):
-        ring, s, res = carry
-        return (ring[0].reshape(K, Wl),
-                None if s is None else s[0].reshape(Wl),
-                None if res is None else res[0].reshape(Wl))
-
-    def pack_carry(carry, rl, sl, resl):
-        ring, s, res = carry
-        return (rl.reshape(ring.shape),
-                None if sl is None else sl.reshape(s.shape),
-                None if resl is None else resl.reshape(res.shape))
-
+    # this device's carry block is (1, K, *lanes) / (1, *lanes), lanes
+    # from _lanes: the Pallas body keeps the kernel's tiles
     if whatif:
         def event(aux, carry, x):
-            rl, sl, resl = unpack_carry(carry)
-            a_l, ws_l = aux[0][0].reshape(Wl), aux[1][0].reshape(Wl)
+            rl, sl, resl = _flat_views(carry, K)
+            a_l, ws_l = (v.reshape(-1) for v in aux)
             ts_col = x["ts"][:, 0]
             if ring_impl == "pallas" and K >= 2:
                 idx = jnp.concatenate(
@@ -490,10 +540,10 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                 rl, sl, resl = optim.apply_event_ring_whatif(
                     spec, rl, sl, resl, a_l, ws_l, ts_col, coef_of(x),
                     x["lrs"], x["prev"], x["slot"])
-            return pack_carry(carry, rl, sl, resl), None
+            return _carry_like(carry, rl, sl, resl), None
     else:
         def event(carry, x):
-            rl, sl, resl = unpack_carry(carry)
+            rl, sl, resl = _flat_views(carry, K)
             w = pulled_weights(rl, x)
             lo = jax.lax.axis_index("learner") * cl
             g = local_gradients(w, x, lo)             # (cl, D)
@@ -517,7 +567,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                 rl, sl, resl = optim.apply_event_ring(
                     spec, rl, sl, resl, gp, cvec, lvec, x["prev"],
                     x["slot"], mode)
-            return pack_carry(carry, rl, sl, resl), None
+            return _carry_like(carry, rl, sl, resl), None
 
     carry_specs = sharding_lib.spmd_carry_specs()
     xs_specs = sharding_lib.spmd_xs_specs(xs_keys)
@@ -754,7 +804,6 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     _, opt_state = init_ps_state(run, init_params)
 
     impl = optim.resolve_ring_impl(run.ring_impl, spec)
-    ef = run.ring_dtype == "bf16"
     whatif = (flat_grad is not None and impl != "stock"
               and trace.mode == "combine" and S == 1 and gs == 1
               and serving is None)
@@ -779,48 +828,43 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
                    batches=None if whatif else batches)
     if serving is not None:
         xs["pub"] = jnp.asarray(_pub_index(serving, steps), jnp.int32)
-    flat0 = flatten.tree_to_flat(init_params)
-    D = flat0.shape[0]
+    D = layout.total
     Dp = topo.padded_width(D)
     if impl != "stock":
-        # flat (K, width) ring in the ring dtype — sharded traces use the
+        # (K, width) ring in the ring dtype — sharded traces use the
         # concatenated shard rows (width = S·Dp ≥ D), the Pallas megakernel
         # a row-block tile multiple on top; padding zeros are inert.  With
         # a bf16 ring the fp32 error-feedback residue of the latest row
-        # completes the carry; the scan donates all three buffers.
+        # completes the carry; the scan donates all three buffers.  The
+        # what-if kernel takes its rows in _lanes tiles, which the carry
+        # keeps (the K = 1 fallback and the fused twin run flat).
         from repro.kernels import replay_ring   # lazy: import cycle
         width = D if S == 1 else S * Dp
         if impl == "pallas":
             width = replay_ring.padded_width(width)
-        rdt = jnp.bfloat16 if ef else jnp.float32
-        flat_pad = flatten.pad_flat(flat0, width)
-        q0 = quantize(flat_pad, rdt)
-        ring = jnp.tile(q0[None], (K, 1))
-        res0 = (flat_pad - q0.astype(jnp.float32)) if ef else None
-        s0 = None
-        if spec.state_keys:
-            s0 = flatten.pad_flat(
-                flatten.tree_to_flat(opt_state[spec.state_keys[0]]), width)
-        carry = (ring, s0, res0)
+        lanes = _lanes(width, whatif and impl == "pallas" and K >= 2)
+        carry = _ring_carry(
+            init_params,
+            opt_state[spec.state_keys[0]] if spec.state_keys else None,
+            lanes=lanes, K=K, ring_dtype=run.ring_dtype)
 
         def params_of(carry, done):
-            row = carry[0][done % K].astype(jnp.float32)
-            if ef:
-                row = row + carry[2]
-            return _unflatten_jit(layout)(row[:D])
+            return _ring_row_params(carry[0], carry[2], done % K,
+                                    layout=layout)
 
         aux = None
         if whatif:
             aux = jax.jit(lambda base: tuple(
-                v[0] for v in _whatif_aux(flat_grad[1], base, width, width,
-                                          D)))(jnp.zeros((1,), jnp.int32))
+                v[0].reshape(lanes)
+                for v in _whatif_aux(flat_grad[1], base, width, width,
+                                     D)))(jnp.zeros((1,), jnp.int32))
     elif S > 1:
         # per-shard rings: (S, K, Dp), row r of shard s = snapshot ts=r of
         # the shard's slice (the σ_s ≤ σ invariant keeps K a valid bound)
-        ring = jnp.broadcast_to(
-            flatten.shard_pack(flat0, S, Dp)[:, None, :], (S, K, Dp))
+        ring = jnp.broadcast_to(flatten.shard_pack(
+            flatten.tree_to_flat(init_params), S, Dp)[:, None, :], (S, K, Dp))
     else:
-        ring = jnp.broadcast_to(flat0, (K, D))
+        ring = jnp.broadcast_to(flatten.tree_to_flat(init_params), (K, D))
     if impl == "stock" and spec.kernel_supported:
         # flat-domain carry: ring + the (D,)/(S, Dp) state vector (or None)
         s0 = None
@@ -1037,9 +1081,7 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
     mesh = mesh_lib.make_sim_mesh(plan.shards, plan.learners)
     per_ps = NamedSharding(mesh, PartitionSpec("ps"))
 
-    # per-device lanes: the Pallas body keeps the kernel's (Wl/128, 128)
-    # tiling so its loop carry needs no relayout (see _make_spmd_scan_fn)
-    lanes = (Wl // 128, 128) if impl == "pallas" else (Wl,)
+    lanes = _lanes(Wl, impl == "pallas")
 
     def pack(vec):                                           # (S, *lanes)
         return flatten.pad_flat(flatten.shard_pack(vec, S, Dp),

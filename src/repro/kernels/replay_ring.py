@@ -17,10 +17,18 @@ row-block grid).  Two properties make it one launch per scan step:
   (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index maps pick the
   ring *rows* dynamically per launch while the grid stays static.
 * **In-place ring writes** — ``input_output_aliases`` aliases the ring (and
-  state/residue) inputs onto the outputs, so the kernel updates one
-  (1, row_block, 128) slot-row block in place instead of copying the whole
-  K·D ring per event.  Under ``lax.scan`` with a donated carry this is the
-  difference between the ring living in memory once vs. three times.
+  state/residue) inputs onto the outputs, so the kernel writes only the
+  (1, row_block, 128) slot-row blocks of the ring it was given.  The
+  what-if kernel takes the ring as ONE operand and reads its previous row
+  through the aliased output; the replay scan (``core/engine.py``) carries
+  that ring, and state, residue, a and w*, in the kernel's (rows, 128)
+  tiles, so the flat views it passes here fold into the reshapes below
+  and the event updates the loop carry itself: no copy of the K·D ring
+  per event, and no scan temporaries of ring size
+  (``tests/test_tpu_compile.py``).  ``ring_apply`` takes its ring once
+  too, but the staged-gradient scan keeps a flat (K, Dp) carry, which a
+  bf16 ring lays out across K: there the reshapes to the tiles and back
+  are copies of the ring.
 
 Compressed ring (``ring_dtype == bf16``): the ring rows store bf16
 snapshots while the update math stays fp32.  The quantization error is not
@@ -58,7 +66,8 @@ Names in a profile: the kernels are the ``pallas_call``s
 ``replay_ring_whatif`` and ``replay_ring_apply``; the reshapes of the
 ring, state, residue (and a, w*) to the kernel's (rows, 128) tiling and
 back run under the scopes ``replay_ring.to_tiles`` and
-``replay_ring.from_tiles``.
+``replay_ring.from_tiles`` where they are copies (the staged-gradient
+body's flat carry).
 """
 
 from __future__ import annotations
@@ -184,53 +193,85 @@ def _apply_kernel(idx_ref, *refs, spec: UpdateSpec, mode: str, c: int,
         res_out[...] = w - q.astype(jnp.float32)
 
 
-def _whatif_kernel(idx_ref, *refs, spec: UpdateSpec, c: int,
-                   stateful: bool, ef: bool):
+def _whatif_kernel(idx_ref, *refs, spec: UpdateSpec, c: int, nb: int,
+                   rb: int, stateful: bool, ef: bool):
     """One fused ring event with IN-KERNEL quadratic gradients.
 
     Grid: (row_blocks, c) — the inner grid axis streams the c slots, each
-    reading its pulled ring row block (``idx_ref[2 + j]``) and accumulating
-    ``coef_j · a ⊙ (w_ts − w*)`` into a VMEM scratch tile; the last slot
-    runs the optimizer event and writes ring/state/residue.  The (c, D)
-    gradient matrix never exists.  Combine mode only; the caller guarantees
-    K ≥ 2 so the slot row written here is never also a pulled row of a
-    *later* row block in this launch's column range (blocks are column-
-    disjoint, so even max-stale reads of the slot row are safe)."""
-    n_in = 6 + int(stateful) + int(ef)
-    ins, outs, acc_ref = refs[:n_in], refs[n_in:-1], refs[-1]
-    coef_ref, lrs_ref = ins[0], ins[1]
-    ring_ts_ref, ring_prev_ref = ins[2], ins[3]
-    a_ref, ws_ref = ins[4], ins[5]
-    k = 6
-    s_ref = ins[k] if stateful else None
-    k += int(stateful)
-    res_ref = ins[k] if ef else None
+    reading its pulled ring row block (``idx_ref[2 + j]``, the ring
+    operand's block) and accumulating ``coef_j · a ⊙ (w_ts − w*)`` into a
+    VMEM scratch tile; the last slot runs the optimizer event and writes
+    ring/state/residue.  The (c, D) gradient matrix never exists.
+
+    The ring output stays in HBM and is the ring operand's own buffer
+    (aliased): the kernel copies the previous row block (``idx_ref[0]``)
+    in from it one row block ahead, and the new slot row block
+    (``idx_ref[1]``) out to it, double-buffered.  The caller guarantees
+    K ≥ 2, so the slot row is not the previous row; it may be a pulled
+    row, but blocks are column-disjoint and block i is written only after
+    its last read."""
+    n_in = 5 + int(stateful) + int(ef)
+    ins, outs = refs[:n_in], refs[n_in:]
+    coef_ref, lrs_ref, ring_ref, a_ref, ws_ref = ins[:5]
+    s_ref = ins[5] if stateful else None
+    res_ref = ins[5 + int(stateful)] if ef else None
     ring_out = outs[0]
     s_out = outs[1] if stateful else None
     res_out = outs[1 + int(stateful)] if ef else None
+    acc_ref, prev_buf, out_buf, sems = outs[1 + int(stateful) + int(ef):]
 
-    j = pl.program_id(1)
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    def cols(b):
+        return pl.ds(pl.multiple_of(b * rb, rb), rb)
+
+    def prev(b):
+        return pltpu.make_async_copy(ring_out.at[idx_ref[0], cols(b)],
+                                     prev_buf.at[b % 2], sems.at[0, b % 2])
+
+    def write(b):
+        return pltpu.make_async_copy(out_buf.at[b % 2],
+                                     ring_out.at[idx_ref[1], cols(b)],
+                                     sems.at[1, b % 2])
+
+    @pl.when((i == 0) & (j == 0))
+    def _first():
+        prev(i).start()
+
+    @pl.when((j == 0) & (i + 1 < nb))
+    def _next_prev():
+        prev(i + 1).start()
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    g_j = a_ref[...] * (ring_ts_ref[0].astype(jnp.float32) - ws_ref[...])
+    g_j = a_ref[...] * (ring_ref[0].astype(jnp.float32) - ws_ref[...])
     acc_ref[...] += coef_ref[j, 0] * g_j
 
     @pl.when(j == c - 1)
     def _apply():
-        w = ring_prev_ref[0].astype(jnp.float32)
+        prev(i).wait()
+        w = prev_buf[i % 2].astype(jnp.float32)
         if ef:
             w = w + res_ref[...]
         s = s_ref[...].astype(jnp.float32) if stateful else None
         w2, s2 = update_event(spec, w, s, acc_ref[...], lrs_ref[0, 0])
-        q = quantize(w2, ring_out.dtype)
-        ring_out[0] = q
+        q = quantize(w2, out_buf.dtype)
+        out_buf[i % 2] = q
+        write(i).start()
         if stateful:
             s_out[...] = s2
         if ef:
             res_out[...] = w2 - q.astype(jnp.float32)
+
+        @pl.when(i > 0)
+        def _():
+            write(i - 1).wait()
+
+        @pl.when(i == nb - 1)
+        def _():
+            write(i).wait()
 
 
 # ---------------------------------------------------------------------------
@@ -333,7 +374,9 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
     ``idx``: (2 + c,) int32 [prev, slot, ts_0 … ts_{c-1}].  ``a``/``wstar``:
     (Dp,) fp32 (zero-padded — padded a makes padded gradients zero, so the
     pad stays inert).  Combine mode only; requires K ≥ 2 (the engine falls
-    back to the streamed jnp twin for K = 1)."""
+    back to the streamed jnp twin for K = 1).  The ring is one operand,
+    aliased to the returned ring: the event writes its slot row into the
+    caller's buffer."""
     if not spec.kernel_supported:
         raise ValueError(f"{spec.optimizer!r} has no kernel path")
     if ring.shape[0] < 2:
@@ -351,7 +394,6 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
         raise ValueError(f"ring width {Dp} is not a multiple of the "
                          f"{row_block}x{LANES} tile; pad via padded_width()")
     rows = Dp // LANES
-    grid = (rows // row_block, c)
     stateful, ef = s is not None, res is not None
 
     with jax.named_scope("replay_ring.to_tiles"):
@@ -363,20 +405,17 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
     coef2 = coef.reshape(c, 1).astype(jnp.float32)
     lrs2 = lrs.reshape(c, 1).astype(jnp.float32)
 
+    nb = rows // row_block
     vec = pl.BlockSpec((c, 1), lambda i, j, idx: (0, 0))
     row = pl.BlockSpec((row_block, LANES), lambda i, j, idx: (i, 0))
     ring_ts = pl.BlockSpec((1, row_block, LANES),
                            lambda i, j, idx: (idx[2 + j], i, 0))
-    ring_prev = pl.BlockSpec((1, row_block, LANES),
-                             lambda i, j, idx: (idx[0], i, 0))
-    ring_out = pl.BlockSpec((1, row_block, LANES),
-                            lambda i, j, idx: (idx[1], i, 0))
 
-    operands = [coef2, lrs2, ringt, ringt, at, wt]
-    in_specs = [vec, vec, ring_ts, ring_prev, row, row]
+    operands = [coef2, lrs2, ringt, at, wt]
+    in_specs = [vec, vec, ring_ts, row, row]
     out_shape = [jax.ShapeDtypeStruct(ringt.shape, ringt.dtype)]
-    out_specs = [ring_out]
-    aliases = {4: 0}          # alias the prev-row ring operand (input idx 4)
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    aliases = {3: 0}          # scalar prefetch is input 0: the ring is 3
     if stateful:
         aliases[len(operands) + 1] = len(out_shape)
         operands.append(st)
@@ -390,17 +429,25 @@ def ring_apply_whatif(ring: jax.Array, s: Optional[jax.Array],
         out_shape.append(jax.ShapeDtypeStruct(rt.shape, rt.dtype))
         out_specs.append(row)
 
-    kernel = functools.partial(_whatif_kernel, spec=spec, c=c,
-                               stateful=stateful, ef=ef)
+    tile = (2, row_block, LANES)
+    kernel = functools.partial(_whatif_kernel, spec=spec, c=c, nb=nb,
+                               rb=row_block, stateful=stateful, ef=ef)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
+            num_scalar_prefetch=1, grid=(nb, c),
             in_specs=in_specs, out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((row_block, LANES), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((row_block, LANES), jnp.float32),
+                            pltpu.VMEM(tile, ringt.dtype),
+                            pltpu.VMEM(tile, ringt.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=out_shape,
         input_output_aliases=aliases,
+        # the row copies run ahead across grid steps: keep the grid in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="replay_ring_whatif",
     )(idx.astype(jnp.int32), *operands)
     return _from_tiles(out, K, Dp, stateful, ef)
+

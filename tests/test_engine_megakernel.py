@@ -28,7 +28,8 @@ import numpy as np
 import pytest
 
 from repro.config import RunConfig
-from repro.core import replay, schedule
+from repro import telemetry
+from repro.core import engine, replay, schedule
 from repro.core.engine import _materialize_batches, replay_batch
 from repro.core.trace import schedule_cached
 from repro.kernels import replay_ring
@@ -169,6 +170,33 @@ def test_event_whatif_megakernel_bitwise_vs_twin(optimizer, ring_dtype):
         _bw(sm, st)
     if res is not None:
         _bw(resm, rest)
+
+
+def test_event_whatif_megakernel_bitwise_across_row_blocks():
+    """Three row blocks, pulled rows repeated and the slot row among them:
+    the kernel's copies of the ring rows follow the grid."""
+    K, c, Dp = 4, 5, 3 * replay_ring.row_block_for(1 << 20) * 128
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    ring32 = jax.random.normal(ks[0], (K, Dp))
+    ring = ring32.astype(jnp.bfloat16)
+    res = ring32[2] - ring[2].astype(jnp.float32)
+    s = 0.1 * jax.random.normal(ks[1], (Dp,))
+    a = jnp.abs(jax.random.normal(ks[2], (Dp,))) + 0.5
+    wstar = jax.random.normal(ks[3], (Dp,))
+    coef = jnp.linspace(0.1, 0.5, c)
+    lrs = jnp.full((c,), 0.05)
+    ts = jnp.array([3, 3, 1, 2, 2], jnp.int32)
+    spec = UpdateSpec(optimizer="momentum")
+    mega = jax.jit(functools.partial(
+        replay_ring.ring_apply_whatif, spec=spec, interpret=True))
+    twin = jax.jit(functools.partial(
+        apply_event_ring_whatif, spec, ts=ts, prev=2, slot=3))
+    got = mega(ring, s, res, a, wstar, coef, lrs,
+               jnp.concatenate([jnp.array([2, 3], jnp.int32), ts]))
+    want = twin(ring=ring, s=s, res=res, a=a, wstar=wstar, coef=coef,
+                lrs=lrs)
+    for g, w in zip(got, want):
+        _bw(g, w)
 
 
 def test_event_bf16_master_chain_exact():
@@ -351,8 +379,8 @@ def _whatif_operands(d=600, seed=0):
     return a, wstar
 
 
-def _whatif_run(cfg, steps=24, impl=None):
-    a, wstar = _whatif_operands()
+def _whatif_run(cfg, steps=24, impl=None, d=600, **kw):
+    a, wstar = _whatif_operands(d)
     cfg = cfg if impl is None else cfg.replace(ring_impl=impl)
     trace = schedule(cfg, steps)
     init = {"w": jnp.zeros((a.shape[0],), jnp.float32)}
@@ -362,15 +390,59 @@ def _whatif_run(cfg, steps=24, impl=None):
         return replay(trace, cfg, grad_fn=grad_fn, init_params=init,
                       batch_fn=lambda l, i: np.zeros((1,), np.float32))
     return replay(trace, cfg, init_params=init,
-                  flat_grad=("quadratic", lambda pos: (a[pos], wstar[pos])))
+                  flat_grad=("quadratic", lambda pos: (a[pos], wstar[pos])),
+                  **kw)
 
 
-def test_whatif_pallas_bitwise_vs_fused():
-    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
-                    minibatch=1, base_lr=0.02, optimizer="momentum",
-                    seed=29)
+def _whatif_cfg(c=4, ring_dtype="fp32", **kw):
+    """softsync over 8 learners: c = 8 / n gradients per update (K = 4 at
+    c = 4, K = 15 at c = 1)."""
+    return RunConfig(protocol="softsync", n_softsync=8 // c, n_learners=8,
+                     minibatch=1, base_lr=0.02, optimizer="momentum",
+                     ring_dtype=ring_dtype, seed=29, **kw)
+
+
+@pytest.mark.parametrize("c", [1, 4])
+@pytest.mark.parametrize("ring_dtype", ["fp32", "bf16"])
+def test_whatif_pallas_bitwise_vs_fused(ring_dtype, c):
+    """The Pallas body, which carries the ring in the kernel's tiles and
+    updates it in place, is bitwise the fused twin's flat carry."""
+    cfg = _whatif_cfg(c, ring_dtype)
     _bw(_whatif_run(cfg, impl="pallas").params["w"],
         _whatif_run(cfg, impl="fused").params["w"])
+
+
+def test_whatif_pallas_bitwise_vs_fused_across_segments():
+    """Segments hand the tiled carry from one scan dispatch to the next,
+    and the eval reads the weights out of it in between; two row blocks
+    per ring row."""
+    cfg = _whatif_cfg(4, "bf16")
+
+    def run(impl):
+        return _whatif_run(cfg, impl=impl, d=40_000, eval_every=8,
+                           eval_fn=lambda p: {"w": np.asarray(p["w"])})
+    pallas, fused = run("pallas"), run("fused")
+    assert len(pallas.history) == len(fused.history) == 3
+    for hp, hf in zip(pallas.history, fused.history):
+        _bw(hp["w"], hf["w"])
+    _bw(pallas.params["w"], fused.params["w"])
+
+
+@pytest.mark.parametrize("protocol,tiled", [("softsync", True),
+                                            ("hardsync", False)])
+def test_whatif_tiled_carry_counter(protocol, tiled):
+    """A what-if scan built with the kernel's tiles counts once; the
+    K = 1 fallback (hardsync: no stale pull) runs the flat twin and does
+    not count."""
+    cfg = RunConfig(protocol=protocol, n_softsync=1, n_learners=4,
+                    minibatch=1, base_lr=0.02, optimizer="momentum",
+                    seed=29, ring_impl="pallas")
+    assert (schedule(cfg, 8).max_staleness + 1 >= 2) is tiled
+    engine._make_scan_fn.cache_clear()          # trace the scan afresh
+    before = telemetry.counters().get(engine.TILED_CARRY_BUILDS, 0)
+    _whatif_run(cfg, steps=8)
+    after = telemetry.counters().get(engine.TILED_CARRY_BUILDS, 0)
+    assert after - before == int(tiled)
 
 
 def test_whatif_matches_staged_stock():

@@ -16,10 +16,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import engine
+from repro.core.trace import PlacementPlan
 from repro.kernels import ps_update, replay_ring
 from repro.optim import UpdateSpec, flatten
 
@@ -29,14 +32,14 @@ C = 8                # gradients per update
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
         try:
-            topo = topologies.get_topology_desc(platform="tpu",
+            desc = topologies.get_topology_desc(platform="tpu",
                                                 topology_name="v5e:2x2")
         except Exception as e:           # no TPU compiler in this install
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -46,9 +49,14 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield desc
         finally:
             jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -99,33 +107,78 @@ def test_ps_apply_combine_compiles(one_chip, optimizer):
     _assert_kernel(fn, f32((D,)), state, f32((C, D)), f32((C,)), f32((C,)))
 
 
-def test_whatif_scan_names_its_kernel_and_relayout(one_chip, monkeypatch):
-    """The what-if replay scan, compiled whole: its kernel instruction is
-    named after the ``pallas_call`` and the ring's reshapes to the
-    kernel's tiling and back carry their scopes in ``op_name``, so a
-    profile can find them."""
+def _while_bodies(text):
+    """The text of each while-loop body computation of an HLO module."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    return [c for c in re.split(r"\n(?=\S)", text)
+            if c.split(" ", 1)[0].lstrip("%") in bodies]
+
+
+def _assert_ring_in_place(compiled, ring_shape, row_bytes):
+    """The compiled replay scan holds the what-if kernel, its loop makes
+    no copy of the whole ring, and it needs less scratch than one row."""
+    text = compiled.as_text()
+    assert re.search(r'%replay_ring_whatif[.\d]* = .*custom_call_target='
+                     r'"tpu_custom_call"', text)
+    bodies = _while_bodies(text)
+    assert bodies
+    ring = re.escape("bf16[" + ",".join(map(str, ring_shape)) + "]")
+    for body in bodies:
+        for line in body.splitlines():
+            assert not re.search(r"%copy[\w.\-]* = " + ring, line), line
+    assert compiled.memory_analysis().temp_size_in_bytes < row_bytes
+
+
+@pytest.mark.parametrize("K,c", [(6, 1), (3, 30)], ids=["K6-c1", "K3-c30"])
+def test_whatif_scan_updates_ring_in_place(topo, one_chip, monkeypatch, K, c):
+    """The what-if replay scan, compiled whole at D = 2^24: the kernel
+    updates the ring the loop carries in place.  The carry keeps the
+    kernel's (rows, 128) tiles and the kernel takes the ring as one
+    aliased operand, so no copy of the (K, rows, 128) ring is made per
+    event — on one chip and in the sharded (SPMD) body on four."""
     # off the chip the engine picks interpret mode; compile the chip's path
     monkeypatch.setattr(replay_ring, "default_interpret", lambda: False)
-    steps = 16
-    W = replay_ring.padded_width(D)
+    D, steps = 1 << 24, 16
+    spec = UpdateSpec(optimizer="momentum")
     layout = flatten.layout_of({"w": jax.ShapeDtypeStruct((D,),
                                                           jnp.float32)})
+    W = replay_ring.padded_width(D)
+    lanes = (W // 128, 128)
     # uncached: a scan traced for the chip stays out of the
     # cache the CPU tests use
     fn = engine._make_scan_fn.__wrapped__(
-        None, UpdateSpec(optimizer="momentum"), "combine", C, K, layout,
-        ring_impl="pallas", ring_dtype="bf16", whatif=True)
+        None, spec, "combine", c, K, layout, ring_impl="pallas",
+        ring_dtype="bf16", whatif=True)
     f32 = functools.partial(_sds, dtype=jnp.float32, sharding=one_chip)
     i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
-    carry = (_sds((K, W), jnp.bfloat16, one_chip), f32((W,)), f32((W,)))
-    xs = {"ts": i32((steps, C)), "prev": i32((steps,)),
-          "slot": i32((steps,)), "lrs": f32((steps, C))}
-    text = fn.lower(carry, xs, (f32((W,)), f32((W,)))).compile().as_text()
-    assert re.search(r'%replay_ring_whatif[.\d]* = .*custom_call_target='
-                     r'"tpu_custom_call"', text)
-    rows = W // 128
-    for shape, scope in ((f"bf16[{K},{rows},128]", "replay_ring.to_tiles"),
-                         (f"bf16[{K},{W}]", "replay_ring.from_tiles")):
-        assert re.search(re.escape(f"= {shape}") + r"\{.*op_name=\"[^\"]*"
-                         + re.escape(f"{engine.SCAN_SCOPE}/") + r"[^\"]*"
-                         + re.escape(f"/{scope}/"), text), scope
+    carry = (_sds((K,) + lanes, jnp.bfloat16, one_chip), f32(lanes),
+             f32(lanes))
+    xs = {"ts": i32((steps, c)), "prev": i32((steps,)),
+          "slot": i32((steps,)), "lrs": f32((steps, c))}
+    _assert_ring_in_place(fn.lower(carry, xs, (f32(lanes), f32(lanes)))
+                          .compile(), (K,) + lanes, 2 * W)
+
+    # the SPMD body: one PS shard per chip of the described 2x2 host
+    S = len(topo.devices)
+    mesh = Mesh(np.array(topo.devices).reshape(S, 1), ("ps", "learner"))
+    monkeypatch.setattr(engine.mesh_lib, "make_sim_mesh",
+                        lambda ps, learners: mesh)
+    Wl = engine._spmd_local_width(D, S, "pallas")
+    lanes = (Wl // 128, 128)
+    keys = ("lrs", "prev", "slot", "ts")
+    fn = engine._make_spmd_scan_fn.__wrapped__(
+        None, spec, "combine", c, K, layout,
+        PlacementPlan(shards=S, learners=1, c=c), keys, ring_impl="pallas",
+        ring_dtype="bf16", whatif=True)
+    ps, rep = NamedSharding(mesh, P("ps")), NamedSharding(mesh, P())
+    carry = (_sds((S, K) + lanes, jnp.bfloat16, ps),
+             _sds((S,) + lanes, jnp.float32, ps),
+             _sds((S,) + lanes, jnp.float32, ps))
+    xs = {"ts": _sds((steps, c, S), jnp.int32,
+                     NamedSharding(mesh, P(None, None, "ps"))),
+          "prev": _sds((steps,), jnp.int32, rep),
+          "slot": _sds((steps,), jnp.int32, rep),
+          "lrs": _sds((steps, c), jnp.float32, rep)}
+    aux = tuple(_sds((S,) + lanes, jnp.float32, ps) for _ in range(2))
+    _assert_ring_in_place(fn.lower(carry, xs, aux).compile(),
+                          (1, K) + lanes, 2 * Wl)
