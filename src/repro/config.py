@@ -45,6 +45,8 @@ BLOCK_MOE_DENSE_RESIDUAL = "moe_dense"   # attention + (dense MLP ∥ MoE)  [arc
 BLOCK_MAMBA = "mamba"                    # Mamba2 SSD block
 BLOCK_RWKV = "rwkv"                      # RWKV6 (Finch) block
 BLOCK_SHARED_ATTN = "shared_attn"        # weight-shared attention block [zamba2]
+BLOCK_MLA = "mla"                        # latent attention + dense MLP
+BLOCK_MLA_MOE = "mla_moe"                # latent attention + dropless MoE
 
 VALID_BLOCKS = {
     BLOCK_ATTN,
@@ -53,6 +55,8 @@ VALID_BLOCKS = {
     BLOCK_MAMBA,
     BLOCK_RWKV,
     BLOCK_SHARED_ATTN,
+    BLOCK_MLA,
+    BLOCK_MLA_MOE,
 }
 
 FAMILIES = ("dense", "moe", "ssm", "hybrid", "vlm", "audio")
@@ -135,6 +139,21 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    # --- fine-grained MoE (DeepSeek-V3; the dropless BLOCK_MLA_MOE path) ----
+    n_shared_experts: int = 0             # shared experts, each moe_d_ff wide
+    routed_scaling: float = 1.0           # gate multiplier after top-k norm
+    norm_topk_prob: bool = True           # normalise the k gates to sum 1
+    router_bias_speed: float = 0.0        # γ of the balancing-bias sign rule
+    seq_aux_loss: float = 0.0             # α of the sequence-wise balance loss
+    # experts this device holds (first index, count); count 0 = all of them.
+    # The router keeps its n_experts outputs; absent experts add nothing.
+    experts_held: Tuple[int, int] = (0, 0)
+    first_k_dense: int = 0                # leading dense layers before units
+    # --- latent attention (MLA, DeepSeek-V2/V3; kv_lora_rank 0 = none) ------
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (Mamba2) -------------------------------------------------------
     ssm_state: int = 0                    # N, state dim per head
     ssm_expand: int = 2                   # d_inner = expand * d_model
@@ -164,13 +183,34 @@ class ModelConfig:
         if self.n_units == 0:
             object.__setattr__(
                 self, "n_units",
-                max(1, self.n_layers // max(1, len(self.block_pattern))))
+                max(1, (self.n_layers - self.first_k_dense)
+                    // max(1, len(self.block_pattern))))
+        if self.block_pattern.count(BLOCK_MLA_MOE) > 1:
+            raise ValueError("one mla_moe block per unit: its routing "
+                             "counts and bias are stacked per unit")
+        first, count = self.experts_held
+        if first < 0 or count < 0 or first + count > self.n_experts:
+            raise ValueError(f"experts_held={self.experts_held} outside "
+                             f"the router's {self.n_experts} experts")
         if self.d_head == 0 and self.n_heads:
             object.__setattr__(self, "d_head", self.d_model // self.n_heads)
 
     @property
     def effective_layers(self) -> int:
-        return self.n_units * len(self.block_pattern)
+        return self.first_k_dense + self.n_units * len(self.block_pattern)
+
+    @property
+    def lead_block(self) -> str:
+        """Block type of the leading dense layers."""
+        return BLOCK_MLA if self.kv_lora_rank else BLOCK_ATTN
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] or self.n_experts
+
+    @property
+    def mla_qk_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def padded_vocab(self) -> int:
@@ -182,7 +222,8 @@ class ModelConfig:
     @property
     def has_attention(self) -> bool:
         return any(b in (BLOCK_ATTN, BLOCK_MOE, BLOCK_MOE_DENSE_RESIDUAL,
-                         BLOCK_SHARED_ATTN) for b in self.block_pattern)
+                         BLOCK_SHARED_ATTN, BLOCK_MLA, BLOCK_MLA_MOE)
+                   for b in self.block_pattern)
 
     @property
     def attention_free(self) -> bool:
@@ -219,8 +260,21 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += V * M                               # lm head
         per_unit = 0
+        mf = self.moe_d_ff or F
+        mla = (M * H * self.mla_qk_dim
+               + M * (self.kv_lora_rank + self.qk_rope_head_dim)
+               + self.kv_lora_rank
+               + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                          + self.v_head_dim)
+               + H * self.v_head_dim * M + 2 * M)   # + 2 block norms
         for b in self.block_pattern:
-            if b in (BLOCK_ATTN, BLOCK_MOE, BLOCK_MOE_DENSE_RESIDUAL,
+            if b == BLOCK_MLA:
+                per_unit += mla + 3 * M * F
+            elif b == BLOCK_MLA_MOE:
+                per_unit += (mla + M * self.n_experts
+                             + 3 * M * mf * (self.n_experts_held
+                                             + self.n_shared_experts))
+            elif b in (BLOCK_ATTN, BLOCK_MOE, BLOCK_MOE_DENSE_RESIDUAL,
                      BLOCK_SHARED_ATTN):
                 attn = M * (H * Dh) + 2 * M * (KV * Dh) + (H * Dh) * M
                 if self.qkv_bias:
@@ -265,6 +319,11 @@ class ModelConfig:
                     + 2 * M * F      # channel-mix squared-relu FFN
                     + 2 * M)
         total += per_unit * self.n_units
+        if self.first_k_dense:
+            lead = (mla + 3 * M * F if self.kv_lora_rank
+                    else M * (H * Dh) + 2 * M * (KV * Dh) + (H * Dh) * M
+                    + 2 * M + 3 * M * F)
+            total += self.first_k_dense * lead
         if BLOCK_SHARED_ATTN in self.block_pattern:
             attn = M * (H * Dh) + 2 * M * (KV * Dh) + (H * Dh) * M
             total += attn + 3 * M * F                    # shared attn + its MLP
@@ -279,6 +338,11 @@ class ModelConfig:
         for b in self.block_pattern:
             if b in (BLOCK_MOE, BLOCK_MOE_DENSE_RESIDUAL):
                 dead += (self.n_experts - self.top_k) * 3 * self.d_model * mf
+            elif b == BLOCK_MLA_MOE:
+                # of the held experts a token reaches k·held/E on average
+                held = self.n_experts_held
+                dead += ((held - self.top_k * held / self.n_experts)
+                         * 3 * self.d_model * mf)
         return int(self.param_count() - dead * self.n_units)
 
 

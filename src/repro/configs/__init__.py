@@ -25,6 +25,7 @@ ARCH_IDS: List[str] = [
     "qwen2_1_5b",
     "llama3_405b",
     "arctic_480b",
+    "moonlight_16b_a3b",
 ]
 
 # CLI aliases with dashes/dots

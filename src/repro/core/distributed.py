@@ -34,7 +34,7 @@ module owns only the round structure and per-event LR schedule.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,9 @@ import numpy as np
 from repro import optim
 from repro.config import ModelConfig, RunConfig
 from repro.core.lr_policies import hardsync_lr, softsync_lr
+
+# (params, opt, metrics) -> (params, opt, metrics), after each update event
+PostEvent = Callable[..., Tuple]
 
 
 # ---------------------------------------------------------------------------
@@ -103,6 +106,16 @@ def split_interleaved(x: jax.Array, n: int) -> jax.Array:
     return jnp.swapaxes(x.reshape((x.shape[0] // n, n) + x.shape[1:]), 0, 1)
 
 
+def fold_metrics(metrics: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """Metrics stacked on a leading axis (micro-batches, events) → one:
+    the counts under ``"counts"`` summed, the ``"maxima"`` maxed, every
+    other metric the last one's."""
+    rule = {"counts": lambda m: jnp.sum(m, axis=0),
+            "maxima": lambda m: jnp.max(m, axis=0)}
+    return {k: jax.tree.map(rule.get(k, lambda m: m[-1]), m)
+            for k, m in metrics.items()}
+
+
 def grad_with_accum(loss_fn: Callable, params, batch, num_microbatches: int,
                     sample_weights=None):
     """value_and_grad with gradient accumulation over micro-batches.
@@ -138,41 +151,51 @@ def grad_with_accum(loss_fn: Callable, params, batch, num_microbatches: int,
     (g_sum, loss_sum), metrics = jax.lax.scan(
         acc_body, (zeros, jnp.float32(0.0)), xs)
     grads = jax.tree.map(lambda g: g / num_microbatches, g_sum)
-    metrics = jax.tree.map(lambda m: m[-1], metrics)
-    return loss_sum / num_microbatches, metrics, grads
+    return loss_sum / num_microbatches, fold_metrics(metrics), grads
 
 
 # ---------------------------------------------------------------------------
 # train steps
 # ---------------------------------------------------------------------------
-def make_hardsync_step(run: RunConfig, loss_fn: Callable):
+def _no_post_event(params, opt, metrics):
+    return params, opt, metrics
+
+
+def make_hardsync_step(run: RunConfig, loss_fn: Callable,
+                       post_event: Optional[PostEvent] = None):
     """Standard data-parallel step: Δθ = mean over the global batch ≡ Eq. 3.
     LR follows the paper's hardsync scaling when lr_policy = sqrt_scale."""
     lr = hardsync_lr(run) if run.lr_policy == "sqrt_scale" else run.base_lr
     spec = optim.spec_from_run(run)
+    post_event = post_event or _no_post_event
 
     def step(params, opt, batch):
         loss, metrics, grads = grad_with_accum(
             loss_fn, params, batch, run.num_microbatches)
         params_new, opt_new = optim.apply_single(spec, params, opt, grads, lr)
-        return params_new, opt_new, metrics
+        return post_event(params_new, opt_new, metrics)
 
     return step
 
 
 def make_softsync_step(run: RunConfig, loss_fn: Callable,
-                       engine: str = "sequential"):
+                       engine: str = "sequential",
+                       post_event: Optional[PostEvent] = None):
     """Round-based n-softsync (DESIGN.md §2).  One call = one round = n
     update events.  The global batch is split into n logical learner groups
     along the batch axis, interleaved (group j holds samples j, j+n, …; see
-    :func:`split_interleaved`).
+    :func:`split_interleaved`).  ``post_event(params, opt, metrics)`` runs
+    after each event's optimizer update with that event's metrics (the
+    routing-bias rule, ``models.moe.routing_bias_update``); the round's
+    metrics fold its events' (:func:`fold_metrics`).
     """
     n = max(1, run.n_softsync)
     if run.protocol == "async":
         n = run.n_learners
+    post_event = post_event or _no_post_event
 
     if engine == "fused":
-        return _make_fused_softsync_step(run, loss_fn, n)
+        return _make_fused_softsync_step(run, loss_fn, n, post_event)
 
     lrs = jnp.asarray(round_event_lrs(run, n), jnp.float32)
     spec = optim.spec_from_run(run)
@@ -187,18 +210,20 @@ def make_softsync_step(run: RunConfig, loss_fn: Callable,
             loss, metrics, grads = grad_with_accum(
                 loss_fn, theta0, group_batch, run.num_microbatches)
             params, opt = optim.apply_single(spec, params, opt, grads, lr)
+            params, opt, metrics = post_event(params, opt, metrics)
             return (params, opt, loss_acc + loss), metrics
 
         (params, opt, loss_sum), metrics = jax.lax.scan(
             event, (params, opt, jnp.float32(0.0)), (grouped, lrs))
-        metrics = jax.tree.map(lambda m: m[-1], metrics)
+        metrics = fold_metrics(metrics)
         metrics["loss_round_mean"] = loss_sum / n
         return params, opt, metrics
 
     return step
 
 
-def _make_fused_softsync_step(run: RunConfig, loss_fn: Callable, n: int):
+def _make_fused_softsync_step(run: RunConfig, loss_fn: Callable, n: int,
+                              post_event: PostEvent):
     """Fused engine: one backward pass over a per-sample-weighted loss.
 
     The per-group θ-update coefficients c_i (fused_coefficients) become
@@ -228,13 +253,17 @@ def _make_fused_softsync_step(run: RunConfig, loss_fn: Callable, n: int):
                                                    fold)
         else:
             params, opt = optim.apply_single(spec, params, opt, grads, total)
-        return params, opt, metrics
+        # the round is one update: the per-event rule runs once, on the
+        # round's whole batch
+        return post_event(params, opt, metrics)
 
     return step
 
 
 def make_train_step(run: RunConfig, loss_fn: Callable,
-                    engine: str = "sequential"):
+                    engine: str = "sequential",
+                    post_event: Optional[PostEvent] = None):
     if run.protocol == "hardsync":
-        return make_hardsync_step(run, loss_fn)
-    return make_softsync_step(run, loss_fn, engine=engine)
+        return make_hardsync_step(run, loss_fn, post_event)
+    return make_softsync_step(run, loss_fn, engine=engine,
+                              post_event=post_event)

@@ -80,7 +80,8 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool, window: int = 0,
                     q_positions: Optional[jax.Array] = None,
                     k_positions: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B,Sq,H,Dh) k/v: (B,Sk,KV,Dh). Returns (B,Sq,H,Dh)."""
+    """q: (B,Sq,H,Dh) k: (B,Sk,KV,Dh) v: (B,Sk,KV,Dv). Returns
+    (B,Sq,H,Dv); the scores are scaled by 1/√Dh."""
     B, Sq, H, Dh = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -100,7 +101,7 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, Dh).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -112,12 +113,16 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       unroll: bool = False) -> jax.Array:
     """Blockwise attention: vmap over Q chunks, scan over KV chunks.
 
-    Equivalent to naive_attention for self-attention with aligned positions.
+    Equivalent to naive_attention for self-attention with aligned positions;
+    v may be narrower than q and k (latent attention: 192 against 128).
     ``unroll`` replaces the loops with trace-time python loops (roofline cost
-    probes — cost_analysis counts while bodies once).
+    probes — cost_analysis counts while bodies once).  Each KV step is
+    checkpointed, as a flash attention's backward is: the backward pass
+    recomputes the step's scores, and the scan saves only its (m, l, acc)
+    carries, not S×S scores per head.
     """
     B, Sq, H, Dh = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
@@ -132,7 +137,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     qb = qp.reshape(B, nq, q_chunk, KV, G, Dh).transpose(1, 0, 3, 4, 2, 5)
     kb = kp_.reshape(B, nk, kv_chunk, KV, Dh).transpose(1, 0, 3, 2, 4)
-    vb = vp.reshape(B, nk, kv_chunk, KV, Dh).transpose(1, 0, 3, 2, 4)
+    vb = vp.reshape(B, nk, kv_chunk, KV, Dv).transpose(1, 0, 3, 2, 4)
     # qb: (nq, B, KV, G, Qc, Dh); kb/vb: (nk, B, KV, Kc, Dh)
 
     q_pos_base = jnp.arange(nq) * q_chunk
@@ -168,9 +173,10 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 preferred_element_type=jnp.float32)
             return (m_new, l_new, acc_new), None
 
+        kv_step = jax.checkpoint(kv_step, prevent_cse=False)
         m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, q_chunk, Dh), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, q_chunk, Dv), jnp.float32)
         if unroll:
             # python loop (roofline probe / TPU-kernel model): skip blocks
             # that are fully masked — the Pallas kernel's pl.when skip (§Perf
@@ -195,7 +201,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          for i in range(nq)])
     else:
         out = jax.vmap(one_q_block)(qb, q_pos_base)  # (nq, B, KV, G, Qc, Dh)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * q_chunk, H, Dh)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * q_chunk, H, Dv)
     return out[:, :Sq].astype(q.dtype)
 
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro import config as C
 from repro.config import ModelConfig, RunConfig
 from repro.models import attention as attn
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
@@ -46,6 +47,16 @@ def init_block(block_type: str, key, cfg: ModelConfig, dtype) -> dict:
                 "norm2": init_rms_norm(M, dtype),
                 "mlp": init_swiglu(ks[1], M, cfg.d_ff, dtype),
                 "moe": moe_mod.init_moe(ks[2], cfg, dtype)}
+    if block_type == C.BLOCK_MLA:
+        return {"norm1": init_rms_norm(M, dtype),
+                "attn": mla_mod.init_mla(ks[0], cfg, dtype),
+                "norm2": init_rms_norm(M, dtype),
+                "mlp": init_swiglu(ks[1], M, cfg.d_ff, dtype)}
+    if block_type == C.BLOCK_MLA_MOE:
+        return {"norm1": init_rms_norm(M, dtype),
+                "attn": mla_mod.init_mla(ks[0], cfg, dtype),
+                "norm2": init_rms_norm(M, dtype),
+                "moe": moe_mod.init_moe_dropless(ks[1], cfg, dtype)}
     if block_type == C.BLOCK_MAMBA:
         return {"norm1": init_rms_norm(M, dtype),
                 "mamba": ssm_mod.init_mamba(ks[0], cfg, dtype)}
@@ -99,6 +110,15 @@ def block_forward(block_type: str, cfg: ModelConfig, run: RunConfig,
         xn = rms_norm(x, p["norm2"]["scale"], cfg.norm_eps)
         mo, aux = moe_mod.moe_forward(cfg, p["moe"], xn)
         return x + mo + swiglu(xn, p["mlp"]), aux
+    if block_type in (C.BLOCK_MLA, C.BLOCK_MLA_MOE):
+        x = x + mla_mod.mla_forward(cfg, run, p["attn"],
+                                    rms_norm(x, p["norm1"]["scale"],
+                                             cfg.norm_eps), positions)
+        xn = rms_norm(x, p["norm2"]["scale"], cfg.norm_eps)
+        if block_type == C.BLOCK_MLA:
+            return x + swiglu(xn, p["mlp"]), ZERO_AUX
+        mo, aux = moe_mod.moe_dropless_forward(cfg, p["moe"], xn)
+        return x + mo, aux
     if block_type == C.BLOCK_MAMBA:
         h = ssm_mod.mamba_forward(cfg, p["mamba"],
                                   rms_norm(x, p["norm1"]["scale"],
@@ -136,6 +156,8 @@ def init_block_cache(block_type: str, cfg: ModelConfig, batch: int,
     if block_type in (C.BLOCK_ATTN, C.BLOCK_MOE, C.BLOCK_MOE_DENSE_RESIDUAL,
                       C.BLOCK_SHARED_ATTN):
         return attn.init_kv_cache(cfg, batch, max_len, dtype)
+    if block_type in (C.BLOCK_MLA, C.BLOCK_MLA_MOE):
+        return {}
     if block_type == C.BLOCK_MAMBA:
         return ssm_mod.init_mamba_cache(cfg, batch, dtype)
     if block_type == C.BLOCK_RWKV:
@@ -171,6 +193,8 @@ def block_decode(block_type: str, cfg: ModelConfig, run: RunConfig,
         xn = rms_norm(x, p["norm2"]["scale"], cfg.norm_eps)
         mo, aux = moe_mod.moe_forward(cfg, p["moe"], xn)
         return x + mo + swiglu(xn, p["mlp"]), cache, aux
+    if block_type in (C.BLOCK_MLA, C.BLOCK_MLA_MOE):
+        mla_mod.mla_decode()
     if block_type == C.BLOCK_MAMBA:
         h, cache = ssm_mod.mamba_decode(
             cfg, p["mamba"],

@@ -47,6 +47,12 @@ def init_model(cfg: ModelConfig, key) -> dict:
         "units": units,
         "final_norm": init_rms_norm(cfg.d_model, dtype),
     }
+    if cfg.first_k_dense:
+        lead_keys = jax.random.split(jax.random.fold_in(k_units, 1),
+                                     cfg.first_k_dense)
+        params["lead"] = {f"layer_{i}": B.init_block(cfg.lead_block, k, cfg,
+                                                     dtype)
+                          for i, k in enumerate(lead_keys)}
     if not cfg.tie_embeddings:
         params["head"] = init_lm_head(k_head, cfg.d_model,
                                       cfg.padded_vocab, dtype)
@@ -85,42 +91,66 @@ def embed_inputs(cfg: ModelConfig, params: dict, batch: Dict[str, jax.Array]
 def model_forward(cfg: ModelConfig, run: RunConfig, params: dict,
                   batch: Dict[str, jax.Array]
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Full-sequence forward.  Returns (logits fp32 (B,S,V), aux)."""
+    """Full-sequence forward.  Returns (logits fp32 (B,S,V), aux).  The
+    leading dense layers (``first_k_dense``) run once each before the scan
+    over the units, under the same remat policy.  The counts the blocks
+    report (aux "counts", summed over a unit's blocks) come back stacked
+    per unit."""
     x = embed_inputs(cfg, params, batch)
     S = x.shape[1]
     positions = jnp.arange(S)
     shared = params.get("shared")
 
+    def constrain(x):
+        if run.residual_spec is None:
+            return x
+        return jax.lax.with_sharding_constraint(
+            x, jax.sharding.PartitionSpec(*run.residual_spec))
+
+    def lead_body(x, layer_params):
+        x, _ = B.block_forward(cfg.lead_block, cfg, run, layer_params,
+                               shared, constrain(x), positions)
+        return x
+
     def unit_body(carry, unit_params):
         x, lb, zl = carry
+        counts = {}
         for i, bt in enumerate(cfg.block_pattern):
-            if run.residual_spec is not None:
-                x = jax.lax.with_sharding_constraint(
-                    x, jax.sharding.PartitionSpec(*run.residual_spec))
             x, aux = B.block_forward(bt, cfg, run, unit_params[f"block_{i}"],
-                                     shared, x, positions)
+                                     shared, constrain(x), positions)
             lb = lb + aux["lb_loss"]
             zl = zl + aux["z_loss"]
-        return (x, lb, zl), None
+            for name, n in aux.get("counts", {}).items():
+                counts[name] = counts[name] + n if name in counts else n
+        return (x, lb, zl), counts
 
     if run.remat:
+        lead_body = jax.checkpoint(lead_body, prevent_cse=False)
         unit_body = jax.checkpoint(unit_body, prevent_cse=False)
 
+    for i in range(cfg.first_k_dense):
+        x = lead_body(x, params["lead"][f"layer_{i}"])
     carry = (x, jnp.float32(0.0), jnp.float32(0.0))
     if run.unroll:
         # python loop for the roofline cost probes (see RunConfig.unroll)
+        per_unit = []
         for u in range(cfg.n_units):
             unit_params = jax.tree.map(lambda a: a[u], params["units"])
-            carry, _ = unit_body(carry, unit_params)
+            carry, st = unit_body(carry, unit_params)
+            per_unit.append(st)
         (x, lb_loss, z_loss) = carry
+        counts = jax.tree.map(lambda *a: jnp.stack(a), *per_unit)
     else:
-        (x, lb_loss, z_loss), _ = jax.lax.scan(unit_body, carry,
-                                               params["units"])
+        (x, lb_loss, z_loss), counts = jax.lax.scan(unit_body, carry,
+                                                    params["units"])
 
     x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
     head_w = (params["embed"].T if cfg.tie_embeddings else params["head"])
     logits = _mask_padded(cfg, lm_head(x, head_w))
-    return logits, {"lb_loss": lb_loss, "z_loss": z_loss}
+    aux = {"lb_loss": lb_loss, "z_loss": z_loss}
+    if counts:
+        aux["counts"] = counts
+    return logits, aux
 
 
 def _mask_padded(cfg: ModelConfig, logits: jax.Array) -> jax.Array:
@@ -173,6 +203,9 @@ def model_decode_step(cfg: ModelConfig, run: RunConfig, params: dict,
     """One decode step.  token: (B, 1) int32 (or (B,1,M) embeds for audio —
     not used: encoder-only models have no decode).  position: () int32.
     Returns (logits (B, 1, V) fp32, new caches)."""
+    if cfg.first_k_dense:
+        raise NotImplementedError("decode through leading dense layers is "
+                                  "not built (training and prefill only)")
     x = embed(token, params["embed"])
     shared = params.get("shared")
 

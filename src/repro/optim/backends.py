@@ -53,7 +53,7 @@ def _combine(grads: Sequence, coef) -> object:
 # ---------------------------------------------------------------------------
 # pytree event application (reference + jit backends)
 # ---------------------------------------------------------------------------
-def _adamw_event(spec: UpdateSpec, params, state, g32, lr):
+def _adamw_event(spec: UpdateSpec, w32, state, g32, lr):
     b1, b2, eps = spec.beta1, spec.beta2, spec.eps
     cnt = state["count"] + 1
     mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state["mu"], g32)
@@ -61,39 +61,54 @@ def _adamw_event(spec: UpdateSpec, params, state, g32, lr):
                       state["nu"], g32)
     c1 = 1 - b1 ** cnt.astype(jnp.float32)
     c2 = 1 - b2 ** cnt.astype(jnp.float32)
-    new_p = jax.tree.map(
-        lambda p, m, n: (p.astype(jnp.float32)
-                         - lr * ((m / c1) / (jnp.sqrt(n / c2) + eps)
-                                 + spec.weight_decay * p.astype(jnp.float32))
-                         ).astype(p.dtype),
-        params, mu, nu)
-    return new_p, {"mu": mu, "nu": nu, "count": cnt}
+    new_w = jax.tree.map(
+        lambda w, m, n: w - lr * ((m / c1) / (jnp.sqrt(n / c2) + eps)
+                                  + spec.weight_decay * w),
+        w32, mu, nu)
+    return new_w, {"mu": mu, "nu": nu, "count": cnt}
+
+
+def _weights(params, state):
+    """The float32 weights an event updates: the master where the state
+    keeps one, else the stored weights widened."""
+    return state["master"] if "master" in state else _f32(params)
+
+
+def _stored(params, state, new_w, new_state):
+    """The stored weights (``new_w`` rounded to each leaf's dtype) and the
+    state, with the master set to ``new_w`` where it is kept."""
+    new_p = jax.tree.map(lambda p, w: w.astype(p.dtype), params, new_w)
+    if "master" in state:
+        new_state = dict(new_state, master=new_w)
+    return new_p, new_state
 
 
 def apply_single(spec: UpdateSpec, params, state, grad, lr):
     """ONE optimizer event with gradient ``grad`` (pytree) and lr ``lr``.
 
     Pure and jit-friendly (``lr`` may be traced) — this is what the
-    distributed engines inline into their step functions."""
+    distributed engines inline into their step functions.  With a float32
+    master in ``state`` (``init_state``) the event updates the master."""
     g32 = _f32(grad)
+    w32 = _weights(params, state)
     if spec.optimizer == "adamw":
-        return _adamw_event(spec, params, state, g32, lr)
-    flat_p, treedef = jax.tree_util.tree_flatten(params)
+        new_w, new_state = _adamw_event(spec, w32, state, g32, lr)
+        return _stored(params, state, new_w, new_state)
+    flat_w, treedef = jax.tree_util.tree_flatten(w32)
     flat_g = jax.tree_util.tree_leaves(g32)
     if spec.optimizer == "sgd":
-        new_p = [update_event(spec, p.astype(jnp.float32), None, g, lr)[0]
-                 .astype(p.dtype) for p, g in zip(flat_p, flat_g)]
-        return jax.tree_util.tree_unflatten(treedef, new_p), state
+        new_w = [update_event(spec, w, None, g, lr)[0]
+                 for w, g in zip(flat_w, flat_g)]
+        return _stored(params, state,
+                       jax.tree_util.tree_unflatten(treedef, new_w), {})
     key = spec.state_keys[0]
     flat_s = jax.tree_util.tree_leaves(state[key])
-    res = [update_event(spec, p.astype(jnp.float32), s.astype(jnp.float32),
-                        g, lr)
-           for p, s, g in zip(flat_p, flat_s, flat_g)]
-    new_p = jax.tree_util.tree_unflatten(
-        treedef, [r[0].astype(p.dtype) for r, p in zip(res, flat_p)])
+    res = [update_event(spec, w, s.astype(jnp.float32), g, lr)
+           for w, s, g in zip(flat_w, flat_s, flat_g)]
+    new_w = jax.tree_util.tree_unflatten(treedef, [r[0] for r in res])
     new_s = jax.tree_util.tree_unflatten(
         treedef, [r[1].astype(s.dtype) for r, s in zip(res, flat_s)])
-    return new_p, {key: new_s}
+    return _stored(params, state, new_w, {key: new_s})
 
 
 def apply_update_tree(spec: UpdateSpec, params, state, grads: Sequence,
@@ -131,11 +146,10 @@ def apply_round_folded(spec: UpdateSpec, params, state, ghat,
     v = state["velocity"]
     new_v = jax.tree.map(lambda vv, g: fold.v_decay * vv + fold.v_gain * g,
                          v, g32)
-    new_p = jax.tree.map(
-        lambda p, g, vv: (p.astype(jnp.float32) - total * g
-                          - fold.v0_coef * vv).astype(p.dtype),
-        params, g32, v)
-    return new_p, {"velocity": new_v}
+    new_w = jax.tree.map(
+        lambda w, g, vv: w - total * g - fold.v0_coef * vv,
+        _weights(params, state), g32, v)
+    return _stored(params, state, new_w, {"velocity": new_v})
 
 
 def apply_event_flat(spec: UpdateSpec, w, s, g, coef, lrs,
@@ -307,23 +321,28 @@ def combine_spmd(g, coef, axis_name: str):
 def apply_update_flat(spec: UpdateSpec, params, state, grads: Sequence,
                       coef, lrs, mode: str = "combine",
                       interpret: bool = True):
-    """Flatten → single ``ps_update`` pallas_call → unflatten."""
+    """Flatten → single ``ps_update`` pallas_call → unflatten.  With a
+    float32 master in ``state`` the kernel updates the master's flat
+    buffer, and the stored weights are its leaves rounded."""
     from repro.kernels import ps_update as _psu   # lazy: breaks import cycle
 
-    p_layout = flatten.layout_of(params)
-    w = flatten.tree_to_flat(params)
+    w_tree = _weights(params, state)
+    w_layout = flatten.layout_of(w_tree)
+    w = flatten.tree_to_flat(w_tree)
     g = flatten.stack_grads_flat(grads)
     if spec.optimizer == "sgd":
         w2, _ = _psu.ps_apply(w, None, g, coef, lrs, spec=spec, mode=mode,
                               interpret=interpret)
-        return flatten.flat_to_tree(w2, p_layout), state
-    key = spec.state_keys[0]
-    s_layout = flatten.layout_of(state[key])
-    s = flatten.tree_to_flat(state[key])
-    w2, s2 = _psu.ps_apply(w, s, g, coef, lrs, spec=spec, mode=mode,
-                           interpret=interpret)
-    return (flatten.flat_to_tree(w2, p_layout),
-            {key: flatten.flat_to_tree(s2, s_layout)})
+        new_state = {}
+    else:
+        key = spec.state_keys[0]
+        s_layout = flatten.layout_of(state[key])
+        s = flatten.tree_to_flat(state[key])
+        w2, s2 = _psu.ps_apply(w, s, g, coef, lrs, spec=spec, mode=mode,
+                               interpret=interpret)
+        new_state = {key: flatten.flat_to_tree(s2, s_layout)}
+    return _stored(params, state, flatten.flat_to_tree(w2, w_layout),
+                   new_state)
 
 
 # ---------------------------------------------------------------------------

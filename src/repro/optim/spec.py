@@ -78,19 +78,36 @@ def spec_from_run(run) -> UpdateSpec:
                       weight_decay=run.weight_decay)
 
 
+def needs_master(params) -> bool:
+    """True when some weight is stored below float32 (bf16): its update
+    then runs on a float32 master copy (``init_state``'s "master")."""
+    return any(jnp.dtype(p.dtype) != jnp.float32
+               for p in jax.tree.leaves(params))
+
+
 def init_state(spec: UpdateSpec, params) -> dict:
     """Optimizer state pytree.  Accumulators are fp32 regardless of the
-    parameter dtype (bf16 params train with fp32 velocity/variance)."""
+    parameter dtype (bf16 params train with fp32 velocity/variance).  Where
+    a weight is stored below float32 the state also holds "master", a
+    float32 copy of every weight: each event updates the master, and the
+    stored weights are the master rounded to their dtype, so an update
+    smaller than half a bf16 step accumulates instead of being lost."""
     f32 = lambda p: jnp.zeros(jnp.shape(p), jnp.float32)
     if spec.optimizer == "momentum":
-        return {"velocity": jax.tree.map(f32, params)}
-    if spec.optimizer == "adagrad":
-        return {"accum": jax.tree.map(f32, params)}
-    if spec.optimizer == "adamw":
-        return {"mu": jax.tree.map(f32, params),
-                "nu": jax.tree.map(f32, params),
-                "count": jnp.zeros((), jnp.int32)}
-    return {}
+        state = {"velocity": jax.tree.map(f32, params)}
+    elif spec.optimizer == "adagrad":
+        state = {"accum": jax.tree.map(f32, params)}
+    elif spec.optimizer == "adamw":
+        state = {"mu": jax.tree.map(f32, params),
+                 "nu": jax.tree.map(f32, params),
+                 "count": jnp.zeros((), jnp.int32)}
+    else:
+        state = {}
+    if needs_master(params):
+        # a copy also of float32 leaves: the step may donate both trees
+        state["master"] = jax.tree.map(
+            lambda p: jnp.array(p, jnp.float32, copy=True), params)
+    return state
 
 
 # ---------------------------------------------------------------------------

@@ -15,10 +15,12 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.config import ModelConfig, RunConfig
 from repro.core.distributed import make_train_step
 from repro.data.pipeline import PrefetchIterator, make_batch_fn
 from repro.models import init_model, model_loss
+from repro.models import moe
 from repro.optim import init_state, spec_from_run
 
 
@@ -61,15 +63,21 @@ def train(cfg: ModelConfig, run: RunConfig, *, steps: int,
         if log:
             log(f"warm-start: {warmstart_steps} hardsync rounds done")
 
-    step_fn = jax.jit(make_train_step(run, loss_fn, engine=engine))
+    step_fn = jax.jit(make_train_step(
+        run, loss_fn, engine=engine,
+        post_event=moe.routing_bias_update(cfg)))
     batch_fn = make_batch_fn(cfg, batch, seq, seed=run.seed)
     it = iter(PrefetchIterator(batch_fn, steps))
 
     history: List[Dict] = []
+    counts = None         # the rounds' counter metrics, summed on the device
     t0 = time.perf_counter()
     for step, b in enumerate(it):
         params, opt, metrics = step_fn(params, opt, b)
+        counts = telemetry.add_step_counts(counts, metrics)
         if eval_every and (step + 1) % eval_every == 0:
+            telemetry.record_step_counts(counts)
+            counts = None
             entry = {"step": step + 1,
                      "loss": float(metrics["loss"]),
                      "ce": float(metrics["ce"])}
@@ -83,4 +91,5 @@ def train(cfg: ModelConfig, run: RunConfig, *, steps: int,
     # device has finished the last one
     params, opt = jax.block_until_ready((params, opt))
     wall = time.perf_counter() - t0
+    telemetry.record_step_counts(counts)
     return TrainResult(params, opt, history, steps, wall)

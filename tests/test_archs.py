@@ -70,6 +70,11 @@ def test_smoke_decode_step(arch):
     caches = init_caches(cfg, B, 32)
     tok = jnp.zeros((B, 1), jnp.int32)
     step = jax.jit(lambda p, t, pos, c: serve_step(cfg, RUN, p, t, pos, c))
+    if cfg.kv_lora_rank:
+        # latent attention trains and prefills only: decode says so
+        with pytest.raises(NotImplementedError):
+            step(params, tok, jnp.int32(0), caches)
+        return
     nxt, caches = step(params, tok, jnp.int32(0), caches)
     assert nxt.shape == (B, 1)
     nxt2, _ = step(params, nxt, jnp.int32(1), caches)

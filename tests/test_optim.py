@@ -95,12 +95,17 @@ def test_bf16_params_fp32_accumulators(optimizer, backend):
     params = _mixed_tree(jax.random.PRNGKey(2), dtype=jnp.bfloat16)
     state = init_state(spec, params)
     assert all(l.dtype == jnp.float32 for l in jax.tree.leaves(state))
+    assert "master" in state
     grads = _grads(jax.random.PRNGKey(3), params, 3)
     coef = jnp.asarray([0.5, 0.3, 0.2])
     lrs = jnp.full((3,), 0.1)
+    before = optim.backends.pallas_dispatches
     p, s = apply_update(spec, params, state, grads, coef, lrs,
                         backend=backend)
     p, s = apply_update(spec, p, s, grads, coef, lrs, backend=backend)
+    # the pallas case runs the fused kernel on the master's flat buffer
+    assert optim.backends.pallas_dispatches == before + (
+        2 if backend == "pallas" else 0)
     # dtypes preserved through the flat-buffer round trip
     assert all(l.dtype == jnp.bfloat16 for l in jax.tree.leaves(p))
     assert all(l.dtype == jnp.float32 for l in jax.tree.leaves(s))
@@ -301,3 +306,37 @@ def test_simulator_sgd_hot_path_dispatches_pallas():
                    init_params=jnp.zeros((6, 2)), batch_fn=batch_fn)
     assert res.updates == 10
     assert optim.backends.pallas_dispatches >= before + 10
+
+
+@pytest.mark.parametrize("apply", ["single", "pallas"])
+def test_fp32_master_keeps_sub_ulp_updates(apply):
+    """bf16 weights with a float32 master: an update under half a bf16 step
+    (at 1.0 the step is 2^-7) accumulates over events in the master, and
+    the stored weights are the master rounded; without the master each
+    rounding would lose it and the weights would never move.  The same
+    through ``apply_single`` and through the fused Pallas kernel."""
+    from repro.optim import apply_single
+    spec = UpdateSpec(optimizer="sgd")
+    params = {"w": jnp.ones((4,), jnp.bfloat16)}
+    state = init_state(spec, params)
+    assert state["master"]["w"].dtype == jnp.float32
+    g = {"w": jnp.full((4,), -1.0, jnp.float32)}
+    lr = 2.0 ** -10                      # an eighth of a bf16 step
+    if apply == "single":
+        step = jax.jit(lambda p, s: apply_single(spec, p, s, g, lr))
+    else:
+        def step(p, s):
+            return apply_update(spec, p, s, [g], [1.0], [lr],
+                                backend="pallas")
+    p, s = params, state
+    for _ in range(12):
+        p, s = step(p, s)
+    np.testing.assert_array_equal(s["master"]["w"], 1.0 + 12 * lr)
+    np.testing.assert_array_equal(
+        p["w"], jnp.asarray(1.0 + 12 * lr, jnp.float32).astype(jnp.bfloat16))
+    assert float(p["w"][0]) > 1.0
+    # the same events on the stored weights alone: every one is rounded away
+    bare, _ = params, None
+    for _ in range(12):
+        bare, _ = apply_single(spec, bare, {}, g, lr)
+    np.testing.assert_array_equal(bare["w"], params["w"])

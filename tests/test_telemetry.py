@@ -139,3 +139,94 @@ def test_build_counters_read_through_telemetry():
         replay_ring.no_such_counter
     with pytest.raises(AttributeError):
         optim.backends.no_such_counter
+
+
+# ---------------------------------------------------------------------------
+# the dropless MoE and latent attention (Moonlight-16B-A3B's SMOKE size)
+# ---------------------------------------------------------------------------
+def _moonlight(held=(1, 2)):
+    import dataclasses
+    from repro.configs import get_smoke
+    return dataclasses.replace(get_smoke("moonlight_16b_a3b"),
+                               experts_held=held)
+
+
+_MOE_RUN = RunConfig(protocol="softsync", n_softsync=2, n_learners=4,
+                     minibatch=1, base_lr=0.01,
+                     lr_policy="staleness_inverse", optimizer="momentum",
+                     attn_q_chunk=16, attn_kv_chunk=16, num_microbatches=2)
+
+
+def test_moe_counters_add_up_to_the_slots_routed():
+    """Over the rounds of ``train``, ``moe.routed_slots`` is every slot
+    routed (tokens × k × MoE layers), ``moe.held_slots`` the share that
+    reached a held expert (the sum of the rounds' held loads), and
+    ``moe.held_slots_max`` the largest held expert's slots in one event."""
+    from repro.core import init_opt_state, make_train_step
+    from repro.data.pipeline import make_batch_fn
+    from repro.models import init_model, model_loss, moe
+    from repro.train.loop import train
+    cfg, run = _moonlight(), _MOE_RUN
+    before = telemetry.counters()
+    train(cfg, run, steps=2, batch=4, seq=16)
+    routed = _delta(before, moe.ROUTED_SLOTS)
+    assert routed == 2 * 4 * 16 * cfg.top_k * cfg.n_units
+
+    # the same two rounds by hand: the held loads of each event
+    params = init_model(cfg, jax.random.PRNGKey(run.seed))
+    step = jax.jit(make_train_step(
+        run, lambda p, b, sample_weights=None: model_loss(cfg, run, p, b),
+        post_event=moe.routing_bias_update(cfg)))
+    opt = init_opt_state(run, params)
+    feed = make_batch_fn(cfg, 4, 16, seed=run.seed)
+    # one event's loads: the loss's own metrics over that event's rows,
+    # summed over its micro-batches
+    loads = jax.jit(lambda p, b: sum(
+        model_loss(cfg, run, p, jax.tree.map(lambda x: x[i::2], b))[1][
+            "counts"][moe.ROUTED_SLOTS] for i in range(2)))
+    held, held_max = 0, 0
+    for r in range(2):
+        b = jax.tree.map(jnp.asarray, feed(r))
+        for j in range(2):                          # the round's events
+            ev = jax.tree.map(lambda x: x[j::2], b)
+            load = np.asarray(loads(params, ev))[:, 1:3]
+            held += int(load.sum())
+            held_max = max(held_max, int(load.max()))
+        params, opt, m = step(params, opt, b)
+    assert _delta(before, moe.HELD_SLOTS) == held
+    assert 0 < held < routed
+    assert telemetry.counters()[moe.HELD_SLOTS_MAX] >= held_max
+
+
+def test_dropless_builds_count_at_trace_time():
+    from repro.models import init_model, model_forward, moe
+    cfg = _moonlight()
+    params = init_model(cfg, jax.random.PRNGKey(0))
+    fwd = jax.jit(lambda p, t: model_forward(cfg, _MOE_RUN, p,
+                                             {"tokens": t})[0])
+    before = telemetry.counters()
+    tok = jnp.zeros((2, 16), jnp.int32)
+    fwd(params, tok)
+    fwd(params, tok + 1)                 # same shapes: no new build
+    assert _delta(before, moe.DROPLESS_BUILDS) == 1
+    fwd(params, jnp.zeros((2, 32), jnp.int32))
+    assert _delta(before, moe.DROPLESS_BUILDS) == 2
+
+
+def test_compiled_step_carries_the_mla_and_moe_scopes():
+    """The scopes land in the compiled step's op metadata, where a
+    profiler's viewer shows them."""
+    from repro.core import init_opt_state, make_train_step
+    from repro.data.pipeline import make_batch_fn
+    from repro.models import init_model, model_loss, moe
+    cfg, run = _moonlight(), _MOE_RUN
+    params = init_model(cfg, jax.random.PRNGKey(0))
+    step = jax.jit(make_train_step(
+        run, lambda p, b, sample_weights=None: model_loss(cfg, run, p, b),
+        post_event=moe.routing_bias_update(cfg)))
+    batch = jax.tree.map(jnp.asarray, make_batch_fn(cfg, 4, 16)(0))
+    text = step.lower(params, init_opt_state(run, params),
+                      batch).compile().as_text()
+    for scope in ("mla.project", "mla.attend", "moe.route", "moe.dispatch",
+                  "moe.experts", "moe.shared", "moe.combine"):
+        assert scope + "/" in text, scope
