@@ -3,9 +3,10 @@
 Three implementations, selected by ``RunConfig.attn_impl``:
 
 * ``naive``   — materializes the full score matrix; the test oracle.
-* ``chunked`` — online-softmax over KV chunks (vmapped over Q chunks);
-                memory O(Sq·Kc); the default for dry-run lowering on CPU and
-                the pure-XLA production fallback.
+* ``chunked`` — online-softmax over the live KV chunks of each Q chunk
+                (blocks the mask removes are skipped); memory O(Qc·Kc);
+                the default for dry-run lowering on CPU and the pure-XLA
+                production fallback.
 * ``pallas``  — the TPU flash-attention kernel in ``repro.kernels``.
 
 The decode path (single new token against a cache) is a plain einsum — the
@@ -22,10 +23,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.config import ModelConfig, RunConfig
 from repro.models.layers import apply_rope, rms_norm
 
 NEG_INF = -1e30
+
+# counters (repro.telemetry), at trace time: the (Q chunk, KV chunk) blocks
+# chunked attention computes, and those it skips as fully masked
+KV_BLOCKS = "attention.kv_blocks"
+KV_BLOCKS_SKIPPED = "attention.kv_blocks_skipped"
 
 
 # ---------------------------------------------------------------------------
@@ -107,19 +114,42 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # Chunked online-softmax implementation
 # ---------------------------------------------------------------------------
+def kv_block_ranges(Sq: int, Sk: int, q_chunk: int, kv_chunk: int, *,
+                    causal: bool, window: int = 0) -> list:
+    """The KV chunks each Q chunk must visit: ``[lo, hi)`` per Q chunk.
+
+    Positions are ``arange`` (self-attention), so a block whose every entry
+    the causal or window mask removes is known at trace time: KV chunks past
+    the Q chunk's last row (causal) or before its first row's window are
+    never computed."""
+    q_chunk, kv_chunk = min(q_chunk, Sq), min(kv_chunk, Sk)
+    nq, nk = -(-Sq // q_chunk), -(-Sk // kv_chunk)
+    ranges = []
+    for i in range(nq):
+        q0 = i * q_chunk
+        hi = min(nk, (q0 + q_chunk - 1) // kv_chunk + 1) if causal else nk
+        lo = max(0, (q0 - window + 1) // kv_chunk) if window > 0 else 0
+        ranges.append((lo, hi))
+    return ranges
+
+
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool, window: int = 0,
                       q_chunk: int = 1024, kv_chunk: int = 1024,
                       unroll: bool = False) -> jax.Array:
-    """Blockwise attention: vmap over Q chunks, scan over KV chunks.
+    """Blockwise attention: per Q chunk, a scan over the KV chunks that
+    the mask leaves live (:func:`kv_block_ranges`).
 
     Equivalent to naive_attention for self-attention with aligned positions;
     v may be narrower than q and k (latent attention: 192 against 128).
-    ``unroll`` replaces the loops with trace-time python loops (roofline cost
-    probes — cost_analysis counts while bodies once).  Each KV step is
-    checkpointed, as a flash attention's backward is: the backward pass
-    recomputes the step's scores, and the scan saves only its (m, l, acc)
-    carries, not S×S scores per head.
+    Causal attention computes the blocks on and below the diagonal only
+    (10 of 16 at four chunks a side), as a flash kernel's skip does; the
+    blocks computed and skipped are counted at trace time.  ``unroll``
+    replaces the scans with trace-time python loops over the same blocks
+    (roofline cost probes — cost_analysis counts while bodies once).  Each
+    KV step is checkpointed, as a flash attention's backward is: the
+    backward pass recomputes the step's scores, and the scan saves only its
+    (m, l, acc) carries, not S×S scores per head.
     """
     B, Sq, H, Dh = q.shape
     Sk, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
@@ -139,12 +169,16 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kb = kp_.reshape(B, nk, kv_chunk, KV, Dh).transpose(1, 0, 3, 2, 4)
     vb = vp.reshape(B, nk, kv_chunk, KV, Dv).transpose(1, 0, 3, 2, 4)
     # qb: (nq, B, KV, G, Qc, Dh); kb/vb: (nk, B, KV, Kc, Dh)
-
-    q_pos_base = jnp.arange(nq) * q_chunk
     k_pos_base = jnp.arange(nk) * kv_chunk
 
-    def one_q_block(qc, q0):
-        # qc: (B, KV, G, Qc, Dh)
+    ranges = kv_block_ranges(Sq, Sk, q_chunk, kv_chunk, causal=causal,
+                             window=window)
+    computed = sum(hi - lo for lo, hi in ranges)
+    telemetry.count(KV_BLOCKS, computed)
+    telemetry.count(KV_BLOCKS_SKIPPED, nq * nk - computed)
+
+    def one_q_block(qc, q0, lo, hi):
+        # qc: (B, KV, G, Qc, Dh); q0, lo, hi: trace-time ints
         qpos = q0 + jnp.arange(q_chunk)                        # (Qc,)
 
         def kv_step(carry, inp):
@@ -174,33 +208,21 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return (m_new, l_new, acc_new), None
 
         kv_step = jax.checkpoint(kv_step, prevent_cse=False)
-        m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, q_chunk, Dv), jnp.float32)
+        carry = (jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32),
+                 jnp.zeros((B, KV, G, q_chunk), jnp.float32),
+                 jnp.zeros((B, KV, G, q_chunk, Dv), jnp.float32))
         if unroll:
-            # python loop (roofline probe / TPU-kernel model): skip blocks
-            # that are fully masked — the Pallas kernel's pl.when skip (§Perf
-            # A2).  q0/q_end are trace-time ints here.
-            carry = (m0, l0, a0)
-            q0i = int(q0)
-            for j in range(nk):
-                k0 = j * kv_chunk
-                if causal and k0 > q0i + q_chunk - 1:
-                    continue                      # strictly-above-diagonal
-                if window > 0 and (k0 + kv_chunk - 1) <= q0i - window:
-                    continue                      # beyond the window
+            for j in range(lo, hi):
                 carry, _ = kv_step(carry, (kb[j], vb[j], k_pos_base[j]))
-            m, l, acc = carry
         else:
-            (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
-                                          (kb, vb, k_pos_base))
+            carry, _ = jax.lax.scan(kv_step, carry, (
+                kb[lo:hi], vb[lo:hi], k_pos_base[lo:hi]))
+        _, l, acc = carry
         return acc / jnp.maximum(l, 1e-30)[..., None]
 
-    if unroll:
-        out = jnp.stack([one_q_block(qb[i], i * q_chunk)
-                         for i in range(nq)])
-    else:
-        out = jax.vmap(one_q_block)(qb, q_pos_base)  # (nq, B, KV, G, Qc, Dh)
+    out = jnp.stack([one_q_block(qb[i], i * q_chunk, lo, hi)
+                     for i, (lo, hi) in enumerate(ranges)])
+    # out: (nq, B, KV, G, Qc, Dv)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(B, nq * q_chunk, H, Dv)
     return out[:, :Sq].astype(q.dtype)
 

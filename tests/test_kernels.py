@@ -180,17 +180,46 @@ def test_wkv_chunked_probe_matches_recurrent():
 # ---------------------------------------------------------------------------
 # model-level: chunked attention == naive attention
 # ---------------------------------------------------------------------------
-def test_chunked_attention_equals_naive():
+def _qkv(S, key=0):
+    ks = jax.random.split(jax.random.PRNGKey(key), 3)
+    return (jax.random.normal(ks[0], (2, S, 8, 16)),
+            jax.random.normal(ks[1], (2, S, 2, 16)),
+            jax.random.normal(ks[2], (2, S, 2, 16)))
+
+
+@pytest.mark.parametrize("causal,window,S,q_chunk,kv_chunk,unroll", [
+    (True, 0, 80, 32, 32, False),      # Sq not a multiple of the chunk
+    (True, 0, 80, 32, 32, True),
+    (True, 24, 80, 32, 32, False),     # window: leading blocks skipped
+    (True, 24, 80, 32, 32, True),
+    (True, 0, 80, 32, 16, False),      # Q and KV chunks of unequal width
+    (True, 40, 80, 16, 32, False),
+    (False, 0, 80, 32, 32, False),     # non-causal: every block computed
+    (False, 0, 80, 32, 32, True),
+])
+def test_chunked_attention_equals_naive(causal, window, S, q_chunk,
+                                        kv_chunk, unroll):
     from repro.models.attention import chunked_attention, naive_attention
-    key = jax.random.PRNGKey(0)
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (2, 80, 8, 16))
-    k = jax.random.normal(ks[1], (2, 80, 2, 16))
-    v = jax.random.normal(ks[2], (2, 80, 2, 16))
-    for window in (0, 24):
-        for unroll in (False, True):
-            out = chunked_attention(q, k, v, causal=True, window=window,
-                                    q_chunk=32, kv_chunk=32, unroll=unroll)
-            want = naive_attention(q, k, v, causal=True, window=window)
-            # bf16 probability×value matmul (§Perf A2) widens the tolerance
-            np.testing.assert_allclose(out, want, atol=6e-3)
+    q, k, v = _qkv(S)
+    out = chunked_attention(q, k, v, causal=causal, window=window,
+                            q_chunk=q_chunk, kv_chunk=kv_chunk, unroll=unroll)
+    want = naive_attention(q, k, v, causal=causal, window=window)
+    # bf16 probability×value matmul (§Perf A2) widens the tolerance
+    np.testing.assert_allclose(out, want, atol=6e-3)
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 24), (False, 0)])
+def test_chunked_attention_gradients_equal_naive(causal, window):
+    """q, k and v cotangents through the skipped blocks' scans."""
+    from repro.models.attention import chunked_attention, naive_attention
+    q, k, v = _qkv(80)
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, 80, 8, 16))
+
+    def loss(attn, **kw):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=causal, window=window, **kw) * w)
+    got = jax.grad(loss(chunked_attention, q_chunk=32, kv_chunk=32),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(naive_attention), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=3e-2)

@@ -213,6 +213,33 @@ def test_dropless_builds_count_at_trace_time():
     assert _delta(before, moe.DROPLESS_BUILDS) == 2
 
 
+@pytest.mark.parametrize("causal,window,ranges", [
+    (True, 0, [(0, 1), (0, 2), (0, 3), (0, 4)]),
+    (False, 0, [(0, 4)] * 4),
+    # a 1,500-token window leaves the last Q chunk's first KV chunk out
+    (True, 1500, [(0, 1), (0, 2), (0, 3), (1, 4)]),
+])
+def test_chunked_attention_counts_and_scans_live_blocks(causal, window,
+                                                        ranges):
+    """At 4,096 tokens in 1,024-wide chunks (the train cell's shapes), traced
+    only: the counters read the live blocks, and the traced program scans
+    exactly those, one scan per Q chunk."""
+    from repro.models import attention
+    assert attention.kv_block_ranges(4096, 4096, 1024, 1024, causal=causal,
+                                     window=window) == ranges
+    q = jax.ShapeDtypeStruct((1, 4096, 2, 8), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4096, 1, 8), jnp.bfloat16)
+    before = telemetry.counters()
+    jaxpr = jax.make_jaxpr(lambda q, k, v: attention.chunked_attention(
+        q, k, v, causal=causal, window=window))(q, kv, kv)
+    computed = sum(hi - lo for lo, hi in ranges)
+    assert _delta(before, attention.KV_BLOCKS) == computed
+    assert _delta(before, attention.KV_BLOCKS_SKIPPED) == 16 - computed
+    lengths = [e.params["length"] for e in jaxpr.jaxpr.eqns
+               if e.primitive.name == "scan"]
+    assert lengths == [hi - lo for lo, hi in ranges]
+
+
 def test_compiled_step_carries_the_mla_and_moe_scopes():
     """The scopes land in the compiled step's op metadata, where a
     profiler's viewer shows them."""
