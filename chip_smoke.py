@@ -5,8 +5,8 @@
 
 A   Trace replay of the MLP teacher task through ``repro.experiments.run``
     (softsync λ=16 μ=4, momentum, seed 17 — the bench-guard configuration):
-    the Pallas megakernel (``ring_impl="auto"``) against its fused jnp twin
-    on the same trace, combine mode on fp32 and bf16 rings plus one
+    the Pallas megakernel the replay picks on a TPU against its fused jnp
+    twin on the same trace, combine mode on fp32 and bf16 rings plus one
     ``per_gradient`` run (sequential mode).
 A2  What-if replay of a D = 1e8 diagonal quadratic on a bf16 ring with
     momentum (the in-kernel-gradient megakernel), Pallas against the twin.
@@ -31,6 +31,7 @@ import math
 import os
 import sys
 import time
+from unittest import mock
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 REL_TOL = 1e-5          # kernel vs twin, and spmd vs single (tests/test_spmd)
@@ -87,6 +88,14 @@ def _timed_run(spec):
     return res, time.perf_counter() - t0
 
 
+def _timed_twin_run(spec):
+    """``_timed_run`` with the replay's event body held to the fused jnp
+    twin of the kernel."""
+    from repro.core import engine
+    with mock.patch.object(engine, "_event_body", lambda opt: "fused"):
+        return _timed_run(spec)
+
+
 def _peak_bytes(device) -> int:
     return int(device.memory_stats()["peak_bytes_in_use"])
 
@@ -107,9 +116,8 @@ def phase_a(steps: int = 48) -> None:
         kernel, t_kernel = _timed_run(ExperimentSpec(
             run=cfg, problem="mlp_teacher", steps=steps))
         _kernel_ran(before, f"A/{name}")
-        twin, t_twin = _timed_run(ExperimentSpec(
-            run=cfg.replace(ring_impl="fused"), problem="mlp_teacher",
-            steps=steps))
+        twin, t_twin = _timed_twin_run(ExperimentSpec(
+            run=cfg, problem="mlp_teacher", steps=steps))
         if not math.isfinite(kernel.metrics["test_error"]):
             _fail(f"A/{name}: test error {kernel.metrics['test_error']}")
         _emit({"phase": "A", "variant": name, "updates": steps,
@@ -138,9 +146,7 @@ def phase_a2(d: int = 100_000_000, lam: int = 128, steps: int = 8) -> None:
     K = kernel.staleness["ring_buffer_K"]
     if K < 2:
         _fail(f"A2: ring K = {K}; the what-if kernel needs K >= 2")
-    twin, t_twin = _timed_run(ExperimentSpec(
-        run=cfg.replace(ring_impl="fused"), problem="quadratic_whatif",
-        problem_args=spec.problem_args, steps=steps))
+    twin, t_twin = _timed_twin_run(spec)
     loss = kernel.metrics["loss"]
     if not math.isfinite(loss):
         _fail(f"A2: loss is {loss}")

@@ -29,7 +29,6 @@ from repro.serve.fleet import FleetConfig
 
 # replay weight-ring knobs (core/engine.py compiled replay, DESIGN.md §12)
 RING_DTYPES = ("fp32", "bf16")
-RING_IMPLS = ("auto", "pallas", "fused", "stock")
 
 # replay placement (DESIGN.md §13): "single" replays the whole trace on one
 # device; "spmd" shard_maps the scan over a (ps, learner) emulated device
@@ -424,18 +423,13 @@ class RunConfig:
     # ring bytes and carries an fp32 error-feedback residue so the master
     # weight chain stays exactly the fp32 trajectory — the only
     # approximation is gradients being evaluated at quantized snapshots.
-    # ring_impl: which scan body executes an update event — "auto" (Pallas
-    # replay megakernel on TPU, its fused jnp twin elsewhere) or a forced
-    # "pallas" / "fused" / "stock" ("stock" is the pre-megakernel
-    # gather→update→set chain, the bitwise baseline; fp32 only).
     ring_dtype: str = "fp32"
-    ring_impl: str = "auto"
     # --- replay placement (DESIGN.md §13) -----------------------------------
     # placement: "single" (default) compiles the replay scan for one device;
     # "spmd" shard_maps it over a make_sim_mesh(S, L) device mesh — each PS
     # shard's (K, Dp) ring lives on its own "ps"-axis device and the c
     # gradient slots of an update split across L "learner"-axis devices, with
-    # cross-shard pulls / combine pushes as real all_gather/psum/ppermute
+    # cross-shard pulls / combine pushes as real all_gather/psum
     # collectives.  spmd_learners: L (0 = auto — the largest divisor of c
     # that fits the visible device count).
     placement: str = "single"
@@ -518,20 +512,12 @@ class RunConfig:
         if self.ring_dtype not in RING_DTYPES:
             raise ValueError(f"unknown ring_dtype {self.ring_dtype!r}: "
                              f"expected one of {RING_DTYPES}")
-        if self.ring_impl not in RING_IMPLS:
-            raise ValueError(f"unknown ring_impl {self.ring_impl!r}: "
-                             f"expected one of {RING_IMPLS}")
-        if self.ring_dtype == "bf16":
-            if self.ring_impl == "stock":
-                raise ValueError(
-                    "ring_dtype='bf16' needs the fused megakernel scan body "
-                    "to carry the error-feedback residue; ring_impl='stock' "
-                    "keeps the fp32 ring (use 'auto', 'fused' or 'pallas')")
-            if self.optimizer not in KERNEL_OPTIMIZERS:
-                raise ValueError(
-                    f"ring_dtype='bf16' requires a kernel-supported "
-                    f"optimizer {KERNEL_OPTIMIZERS}; {self.optimizer!r} "
-                    f"replays on the pytree path with an fp32 ring")
+        if (self.ring_dtype == "bf16"
+                and self.optimizer not in KERNEL_OPTIMIZERS):
+            raise ValueError(
+                f"ring_dtype='bf16' requires a kernel-supported "
+                f"optimizer {KERNEL_OPTIMIZERS}; {self.optimizer!r} "
+                f"replays on the pytree path with an fp32 ring")
         if self.placement not in PLACEMENTS:
             raise ValueError(f"unknown placement {self.placement!r}: "
                              f"expected one of {PLACEMENTS}")
@@ -566,12 +552,6 @@ class RunConfig:
                     "single-device replay scan, which shard_map splits into "
                     "per-shard (K, Dp) rings; replay the serving trace with "
                     "placement='single' (the default)")
-            if self.shards > 1 and self.ring_impl == "stock":
-                raise ValueError(
-                    "serving with shards>1 needs the fused ring "
-                    "(ring_impl='auto'/'fused'/'pallas'): the stock sharded "
-                    "scan keeps a (S, K, Dp) ring with no flat row for a "
-                    "publication to read")
         if self.elastic and self.lr_policy == "per_gradient":
             raise ValueError(
                 "per_gradient LRs imply sequential optimizer events, which "

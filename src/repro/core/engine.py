@@ -59,10 +59,15 @@ PULL_ROWS = "replay_ring.pull_rows"
 # what-if scans built (traced) with the ring carried in the kernel's tiles
 TILED_CARRY_BUILDS = "engine.replay.tiled_carry_builds"
 
-# cross-shard pull assembly for the SPMD replay (DESIGN.md §13): one fused
-# all_gather over the "ps" axis, or the equivalent S−1 neighbor-ppermute
-# ring exchange (bitwise-equal data movement; slower on emulated devices)
-SPMD_ASSEMBLIES = ("all_gather", "ppermute")
+
+def _event_body(spec) -> str:
+    """The body an update event runs (DESIGN.md §12): ``"pytree"`` for an
+    optimizer with no flat event path (adamw), else the Pallas megakernel
+    ``"pallas"`` on a TPU and its jnp twin ``"fused"`` anywhere else (the
+    same math without interpret mode's per-event launch)."""
+    if not spec.kernel_supported:
+        return "pytree"
+    return "pallas" if jax.default_backend() == "tpu" else "fused"
 
 
 @functools.lru_cache(maxsize=32)
@@ -142,31 +147,40 @@ def _rows(ring: jax.Array, idx: jax.Array) -> jax.Array:
 
 @functools.lru_cache(maxsize=32)
 def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
-                  layout: flatten.TreeLayout, batched: bool = False,
-                  shards: int = 1, group_size: int = 1,
-                  masked: bool = False, member_masked: bool = False,
-                  ring_impl: str = "stock", ring_dtype: str = "fp32",
+                  layout: flatten.TreeLayout, body: str,
+                  batched: bool = False, shards: int = 1,
+                  group_size: int = 1, masked: bool = False,
+                  member_masked: bool = False, ring_dtype: str = "fp32",
                   whatif: bool = False, publish: bool = False):
     """The jitted scan over update events — cached per static config so
     repeated replays (benchmark/sweep loops) reuse the compiled program;
     the LRU bound keeps long-lived processes from pinning every grad_fn
     closure + executable ever seen.
 
-    Kernel-supported optimizers (sgd / momentum / adagrad) never leave the
-    flat domain: the carry is just the (K, D) ring plus the (D,) state
-    vector, gradients are flattened once per event, and the apply is ONE
-    fused ``optim.apply_event_flat`` over the whole model — the scan body
-    is the jnp twin of the Pallas ``ps_update`` tile.  adamw (scalar step
-    counter, no kernel path) falls back to the pytree apply.
+    ``body`` (from :func:`_event_body`, DESIGN.md §12) is what one event
+    runs:
+
+    * ``fused``  — ``optim.apply_event_ring``: gather the pulled rows,
+      ``apply_event_flat``, write the new row — ONE fused read-update-write
+      over a flat (K, Dp) ring carried with the (Dp,) optimizer state and,
+      when ``ring_dtype == "bf16"``, the fp32 error-feedback residue.
+      Gradients are flattened once per event and the apply covers the
+      whole model.
+    * ``pallas`` — the ``kernels/replay_ring`` megakernel: one pallas_call
+      per event with scalar-prefetched ring rows and in-place aliased
+      writes (interpret mode off-TPU); per event, bitwise the fused twin.
+    * ``pytree`` — ``optim.apply_update_tree`` on the params pytree, with
+      an fp32 (K, D) ring of flat snapshots: optimizers with no flat event
+      path (adamw; scalar step counter), trivial shard topology only.
 
     Topology (DESIGN.md §6) — the trivial (1, 1) case compiles the exact
     pre-topology body:
 
-    * ``shards`` = S > 1: the carry ring becomes S per-shard (K, Dp) rings
-      stacked as (S, K, Dp); each event gathers every slot's weight vector
-      from per-shard rows (``x["ts"]`` is (c, S) — inconsistent reads) and
-      applies the fused event per shard slice via the vmapped
-      ``optim.apply_event_sharded``.
+    * ``shards`` = S > 1: a ring row is the S shard slices, each padded to
+      Dp, concatenated.  Each event assembles every slot's weight vector
+      from per-shard rows (``x["ts"]`` is (c, S) — inconsistent reads);
+      the update itself is elementwise, so one event over the (K, S·Dp)
+      buffer computes the shard slices of the unsharded apply.
     * ``group_size`` = gs > 1: each slot aggregates gs member gradients
       computed against the slot's pulled weights (the group pulls once and
       broadcasts); minibatches carry a (c, gs, …) leading shape and the
@@ -193,31 +207,9 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     place and copying it every event.  Only ``ts`` (which snapshots each
     lane's c gradients read), ``lrs``, and the minibatches are per-lane.
 
-    Ring scan bodies (DESIGN.md §12) — ``ring_impl`` selects how a
-    kernel-supported event executes:
-
-    * ``stock``  — the original gather → ``apply_event_flat`` →
-      ``.at[slot].set`` chain (the bitwise baseline; adamw always lands
-      here via its pytree body).
-    * ``fused``  — ``optim.apply_event_ring``: the same math phrased as
-      ONE fused read-update-write over a flat (K, Dp) ring (bitwise-equal
-      to stock at fp32), plus the bf16 error-feedback residue when
-      ``ring_dtype == "bf16"``.  Sharded traces unify onto the flat padded
-      buffer: the per-shard structure only matters for the *gather* (each
-      slot assembles per-shard rows at per-shard timestamps); the update
-      itself is elementwise, so one fused event over the (K, S·Dp) buffer
-      computes the same values as the stacked per-shard applies.  Bitwise
-      it matches the flat ``apply_event_flat`` reference — the *stock
-      sharded* body runs the combine on (S, c, Dp) operands, which XLA
-      may fuse with different rounding (~1 ulp/event), so sharded fused
-      vs stock agree to fp32 accumulation tolerance only.
-    * ``pallas`` — the ``kernels/replay_ring`` megakernel: one pallas_call
-      per event with scalar-prefetched ring rows and in-place aliased
-      writes (interpret mode off-TPU).
-
-    For non-stock impls the carry is ``(ring, state, residue)`` and the
-    jitted scan **donates** it (``donate_argnums=0``): the K·D ring stops
-    being double-buffered across scan dispatches.  ``whatif=True`` swaps
+    The flat bodies' carry is ``(ring, state, residue)`` and the jitted
+    scan **donates** it (``donate_argnums=0``): the K·D ring is not
+    double-buffered across scan dispatches.  ``whatif=True`` swaps
     the gradient stage for the in-kernel/streamed closed-form gradients
     (``g = a ⊙ (w_pulled − w*)``; combine mode, trivial topology): the
     scan fn then takes ``(carry, xs, (a, w*))`` and no minibatches ride
@@ -232,17 +224,6 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
 
     def coef_of(x):
         return x["coef"] if masked else coef
-
-    def slot_weights(ring, x):
-        """The (c, D) weight vectors the slots' gradients are computed
-        against: one ring gather, or the per-shard assembly (each slot
-        concatenates its S pulled slices — possibly different timestamps:
-        weights that never existed as one consistent version, §3.1)."""
-        if shards == 1:
-            return ring[x["ts"]]      # (c, D) gather; ts pre-wrapped mod K
-        # ring: (S, K, Dp); x["ts"]: (c, S) → per-shard (S, c, Dp) gather
-        parts = jax.vmap(lambda r, t: r[t], in_axes=(0, 1))(ring, x["ts"])
-        return flatten.shard_unpack(jnp.moveaxis(parts, 0, 1), D)
 
     def gradients_of(pulled_flat, x):
         """vmapped grad_fn at the (c, D) fp32 pulled weights, cast to fp32
@@ -268,20 +249,25 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
             return jax.tree.map(wmean, g)
         return jax.tree.map(lambda a: a.mean(axis=1), g)
 
-    def gradients(ring, x):
-        return gradients_of(slot_weights(ring, x), x)
-
-    fused = ring_impl in ("fused", "pallas") and spec.kernel_supported
-    if fused:
+    flat = body != "pytree"
+    if not flat:
+        def event(carry, x):
+            ring, (params, opt_state) = carry
+            grads = _unstack_tree(gradients_of(ring[x["ts"]], x), c)
+            params, opt_state = optim.apply_update_tree(
+                spec, params, opt_state, grads, coef_of(x), x["lrs"], mode)
+            ring = ring.at[x["slot"]].set(flatten.tree_to_flat(params))
+            return (ring, (params, opt_state)), None
+    else:
         from repro.kernels import replay_ring   # lazy: breaks import cycle
 
-        def slot_weights_flat(ring, x):
-            """Fused-impl gather off the flat (K, Dp) ring (padding and —
-            with a bf16 ring — quantization stripped): the (c, D) fp32
-            weights the slot gradients see.  Sharded traces view the
-            buffer as (K, S, Dp) rows for the per-shard-timestamp
-            assembly; the flat layout is the shard rows concatenated, so
-            this is bitwise the stock per-shard gather."""
+        def slot_weights(ring, x):
+            """The (c, D) fp32 weights the slot gradients see, off the flat
+            (K, Dp) ring (padding and — with a bf16 ring — quantization
+            stripped).  Sharded traces view the buffer as (K, S, Dp) rows
+            for the per-shard-timestamp assembly: each slot concatenates
+            its S pulled slices — possibly different timestamps, weights
+            that never existed as one consistent version (§3.1)."""
             if shards == 1:
                 return _rows(ring, x["ts"])[..., :D].astype(jnp.float32)
             # slot i, shard j: row ts[i, j], columns [j·Dp, (j+1)·Dp)
@@ -295,7 +281,7 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
             def event(aux, carry, x):
                 ring, s, res = _flat_views(carry, K)
                 a, wstar = (v.reshape(-1) for v in aux)
-                if ring_impl == "pallas" and K >= 2:
+                if body == "pallas" and K >= 2:
                     idx = jnp.concatenate(
                         [jnp.stack([x["prev"], x["slot"]]), x["ts"]])
                     ring, s, res = replay_ring.ring_apply_whatif(
@@ -310,9 +296,9 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
             def event(carry, x):
                 ring, s, res = carry
                 g = flatten.batched_tree_to_flat(
-                    gradients_of(slot_weights_flat(ring, x), x))
+                    gradients_of(slot_weights(ring, x), x))
                 gp = flatten.pad_flat(g, ring.shape[1])
-                if ring_impl == "pallas":
+                if body == "pallas":
                     idx = jnp.stack([x["prev"], x["slot"]])
                     ring, s, res = replay_ring.ring_apply(
                         ring, s, res, gp, coef_of(x), x["lrs"], idx,
@@ -322,29 +308,6 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                         spec, ring, s, res, gp, coef_of(x), x["lrs"],
                         x["prev"], x["slot"], mode)
                 return (ring, s, res), None
-    elif spec.kernel_supported and shards > 1:
-        def event(carry, x):
-            ring, s = carry
-            g = flatten.batched_tree_to_flat(gradients(ring, x))
-            gp = flatten.shard_pack_grads(g, shards, Dp)     # (S, c, Dp)
-            w, s = optim.apply_event_sharded(
-                spec, ring[:, x["prev"]], s, gp, coef_of(x), x["lrs"], mode)
-            return (ring.at[:, x["slot"]].set(w), s), None
-    elif spec.kernel_supported:
-        def event(carry, x):
-            ring, s = carry
-            g = flatten.batched_tree_to_flat(gradients(ring, x))
-            w, s = optim.apply_event_flat(spec, ring[x["prev"]], s, g,
-                                          coef_of(x), x["lrs"], mode)
-            return (ring.at[x["slot"]].set(w), s), None
-    else:
-        def event(carry, x):
-            ring, (params, opt_state) = carry
-            grads = _unstack_tree(gradients(ring, x), c)
-            params, opt_state = optim.apply_update_tree(
-                spec, params, opt_state, grads, coef_of(x), x["lrs"], mode)
-            ring = ring.at[x["slot"]].set(flatten.tree_to_flat(params))
-            return (ring, (params, opt_state)), None
 
     if publish:
         # serving lane (DESIGN.md §14): capture each *published* weight
@@ -397,17 +360,17 @@ def _make_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
         if masked:
             axes["coef"] = 0
         vrun = jax.vmap(run, in_axes=(0, axes))
-        return (jax.jit(vrun, donate_argnums=0) if fused else jax.jit(vrun))
-    # non-stock carries are donated: the ring/state/residue buffers are
+        return jax.jit(vrun, donate_argnums=0) if flat else jax.jit(vrun)
+    # flat carries are donated: the ring/state/residue buffers are
     # updated in place across scan dispatches instead of double-buffered
-    return jax.jit(run, donate_argnums=0) if fused else jax.jit(run)
+    return jax.jit(run, donate_argnums=0) if flat else jax.jit(run)
 
 
-def _spmd_local_width(D: int, shards: int, ring_impl: str) -> int:
+def _spmd_local_width(D: int, shards: int, body: str) -> int:
     """Per-"ps"-device ring row width: the shard slice Dp = ⌈D/S⌉, padded
     to the megakernel tile multiple when the local body is Pallas."""
     Dp = -(-D // shards)
-    if ring_impl == "pallas":
+    if body == "pallas":
         from repro.kernels import replay_ring   # lazy: import cycle
         return replay_ring.padded_width(Dp)
     return Dp
@@ -416,10 +379,9 @@ def _spmd_local_width(D: int, shards: int, ring_impl: str) -> int:
 @functools.lru_cache(maxsize=32)
 def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                        layout: flatten.TreeLayout, plan: PlacementPlan,
-                       xs_keys: tuple, group_size: int = 1,
+                       xs_keys: tuple, body: str, group_size: int = 1,
                        masked: bool = False, member_masked: bool = False,
-                       ring_impl: str = "fused", ring_dtype: str = "fp32",
-                       whatif: bool = False, assembly: str = "all_gather"):
+                       ring_dtype: str = "fp32", whatif: bool = False):
     """The replay scan shard_mapped over a ``(ps, learner)`` device mesh —
     the distributed twin of :func:`_make_scan_fn` (DESIGN.md §13).
 
@@ -432,22 +394,21 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     * **pull** — each PS device gathers its own ring rows at its own
       per-shard timestamps (the inconsistent-read column ``ts[:, s]``) and
       an ``all_gather`` over "ps" assembles the (c, D) pulled weights on
-      every device (``assembly="ppermute"`` swaps in the bitwise-equal
-      S−1-hop neighbor ring exchange, ``optim.ring_all_gather``);
+      every device;
     * **push** — combine mode reduces each learner device's local-slot
       partial of ĝ = Σ coef_j·g_j with ONE ``psum`` over "learner"
       (``optim.combine_spmd``); sequential mode ``all_gather``s the slot
       gradients over "learner" instead (every event needs every slot);
-    * **update** — each PS device applies the fused/Pallas ring body
-      (``optim.apply_event_ring`` / ``replay_ring.ring_apply``) to its own
-      slice of ĝ — elementwise math, so per-shard applies are exactly the
-      shard slices of the single-device apply.
+    * **update** — each PS device applies the ``body`` from
+      :func:`_event_body` (``optim.apply_event_ring`` /
+      ``replay_ring.ring_apply``) to its own slice of ĝ — elementwise
+      math, so per-shard applies are exactly the shard slices of the
+      single-device apply.
 
     Equivalence to ``placement="single"`` (pinned by tests/test_spmd.py;
     tolerance policy in DESIGN.md §13): the **what-if** body is bitwise
     against single-device replay, any S — shard-local closed-form
-    gradients, no reduction to reorder — and ``assembly="ppermute"`` is
-    bitwise against ``"all_gather"``.  The **staged-gradient** bodies
+    gradients, no reduction to reorder.  The **staged-gradient** bodies
     track single-device replay to ~1 ulp per event even at L = 1: the
     math is op-for-op identical, but XLA fuses the combine/update chain
     differently (fma contraction) inside the shard_map body, and L > 1
@@ -467,30 +428,22 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
     cl = c // L
     D = layout.total
     Dp = -(-D // S)
-    Wl = _spmd_local_width(D, S, ring_impl)
+    Wl = _spmd_local_width(D, S, body)
     from repro.kernels import replay_ring       # lazy: import cycle
     from repro.launch import sharding as sharding_lib
 
-    if assembly not in SPMD_ASSEMBLIES:
-        raise ValueError(f"unknown spmd_assembly {assembly!r}: expected "
-                         f"one of {SPMD_ASSEMBLIES}")
     mesh = mesh_lib.make_sim_mesh(S, L)
     coef = jnp.full((c,), 1.0 / c, jnp.float32)
 
     def coef_of(x):
         return x["coef"] if masked else coef
 
-    def assemble(mine):
-        if assembly == "ppermute":
-            return optim.ring_all_gather(mine, "ps", S)
-        return jax.lax.all_gather(mine, "ps", axis=0)
-
     def pulled_weights(rl, x):
         """(c, D) fp32 pulled weights, assembled from every shard's local
         gather (pallas pad stripped per shard) — the same moveaxis/reshape
-        assembly as the single-device fused ``slot_weights_flat``."""
+        assembly as the single-device ``slot_weights``."""
         mine = _rows(rl, x["ts"][:, 0])[:, :Dp]       # (c, Dp) local rows
-        parts = assemble(mine)                        # (S, c, Dp)
+        parts = jax.lax.all_gather(mine, "ps", axis=0)    # (S, c, Dp)
         full = jnp.moveaxis(parts, 0, 1).reshape(c, S * Dp)
         return full[:, :D].astype(jnp.float32)
 
@@ -530,7 +483,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
             rl, sl, resl = _flat_views(carry, K)
             a_l, ws_l = (v.reshape(-1) for v in aux)
             ts_col = x["ts"][:, 0]
-            if ring_impl == "pallas" and K >= 2:
+            if body == "pallas" and K >= 2:
                 idx = jnp.concatenate(
                     [jnp.stack([x["prev"], x["slot"]]), ts_col])
                 rl, sl, resl = replay_ring.ring_apply_whatif(
@@ -559,7 +512,7 @@ def _make_spmd_scan_fn(grad_fn, spec, mode: str, c: int, K: int,
                 gp = flatten.pad_flat(shard_slice(g_all, si), Wl)  # (c, Wl)
                 cvec = coef_of(x)
                 lvec = x["lrs"]
-            if ring_impl == "pallas":
+            if body == "pallas":
                 idx = jnp.stack([x["prev"], x["slot"]])
                 rl, sl, resl = replay_ring.ring_apply(
                     rl, sl, resl, gp, cvec, lvec, idx, spec=spec, mode=mode)
@@ -700,7 +653,6 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
            eval_every: int = 0,
            flat_grad=None,
            placement: Optional[str] = None,
-           spmd_assembly: str = "all_gather",
            serve_batches=None,
            serve_eval_fn: Optional[Callable] = None) -> SimResult:
     """Execute a scheduled trace against real gradients, compiled.
@@ -713,10 +665,9 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     skips the per-slot Python staging loop entirely; this is where most of
     the single-replay wall clock went before PR 6).
 
-    ``run.ring_impl``/``run.ring_dtype`` select the scan body and ring
-    storage (DESIGN.md §12): the default ``auto`` runs the fused megakernel
-    path (Pallas on TPU, its bitwise jnp twin elsewhere) with a donated
-    carry; ``stock`` forces the pre-megakernel chain.
+    The event body is :func:`_event_body`'s (DESIGN.md §12): the Pallas
+    megakernel on a TPU, its jnp twin elsewhere, the pytree apply for
+    optimizers with no flat path; ``run.ring_dtype`` sets the ring storage.
 
     ``flat_grad = ("quadratic", coeffs)`` opts into the **what-if
     replay**: ``coeffs(pos) -> (a, w*)`` gives the fp32 curvature and
@@ -725,7 +676,7 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     data is staged
     — peak memory O(K·D_ring + D), which is what makes trace-driven studies
     at ``configs/`` big-model D feasible.  Requires a kernel-supported
-    optimizer, combine mode, the trivial topology and a non-stock impl
+    optimizer, combine mode and the trivial topology
     (``placement="spmd"`` lifts the topology restriction: closed-form
     gradients are shard-local, so every PS device what-ifs its own slice);
     anything else falls back to the staged-gradient path (so ``batch_fn``/
@@ -736,10 +687,10 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     ``"spmd"`` shard_maps it over a ``make_sim_mesh(S, L)`` device mesh —
     per-shard rings on distinct "ps" devices, slot blocks on distinct
     "learner" devices, cross-shard pulls / combine pushes as real
-    all_gather/psum (or ppermute, ``spmd_assembly="ppermute"``)
-    collectives.  What-if spmd replay is bitwise-equal to single-device;
-    staged-gradient paths track it to ~1 ulp/event (XLA fusion inside the
-    shard_map body; psum reduction order at L > 1) — see DESIGN.md §13.
+    all_gather/psum collectives.  What-if spmd replay is bitwise-equal to
+    single-device; staged-gradient paths track it to ~1 ulp/event (XLA
+    fusion inside the shard_map body; psum reduction order at L > 1) —
+    see DESIGN.md §13.
 
     With ``eval_every`` set, the scan runs in eval_every-sized segments;
     a trailing remainder segment (steps % eval_every != 0) has a different
@@ -796,15 +747,14 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
                             layout=layout, grad_fn=grad_fn,
                             init_params=init_params, batch_fn=batch_fn,
                             batches=batches, eval_fn=eval_fn,
-                            eval_every=eval_every, flat_grad=flat_grad,
-                            assembly=spmd_assembly)
+                            eval_every=eval_every, flat_grad=flat_grad)
     if place != "single":
         raise ValueError(f"unknown placement {place!r}: expected "
                          f"'single' or 'spmd'")
     _, opt_state = init_ps_state(run, init_params)
 
-    impl = optim.resolve_ring_impl(run.ring_impl, spec)
-    whatif = (flat_grad is not None and impl != "stock"
+    body = _event_body(spec)
+    whatif = (flat_grad is not None and body != "pytree"
               and trace.mode == "combine" and S == 1 and gs == 1
               and serving is None)
     if whatif:
@@ -818,10 +768,10 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
         raise ValueError("pass exactly one of batch_fn / batches")
 
     scan_fn = _make_scan_fn(None if whatif else grad_fn, spec, trace.mode,
-                            c, K, layout, shards=S, group_size=gs,
+                            c, K, layout, body, shards=S, group_size=gs,
                             masked=trace.valid is not None,
                             member_masked=trace.member_valid is not None,
-                            ring_impl=impl, ring_dtype=run.ring_dtype,
+                            ring_dtype=run.ring_dtype,
                             whatif=whatif, publish=serving is not None)
 
     xs = _trace_xs(trace, K, None if whatif else batch_fn,
@@ -829,8 +779,14 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
     if serving is not None:
         xs["pub"] = jnp.asarray(_pub_index(serving, steps), jnp.int32)
     D = layout.total
-    Dp = topo.padded_width(D)
-    if impl != "stock":
+    aux = None
+    if body == "pytree":
+        ring = jnp.broadcast_to(flatten.tree_to_flat(init_params), (K, D))
+        carry = (ring, (init_params, opt_state))
+
+        def params_of(carry, done):
+            return carry[1][0]
+    else:
         # (K, width) ring in the ring dtype — sharded traces use the
         # concatenated shard rows (width = S·Dp ≥ D), the Pallas megakernel
         # a row-block tile multiple on top; padding zeros are inert.  With
@@ -839,10 +795,10 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
         # what-if kernel takes its rows in _lanes tiles, which the carry
         # keeps (the K = 1 fallback and the fused twin run flat).
         from repro.kernels import replay_ring   # lazy: import cycle
-        width = D if S == 1 else S * Dp
-        if impl == "pallas":
+        width = D if S == 1 else S * topo.padded_width(D)
+        if body == "pallas":
             width = replay_ring.padded_width(width)
-        lanes = _lanes(width, whatif and impl == "pallas" and K >= 2)
+        lanes = _lanes(width, whatif and body == "pallas" and K >= 2)
         carry = _ring_carry(
             init_params,
             opt_state[spec.state_keys[0]] if spec.state_keys else None,
@@ -852,37 +808,11 @@ def replay(trace: ArrivalTrace, run: RunConfig, *,
             return _ring_row_params(carry[0], carry[2], done % K,
                                     layout=layout)
 
-        aux = None
         if whatif:
             aux = jax.jit(lambda base: tuple(
                 v[0].reshape(lanes)
                 for v in _whatif_aux(flat_grad[1], base, width, width,
                                      D)))(jnp.zeros((1,), jnp.int32))
-    elif S > 1:
-        # per-shard rings: (S, K, Dp), row r of shard s = snapshot ts=r of
-        # the shard's slice (the σ_s ≤ σ invariant keeps K a valid bound)
-        ring = jnp.broadcast_to(flatten.shard_pack(
-            flatten.tree_to_flat(init_params), S, Dp)[:, None, :], (S, K, Dp))
-    else:
-        ring = jnp.broadcast_to(flatten.tree_to_flat(init_params), (K, D))
-    if impl == "stock" and spec.kernel_supported:
-        # flat-domain carry: ring + the (D,)/(S, Dp) state vector (or None)
-        s0 = None
-        if spec.state_keys:
-            s0 = flatten.tree_to_flat(opt_state[spec.state_keys[0]])
-            if S > 1:
-                s0 = flatten.shard_pack(s0, S, Dp)
-        carry = (ring, s0)
-
-        def params_of(carry, done):
-            row = (carry[0][done % K] if S == 1
-                   else flatten.shard_unpack(carry[0][:, done % K], D))
-            return _unflatten_jit(layout)(row)
-    elif impl == "stock":
-        carry = (ring, (init_params, opt_state))
-
-        def params_of(carry, done):
-            return carry[1][0]
 
     if serving is not None:
         # snapshot buffer riding the carry: one row per published version
@@ -1015,7 +945,7 @@ def _serve_eval(snaps, layout, D: int, serving, serve_batches,
 
 def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
                  grad_fn, init_params, batch_fn, batches, eval_fn,
-                 eval_every, flat_grad, assembly) -> SimResult:
+                 eval_every, flat_grad) -> SimResult:
     """The ``placement="spmd"`` arm of :func:`replay`: resolve the trace's
     :func:`placement_plan` against the visible devices, build the sharded
     ``(S, K, Wl)`` carry, and drive the shard_mapped scan
@@ -1031,15 +961,11 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
             "serving lane captures published ring rows inside the "
             "single-device scan, which shard_map splits into per-shard "
             "(K, Dp) rings; replay with placement='single' (the default)")
-    if not spec.kernel_supported:
+    body = _event_body(spec)
+    if body == "pytree":
         raise ValueError(
             f"placement='spmd' needs a kernel-supported optimizer (flat "
             f"per-shard ring carries); {spec.optimizer!r} has none")
-    # "stock" has no per-device flat ring body; its fused twin is bitwise
-    # at fp32 (RunConfig validation already keeps bf16 off stock)
-    impl = optim.resolve_ring_impl(run.ring_impl, spec)
-    if impl == "stock":
-        impl = "fused"
     ef = run.ring_dtype == "bf16"
     whatif = (flat_grad is not None and trace.mode == "combine" and gs == 1)
     if whatif:
@@ -1063,11 +989,10 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
         xs["ts"] = xs["ts"][..., None]      # (steps, c, 1): one shard column
     scan_fn = _make_spmd_scan_fn(None if whatif else grad_fn, spec,
                                  trace.mode, c, K, layout, plan,
-                                 tuple(sorted(xs)), group_size=gs,
+                                 tuple(sorted(xs)), body, group_size=gs,
                                  masked=trace.valid is not None,
                                  member_masked=trace.member_valid is not None,
-                                 ring_impl=impl, ring_dtype=run.ring_dtype,
-                                 whatif=whatif, assembly=assembly)
+                                 ring_dtype=run.ring_dtype, whatif=whatif)
 
     # the carry (and the what-if auxiliaries) are built by one program whose
     # outputs are laid out per "ps" device, so each device computes only
@@ -1076,12 +1001,12 @@ def _replay_spmd(trace: ArrivalTrace, run: RunConfig, *, spec, layout,
     # packed in place; nothing of model size ever sits whole on one device.
     D = layout.total
     Dp = topo.padded_width(D)
-    Wl = _spmd_local_width(D, S, impl)
+    Wl = _spmd_local_width(D, S, body)
     rdt = jnp.bfloat16 if ef else jnp.float32
     mesh = mesh_lib.make_sim_mesh(plan.shards, plan.learners)
     per_ps = NamedSharding(mesh, PartitionSpec("ps"))
 
-    lanes = _lanes(Wl, impl == "pallas")
+    lanes = _lanes(Wl, body == "pallas")
 
     def pack(vec):                                           # (S, *lanes)
         return flatten.pad_flat(flatten.shard_pack(vec, S, Dp),
@@ -1203,13 +1128,13 @@ def replay_batch(traces: Sequence[ArrivalTrace],
         if other != spec:
             raise ValueError(f"batch members must share the optimizer "
                              f"spec: {spec} vs {other}")
-    ring_cfg = (runs[0].ring_impl, runs[0].ring_dtype)
+    ring_dtype = runs[0].ring_dtype
     for run in runs[1:]:
-        if (run.ring_impl, run.ring_dtype) != ring_cfg:
+        if run.ring_dtype != ring_dtype:
             raise ValueError(
-                f"batch members must share (ring_impl, ring_dtype): "
-                f"{ring_cfg} vs {(run.ring_impl, run.ring_dtype)} — a bf16 "
-                f"lane's carry has a different dtype/residue layout")
+                f"batch members must share ring_dtype: {ring_dtype!r} vs "
+                f"{run.ring_dtype!r} — a bf16 lane's carry has a different "
+                f"dtype/residue layout")
     for run in runs:
         if run.placement != "single":
             raise ValueError(
@@ -1217,7 +1142,8 @@ def replay_batch(traces: Sequence[ArrivalTrace],
                 f"a device mesh cannot share the carry); replay "
                 f"placement={run.placement!r} specs individually")
     opt_state = optim.init_state(spec, init_params)
-    if not spec.kernel_supported:
+    body = _event_body(spec)
+    if body == "pytree":
         raise ValueError(f"{spec.optimizer!r} has no flat lane layout; "
                          f"replay each trace sequentially")
     for trace, run in zip(traces, runs):
@@ -1228,11 +1154,10 @@ def replay_batch(traces: Sequence[ArrivalTrace],
                 f"sharded/grouped traces sequentially")
     K = max(trace.max_staleness for trace in traces) + 1
     layout = flatten.layout_of(init_params)
-    impl = optim.resolve_ring_impl(runs[0].ring_impl, spec)
-    ef = runs[0].ring_dtype == "bf16"
-    scan_fn = _make_scan_fn(grad_fn, spec, mode, c, K, layout, batched=True,
-                            masked=masked, ring_impl=impl,
-                            ring_dtype=runs[0].ring_dtype)
+    ef = ring_dtype == "bf16"
+    scan_fn = _make_scan_fn(grad_fn, spec, mode, c, K, layout, body,
+                            batched=True, masked=masked,
+                            ring_dtype=ring_dtype)
 
     if batches is None:
         xs_lanes = [_trace_xs(trace, K, fn)
@@ -1251,37 +1176,26 @@ def replay_batch(traces: Sequence[ArrivalTrace],
     xs["slot"] = xs_lanes[0]["slot"]
     flat0 = flatten.tree_to_flat(init_params)
     D = flat0.shape[0]
-    if impl != "stock":
-        from repro.kernels import replay_ring   # lazy: import cycle
-        width = replay_ring.padded_width(D) if impl == "pallas" else D
-        rdt = jnp.bfloat16 if ef else jnp.float32
-        flat_pad = flatten.pad_flat(flat0, width)
-        q0 = quantize(flat_pad, rdt)
-        ring = jnp.tile(q0[None, None], (B, K, 1))
-        res0 = (jnp.tile((flat_pad - q0.astype(jnp.float32))[None], (B, 1))
-                if ef else None)
-        s0 = None
-        if spec.state_keys:
-            s_flat = flatten.pad_flat(
-                flatten.tree_to_flat(opt_state[spec.state_keys[0]]), width)
-            s0 = jnp.tile(s_flat[None], (B, 1))
-        carry = (ring, s0, res0)
+    from repro.kernels import replay_ring   # lazy: import cycle
+    width = replay_ring.padded_width(D) if body == "pallas" else D
+    rdt = jnp.bfloat16 if ef else jnp.float32
+    flat_pad = flatten.pad_flat(flat0, width)
+    q0 = quantize(flat_pad, rdt)
+    ring = jnp.tile(q0[None, None], (B, K, 1))
+    res0 = (jnp.tile((flat_pad - q0.astype(jnp.float32))[None], (B, 1))
+            if ef else None)
+    s0 = None
+    if spec.state_keys:
+        s_flat = flatten.pad_flat(
+            flatten.tree_to_flat(opt_state[spec.state_keys[0]]), width)
+        s0 = jnp.tile(s_flat[None], (B, 1))
+    carry = (ring, s0, res0)
 
-        def params_of(carry, lane, done):
-            row = carry[0][lane, done % K].astype(jnp.float32)
-            if ef:
-                row = row + carry[2][lane]
-            return _unflatten_jit(layout)(row[:D])
-    else:
-        ring = jnp.broadcast_to(flat0, (B, K) + flat0.shape)
-        s0 = None
-        if spec.state_keys:
-            s_flat = flatten.tree_to_flat(opt_state[spec.state_keys[0]])
-            s0 = jnp.broadcast_to(s_flat, (B,) + s_flat.shape)
-        carry = (ring, s0)
-
-        def params_of(carry, lane, done):
-            return _unflatten_jit(layout)(carry[0][lane, done % K])
+    def params_of(carry, lane, done):
+        row = carry[0][lane, done % K].astype(jnp.float32)
+        if ef:
+            row = row + carry[2][lane]
+        return _unflatten_jit(layout)(row[:D])
 
     def segment(lo, hi):
         # prev/slot are unbatched (steps,); everything else is (B, steps, …)

@@ -395,7 +395,7 @@ def schedule(run: RunConfig, steps: int,
 
 # RunConfig fields the schedule pass NEVER reads — replay/runtime knobs
 # only.  The lru key canonicalizes them to their defaults so e.g. a
-# ring_impl × ring_dtype sweep over one protocol shape shares ONE cached
+# ring_dtype × optimizer sweep over one protocol shape shares ONE cached
 # trace instead of fragmenting the cache.  Every field NOT listed here is
 # part of the cache key (the frozen-dataclass hash covers it), which is the
 # audited guarantee that schedule-relevant fields — protocol, topology,
@@ -405,7 +405,7 @@ def schedule(run: RunConfig, steps: int,
 # field without triaging it here fails loudly.
 _REPLAY_ONLY_FIELDS = (
     "momentum", "optimizer", "weight_decay",
-    "ring_dtype", "ring_impl", "placement", "spmd_learners",
+    "ring_dtype", "placement", "spmd_learners",
     "num_microbatches", "remat", "fsdp", "use_pallas",
     "attn_impl", "attn_q_chunk", "attn_kv_chunk", "unroll", "residual_spec",
 )

@@ -23,10 +23,6 @@ Guarded metrics:
   timeline (crash-restarts + leaves).  Absolute, wide margin like the
   compiled throughput: catches the schedule pass collapsing (e.g. the
   threshold refresh going quadratic), not runner noise.
-* ``megakernel_vs_xla_ratio``  — fused megakernel scan body vs the stock
-  XLA chain on the same trace + staged batches (DESIGN.md §12).
-  Machine-relative; fails if the default replay path regresses vs what
-  plain XLA delivers.
 * ``distributed_replay_updates_per_s`` — ``placement="spmd"`` what-if
   throughput at S=4 on the emulated 8-device host (DESIGN.md §13),
   measured in a subprocess so the device-count flag lands before jax
@@ -67,11 +63,6 @@ FLOOR_MARGINS = {
     "engine_speedup": 0.55,
     "batched_sweep_speedup": 0.55,
     "elastic_schedule_updates_per_s": 0.25,
-    # megakernel scan body vs the stock XLA chain on the same trace +
-    # staged batches (machine-relative; ~1.0 on CPU where the fused body's
-    # win is donation/memory, not FLOPs) — fails if the megakernel path
-    # ever regresses the hot loop vs what plain XLA delivers
-    "megakernel_vs_xla_ratio": 0.55,
     # absolute spmd throughput on the emulated mesh: wide margin, same
     # rationale as compiled_updates_per_s (CI hardware + core count vary)
     "distributed_replay_updates_per_s": 0.25,
@@ -116,8 +107,7 @@ def measure() -> dict:
     from repro.config import RunConfig
     from repro.experiments.cells.distributed_replay import \
         measure as _measure_dist
-    from repro.experiments.cells.sim_engine_bench import (_bench_megakernel,
-                                                          _bench_one,
+    from repro.experiments.cells.sim_engine_bench import (_bench_one,
                                                           _bench_sweep)
     from repro.experiments.cells.train_while_serve import \
         measure as _measure_serve
@@ -129,7 +119,6 @@ def measure() -> dict:
     row = _bench_one(cfg, updates=48, repeats=3)
     sweep = _bench_sweep(updates=30, lam=16, seeds=3, repeats=3)
     elastic = _bench_elastic_schedule()
-    mk = _bench_megakernel(updates=48, lam=16, repeats=3)
     dist = _measure_dist(updates=32, d=1_000_000, repeats=2, shards=(1, 4))
     serve = _measure_serve(updates=32, requests=512, repeats=2)
     return {
@@ -138,7 +127,6 @@ def measure() -> dict:
             "engine_speedup": row["speedup"],
             "batched_sweep_speedup": sweep["speedup"],
             "elastic_schedule_updates_per_s": elastic["updates_per_s"],
-            "megakernel_vs_xla_ratio": mk["megakernel_vs_xla_ratio"],
             "distributed_replay_updates_per_s":
                 dist["updates_per_s"]["spmd_s4"],
             "serving_requests_per_s": serve["requests_per_s"],
@@ -146,7 +134,6 @@ def measure() -> dict:
         "engine_cell": row,
         "sweep_cell": sweep,
         "elastic_schedule_cell": elastic,
-        "megakernel_cell": mk,
         "distributed_replay_cell": dist,
         "serving_cell": serve,
     }

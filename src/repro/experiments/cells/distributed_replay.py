@@ -74,14 +74,13 @@ def _inner(payload: dict) -> dict:
                         minibatch=4, base_lr=0.05,
                         lr_policy="staleness_inverse", optimizer="momentum",
                         duration_model="calibrated:adv:300mb", shards=s,
-                        placement="spmd", ring_impl="fused", seed=0)
+                        placement="spmd", seed=0)
         rows[f"spmd_s{s}"] = measure_one(cfg)
     single = RunConfig(protocol="softsync", n_softsync=4, n_learners=16,
                        minibatch=4, base_lr=0.05,
                        lr_policy="staleness_inverse", optimizer="momentum",
                        duration_model="calibrated:adv:300mb",
-                       shards=max(payload["shards"]), ring_impl="fused",
-                       seed=0)
+                       shards=max(payload["shards"]), seed=0)
     rows["single_s%d" % max(payload["shards"])] = measure_one(single)
 
     s_lo, s_hi = min(payload["shards"]), max(payload["shards"])

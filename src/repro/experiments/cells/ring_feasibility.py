@@ -24,8 +24,8 @@ what opens ``configs/`` big-model shapes to staleness what-if studies.
 
 Part 2 — empirical: ``RLIMIT_AS``-capped subprocesses replay the same
 trace shape (softsync n=1, λ=128, 8 updates) under the same 2.5 GiB
-address-space cap.  The stock path with a real MLP backward dies at
-D₀ ≈ 10 M params; the what-if megakernel on the bf16 error-feedback
+address-space cap.  A replay with a real MLP backward dies at
+D₀ ≈ 10 M params (its (c, D) matrices alone need ~10 GB); the what-if megakernel on the bf16 error-feedback
 ring replays 10·D₀ = 100 M.  ``skip_quick``: the capped subprocesses take
 minutes.
 """
@@ -61,7 +61,7 @@ from repro.experiments import run as run_spec
 
 cfg = RunConfig(protocol="softsync", n_softsync=1, n_learners={lam},
                 minibatch=1, base_lr=0.01, optimizer="sgd", seed=5,
-                ring_impl={impl!r}, ring_dtype={ring_dtype!r})
+                ring_dtype={ring_dtype!r})
 spec = ExperimentSpec(run=cfg, problem={problem!r},
                       problem_args={pargs!r}, steps={steps})
 res = run_spec(spec)
@@ -70,12 +70,12 @@ print("FEASIBLE", sorted(res.metrics)[0], f"peak_bytes={{peak}}")
 """
 
 
-def _try_replay(label: str, d: int, impl: str, problem: str, pargs: tuple,
+def _try_replay(label: str, d: int, problem: str, pargs: tuple,
                 ring_dtype: str = "fp32", cap: int = _CAP_BYTES) -> dict:
     """Run one capped replay in a subprocess; MemoryError / bad-alloc
     aborts count as infeasible (the allocator may kill the process
     outright rather than raise, so any nonzero exit is a fail)."""
-    code = _CHILD.format(cap=cap, lam=_LAM, impl=impl, problem=problem,
+    code = _CHILD.format(cap=cap, lam=_LAM, problem=problem,
                          pargs=pargs, steps=_STEPS, ring_dtype=ring_dtype)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"      # RLIMIT_AS caps host memory only
@@ -85,8 +85,7 @@ def _try_replay(label: str, d: int, impl: str, problem: str, pargs: tuple,
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
                           text=True, env=env, timeout=600)
     ok = proc.returncode == 0 and "FEASIBLE" in proc.stdout
-    return {"label": label, "platform": "cpu", "d": d, "impl": impl,
-            "problem": problem,
+    return {"label": label, "platform": "cpu", "d": d, "problem": problem,
             "ring_dtype": ring_dtype, "cap_bytes": cap, "feasible": ok,
             "detail": (proc.stdout.strip() if ok else
                        (proc.stderr.strip().splitlines() or ["killed"])[-1]
@@ -171,13 +170,13 @@ def compute(**params):
          len(fits), ",".join(fits))
 
     # ---- empirical: RLIMIT_AS-capped subprocess replays -------------------
-    # old path = real MLP backward through the stock engine at D0 (the only
-    # pre-megakernel way to replay a trace); new path = what-if megakernel
-    # on the closed-form quadratic at 10*D0, same trace shape and cap.
+    # staged path = real MLP backward at D0 (the only pre-megakernel way
+    # to replay a trace); new path = what-if megakernel on the closed-form
+    # quadratic at 10*D0, same trace shape and cap.
     trials = [
-        _try_replay("stock_real_backward_D0", _D0, "stock", "mlp_teacher",
+        _try_replay("real_backward_D0", _D0, "mlp_teacher",
                     (("hidden", _HIDDEN0),)),
-        _try_replay("whatif_megakernel_10xD0", _D_WHATIF, "auto",
+        _try_replay("whatif_megakernel_10xD0", _D_WHATIF,
                     "quadratic_whatif", (("d", _D_WHATIF),),
                     ring_dtype="bf16"),
     ]

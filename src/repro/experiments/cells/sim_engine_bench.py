@@ -144,13 +144,11 @@ def _bench_sweep(updates: int = 60, lam: int = 32, mu: int = 1,
 
 def _bench_megakernel(updates: int = 96, lam: int = 32,
                       repeats: int = 5) -> dict:
-    """Megakernel scan body vs the stock XLA gather/assemble/slice chain on
-    the same trace and staged batches (DESIGN.md §12): both sides go
-    through the driver's cached-trace + staged-minibatch path, so the
-    ratio isolates the scan-body change — the fused read-update-write
-    event with a donated (ring, state, residue) carry vs the undonated
-    ``.at[slot].set`` chain.  Also times the bf16 compressed ring (same
-    event count, half the ring bytes, error-feedback residue carried)."""
+    """The megakernel scan body (its jnp twin off the TPU) on an fp32 and
+    on the bf16 compressed ring (same event count, half the ring bytes,
+    error-feedback residue carried; DESIGN.md §12), both through the
+    driver's cached-trace + staged-minibatch path.  The bf16 row's drift
+    is against the fp32 row."""
     import jax.numpy as jnp
 
     from repro.config import RunConfig
@@ -167,10 +165,8 @@ def _bench_megakernel(updates: int = 96, lam: int = 32,
 
     rows = {}
     ref = None
-    for label, kw in (("xla_stock", {"ring_impl": "stock"}),
-                      ("megakernel", {"ring_impl": "fused"}),
-                      ("megakernel_bf16", {"ring_impl": "fused",
-                                           "ring_dtype": "bf16"})):
+    for label, kw in (("megakernel", {}),
+                      ("megakernel_bf16", {"ring_dtype": "bf16"})):
         spec = cell(**kw)
         _wait(run_spec(spec))                               # compile + warm
         t, res = _best_of(lambda s=spec: _wait(run_spec(s)), repeats)
@@ -191,8 +187,6 @@ def _bench_megakernel(updates: int = 96, lam: int = 32,
         "protocol_shape": f"1-softsync lam={lam} c={lam} mu={MU}",
         "updates": updates,
         **{f"{k}_{m}": v for k, row in rows.items() for m, v in row.items()},
-        "megakernel_vs_xla_ratio": (rows["megakernel"]["updates_per_s"]
-                                    / rows["xla_stock"]["updates_per_s"]),
         "bf16_ring_bytes_saved": (rows["megakernel"]["ring_bytes_total"]
                                   - rows["megakernel_bf16"]
                                   ["ring_bytes_total"]),
@@ -203,16 +197,19 @@ def _bench_megakernel(updates: int = 96, lam: int = 32,
 def _bench_whatif(updates: int = 96, d: int = 1_000_000,
                   repeats: int = 3) -> dict:
     """The what-if replay (in-kernel closed-form gradients, no staged
-    data) vs the staged-gradient stock path on the same quadratic problem
-    and trace.  Wall clock is ~parity (same FLOPs either way on CPU); the
+    data) vs the staged-gradient path (the problem's ``grad_fn`` on staged
+    dummy batches) on the same quadratic problem and trace.  Wall clock is ~parity (same FLOPs either way on CPU); the
     win is PEAK MEMORY — no (c, D) pulled/gradient matrices, a donated
     ring carry — which is what runs at ``configs/`` big-model D (the
     ``ring`` feasibility cell's limit study)."""
     import jax.numpy as jnp
 
     from repro.config import RunConfig
+    from repro.core.engine import replay
+    from repro.core.trace import schedule_cached
     from repro.experiments import ExperimentSpec
     from repro.experiments import run as run_spec
+    from repro.experiments.problems import get_problem
     from repro.launch.roofline import ring_bytes
 
     cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
@@ -220,7 +217,13 @@ def _bench_whatif(updates: int = 96, d: int = 1_000_000,
     args = (("d", d),)
     whatif = ExperimentSpec(run=cfg, problem="quadratic_whatif",
                             problem_args=args, steps=updates)
-    stock = whatif.replace(run=cfg.replace(ring_impl="stock"))
+    problem = get_problem("quadratic_whatif", args)
+    trace = schedule_cached(cfg, updates)
+
+    def staged():
+        return replay(trace, cfg, grad_fn=problem.grad_fn,
+                      init_params=problem.init,
+                      batch_fn=problem.batch_fn_for(cfg.minibatch))
 
     def wait_q(res):
         jnp.asarray(res.params["w"]).block_until_ready()
@@ -228,16 +231,16 @@ def _bench_whatif(updates: int = 96, d: int = 1_000_000,
 
     wait_q(run_spec(whatif))
     t_whatif, rw = _best_of(lambda: wait_q(run_spec(whatif)), repeats)
-    wait_q(run_spec(stock))
-    t_stock, rs = _best_of(lambda: wait_q(run_spec(stock)), repeats)
+    wait_q(staged())
+    t_staged, rs = _best_of(lambda: wait_q(staged()), repeats)
     K = rw.staleness["ring_buffer_K"]
     drift = float(jnp.max(jnp.abs(jnp.asarray(rw.params["w"]) -
                                   jnp.asarray(rs.params["w"]))))
     return {
         "d": d, "updates": updates, "ring_buffer_K": K,
         "whatif_updates_per_s": updates / t_whatif,
-        "staged_stock_updates_per_s": updates / t_stock,
-        "vs_staged_ratio": t_stock / t_whatif,
+        "staged_updates_per_s": updates / t_staged,
+        "vs_staged_ratio": t_staged / t_whatif,
         "max_param_drift": drift,
         "ring_bytes_total": ring_bytes(
             K, d, cfg.ring_dtype, cfg.optimizer)["total_bytes"],
@@ -273,12 +276,10 @@ def compute(updates: int = 480):
          f"speedup={sweep_row['speedup']:.1f}x "
          f"drift={sweep_row['max_param_drift']:.1e}")
     mk_row = _bench_megakernel(updates=max(24, updates // 5))
-    out["megakernel_vs_xla"] = mk_row
-    emit("sim_engine/megakernel_vs_xla",
-         f"megakernel={mk_row['megakernel_updates_per_s']:.1f}up/s "
-         f"xla={mk_row['xla_stock_updates_per_s']:.1f}up/s",
-         f"ratio={mk_row['megakernel_vs_xla_ratio']:.2f}x "
-         f"drift={mk_row['megakernel_max_param_drift']:.1e}")
+    out["megakernel"] = mk_row
+    emit("sim_engine/megakernel",
+         f"{mk_row['megakernel_updates_per_s']:.1f}up/s",
+         f"ring_bytes={mk_row['megakernel_ring_bytes_total']}")
     emit("sim_engine/megakernel_bf16_ring",
          f"{mk_row['megakernel_bf16_updates_per_s']:.1f}up/s",
          f"ring_bytes={mk_row['megakernel_bf16_ring_bytes_total']} "
@@ -289,7 +290,7 @@ def compute(updates: int = 480):
     emit("sim_engine/whatif_quadratic",
          f"{whatif_row['whatif_updates_per_s']:.1f}up/s at "
          f"D={whatif_row['d']}",
-         f"staged={whatif_row['staged_stock_updates_per_s']:.1f}up/s "
+         f"staged={whatif_row['staged_updates_per_s']:.1f}up/s "
          f"ratio={whatif_row['vs_staged_ratio']:.2f}x "
          f"ring={whatif_row['ring_bytes_total']/1e6:.0f}MB")
     return [], out
@@ -307,8 +308,8 @@ register_cell(Cell(
         Claim("sweep_drift_small",
               lambda d: (d["sweep_batched_vs_sequential"]["max_param_drift"]
                          < 1e-3)),
-        Claim("megakernel_drift_small",
-              lambda d: (d["megakernel_vs_xla"]["megakernel_max_param_drift"]
+        Claim("megakernel_bf16_drift_small",
+              lambda d: (d["megakernel"]["megakernel_bf16_max_param_drift"]
                          < 1e-3)),
         Claim("whatif_drift_small",
               lambda d: d["whatif_quadratic"]["max_param_drift"] < 1e-3),

@@ -236,10 +236,10 @@ class _Job:
         return (id(self.problem), self.steps, self.trace.c, self.trace.mode,
                 opt, self.spec.run.minibatch, self.spec.eval_every,
                 self.trace.valid is not None,
-                # lanes must agree on ring storage/impl: a bf16 lane's
-                # carry has a different dtype + residue layout, and
-                # replay_batch rejects mixed groups
-                self.spec.run.ring_impl, self.spec.run.ring_dtype)
+                # lanes must agree on ring storage: a bf16 lane's carry
+                # has a different dtype + residue layout, and replay_batch
+                # rejects mixed groups
+                self.spec.run.ring_dtype)
 
     def run_single(self) -> RunResult:
         if self.engine == "measure":

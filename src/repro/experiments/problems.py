@@ -152,7 +152,7 @@ class QuadraticProblem:
     device inside whichever program needs them — the problem stores no
     (D,) array, and each "ps" device of an SPMD replay computes only its
     own slice.  The ``grad_fn``/``batch_fn_for`` twins keep the problem
-    valid on every non-what-if path (stock impl, legacy oracle, sharded
+    valid on every non-what-if path (staged gradients, legacy oracle, sharded
     traces): the batch is a 1-element dummy the gradient ignores.
 
     ``shards`` = S > 1 lays ``init`` out over the "ps" devices of

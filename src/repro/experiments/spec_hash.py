@@ -102,12 +102,19 @@ def canonical_value(x: Any) -> Any:
     return x
 
 
+# RunConfig fields since removed, at the value every stored echo holds:
+# pruned like a default, so records written before the removal keep
+# their hashes
+_RETIRED_RUN_DEFAULTS = {"ring_impl": "auto"}
+
+
 @functools.lru_cache(maxsize=1)
 def _run_defaults() -> Dict[str, Any]:
     import dataclasses
 
     from repro.config import RunConfig
-    return canonical_value(dataclasses.asdict(RunConfig()))
+    return {**_RETIRED_RUN_DEFAULTS,
+            **canonical_value(dataclasses.asdict(RunConfig()))}
 
 
 @functools.lru_cache(maxsize=1)

@@ -13,24 +13,18 @@ same event body, making the optimized path the measured path.
 from repro.optim.spec import (KERNEL_OPTIMIZERS, OPTIMIZERS, RoundFold,
                               UpdateSpec, init_state, sequential_fold,
                               spec_from_run, update_event)
-from repro.optim.backends import (BACKENDS, RING_DTYPES, RING_IMPLS,
-                                  apply_event_flat, apply_event_ring,
-                                  apply_event_ring_whatif,
-                                  apply_event_sharded, apply_round_folded,
-                                  apply_single, apply_update,
-                                  apply_update_tree, apply_update_flat,
-                                  combine_spmd, resolve_ring_impl,
-                                  ring_all_gather, sgd_step)
+from repro.optim.backends import (BACKENDS, apply_event_flat,
+                                  apply_event_ring, apply_event_ring_whatif,
+                                  apply_round_folded, apply_single,
+                                  apply_update, apply_update_tree,
+                                  apply_update_flat, combine_spmd, sgd_step)
 from repro.optim import flatten  # noqa: F401
 
 __all__ = [
     "OPTIMIZERS", "KERNEL_OPTIMIZERS", "BACKENDS",
-    "RING_IMPLS", "RING_DTYPES",
     "UpdateSpec", "RoundFold", "init_state", "spec_from_run",
     "update_event", "sequential_fold",
     "apply_update", "apply_update_tree", "apply_update_flat",
     "apply_event_flat", "apply_event_ring", "apply_event_ring_whatif",
-    "apply_event_sharded", "apply_single",
-    "apply_round_folded", "resolve_ring_impl", "sgd_step",
-    "combine_spmd", "ring_all_gather",
+    "apply_single", "apply_round_folded", "sgd_step", "combine_spmd",
 ]

@@ -177,33 +177,11 @@ def apply_event_flat(spec: UpdateSpec, w, s, g, coef, lrs,
     return w, s
 
 
-RING_IMPLS = ("auto", "pallas", "fused", "stock")
-RING_DTYPES = ("fp32", "bf16")
-
-
-def resolve_ring_impl(impl: str, spec: UpdateSpec) -> str:
-    """Resolve a RunConfig's ``ring_impl`` axis to a concrete scan body.
-
-    ``auto`` picks the Pallas megakernel on TPU and its fused jnp twin
-    everywhere else (same math, no interpret-mode launch overhead on the
-    CPU hot loop).  Optimizers without a flat event path (adamw) always
-    take the stock pytree body — their RunConfig validation already
-    rejected a bf16 ring."""
-    if impl not in RING_IMPLS:
-        raise ValueError(f"unknown ring_impl {impl!r}: expected one of "
-                         f"{RING_IMPLS}")
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "fused"
-    if not spec.kernel_supported:
-        return "stock"
-    return impl
-
-
 def apply_event_ring(spec: UpdateSpec, ring, s, res, g, coef, lrs,
                      prev, slot, mode: str = "combine"):
     """ONE fused ring event on flat buffers — the jnp twin of the Pallas
     replay megakernel (``kernels/replay_ring.ring_apply``), and the
-    engine's ``ring_impl="fused"`` scan body.
+    engine's ``"fused"`` event body off the TPU.
 
     ``ring``: (K, Dp) in the ring dtype (fp32 or bf16); ``s``: (Dp,) fp32
     optimizer state or None (sgd); ``res``: (Dp,) fp32 error-feedback
@@ -212,7 +190,8 @@ def apply_event_ring(spec: UpdateSpec, ring, s, res, g, coef, lrs,
     ``apply_event_flat`` are ``q(w) + (w − q(w)) = w``, so with a bf16 ring
     the only approximation anywhere is gradients being *evaluated* at
     quantized snapshots (DESIGN.md §12).  With an fp32 ring the casts are
-    no-ops and this is bitwise the stock gather/update/set body."""
+    no-ops and this is bitwise the gather → ``apply_event_flat`` → row
+    write chain."""
     w = ring[prev].astype(jnp.float32)
     if res is not None:
         w = w + res
@@ -253,56 +232,9 @@ def apply_event_ring_whatif(spec: UpdateSpec, ring, s, res, a, wstar, ts,
     return ring, s, res
 
 
-def apply_event_sharded(spec: UpdateSpec, w, s, g, coef, lrs,
-                        mode: str = "combine"):
-    """:func:`apply_event_flat` vmapped over a leading shard axis — the
-    sharded-PS replay's per-event apply (DESIGN.md §6).
-
-    ``w``: (S, Dp) per-shard weight rows; ``s``: (S, Dp) state rows or None
-    (sgd); ``g``: (S, c, Dp) per-shard gradient slices; ``coef``/``lrs``:
-    (c,) shared across shards (every shard folds the same c pushes — the
-    update events are aligned, only the *pulled* slices differ).  Because
-    ``update_event`` is elementwise, the per-shard apply is exactly the
-    shard slice of the unsharded apply (partition invariance, pinned by
-    ``tests/test_topology.py``)."""
-    if not spec.kernel_supported:
-        raise ValueError(f"{spec.optimizer!r} has no flat event path")
-    fn = jax.vmap(
-        lambda ws, ss, gs: apply_event_flat(spec, ws, ss, gs, coef, lrs,
-                                            mode),
-        in_axes=(0, None if s is None else 0, 0))
-    return fn(w, s, g)
-
-
 # ---------------------------------------------------------------------------
 # SPMD replay collectives (DESIGN.md §13)
 # ---------------------------------------------------------------------------
-def ring_all_gather(x, axis_name: str, size: int):
-    """``lax.all_gather(x, axis_name)`` rebuilt from size − 1 neighbor
-    ``ppermute`` exchanges — the parameter-server ring-pull pattern, where
-    each PS device forwards the slice it just received to its neighbor.
-
-    Returns the (size, *x.shape) stack in device order.  Pure data
-    movement (a permutation, no arithmetic), so the result is **bitwise**
-    equal to ``lax.all_gather`` (pinned by ``tests/test_spmd.py``); it
-    trades one fused collective for S − 1 dependent hops, so the engine
-    uses it only when asked (``spmd_assembly='ppermute'``)."""
-    if size == 1:
-        return x[None]
-    perm = [(i, (i + 1) % size) for i in range(size)]
-    chunks = [x]
-    cur = x
-    for _ in range(size - 1):
-        cur = jax.lax.ppermute(cur, axis_name, perm)
-        chunks.append(cur)
-    # chunk k on device i originated at device (i − k) mod size; reorder so
-    # position s holds device s's slice, matching all_gather
-    stacked = jnp.stack(chunks)
-    i = jax.lax.axis_index(axis_name)
-    order = jnp.mod(i - jnp.arange(size), size)
-    return jnp.take(stacked, order, axis=0)
-
-
 def combine_spmd(g, coef, axis_name: str):
     """The combine-mode sum ĝ = Σ_j coef_j·g_j with the slot axis split
     over ``axis_name``: each learner device reduces its local slot block,

@@ -89,22 +89,11 @@ def shard_pack(flat: jax.Array, shards: int, width: int) -> jax.Array:
     """(D,) flat buffer → (S, Dp) per-shard rows, zero-padding the last
     shard to the equal width Dp = ⌈D/S⌉ (core/topology.py layout).  Zeros
     are inert through sgd/momentum/adagrad events, so packing is pure
-    layout — ``shard_unpack`` is its exact inverse."""
+    layout: flattening the rows and slicing ``[..., :D]`` inverts it."""
     d = flat.shape[-1]
     return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1)
                    + [(0, shards * width - d)]).reshape(
         flat.shape[:-1] + (shards, width))
-
-
-def shard_pack_grads(g: jax.Array, shards: int, width: int) -> jax.Array:
-    """(c, D) stacked gradients → (S, c, Dp): the per-shard gradient slices
-    the vmapped shard apply consumes."""
-    return jnp.moveaxis(shard_pack(g, shards, width), -2, 0)
-
-
-def shard_unpack(mat: jax.Array, dim: int) -> jax.Array:
-    """(S, Dp) per-shard rows → the (D,) flat buffer (padding dropped)."""
-    return mat.reshape(mat.shape[:-2] + (-1,))[..., :dim]
 
 
 def flat_to_tree(flat: jax.Array, layout: TreeLayout):

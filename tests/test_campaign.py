@@ -67,7 +67,6 @@ _RUN_FLIPS = {
     "groups": {"groups": 1},
     "shard_pull_jitter": {"shard_pull_jitter": 0.5},
     "ring_dtype": {"ring_dtype": "bf16"},
-    "ring_impl": {"ring_impl": "fused"},
     "placement": {"placement": "spmd"},
     "spmd_learners": {"spmd_learners": 1, "extra": {"placement": "spmd"}},
     "membership": {"membership": MembershipTimeline(((1.0, 0, "crash"),))},
@@ -209,6 +208,18 @@ def test_hash_invariant_to_default_materialization():
     del trimmed["run"]["ref_batch"]       # pretend ref_batch predates echo
     del trimmed["eval_every"]
     assert spec_hash_from_echo(echo) == spec_hash_from_echo(trimmed)
+
+
+def test_retired_field_echo_keeps_its_hash():
+    # records stored before RunConfig lost ring_impl echo it at its only
+    # stored value; they must keep the hash they were filed under
+    spec = ExperimentSpec(run=RunConfig(n_learners=4), steps=50)
+    echo = spec.echo()
+    old = copy.deepcopy(echo)
+    old["run"]["ring_impl"] = "auto"
+    assert spec_hash_from_echo(old) == spec_hash_from_echo(echo)
+    old["run"]["ring_impl"] = "pallas"      # not the default: a new address
+    assert spec_hash_from_echo(old) != spec_hash_from_echo(echo)
 
 
 def test_default_serving_fleet_is_not_pruned_to_none():

@@ -6,14 +6,15 @@ Equivalence contract, pinned here:
   ``ring_apply_whatif`` (interpret mode on CPU) is BITWISE its fused jnp
   twin — and with an fp32 ring the twin is bitwise the flat
   ``apply_event_flat`` reference.
-* **Engine level**: the fused scan body equals the stock pytree body
-  bitwise on the trivial topology (the casts are no-ops); the Pallas body
-  equals the fused body bitwise for stateless/adagrad cells and to fp32
-  accumulation tolerance on momentum cells (XLA forms FMAs differently
-  per compiled program at some ring depths — ~1 ulp/event).
-* **Sharded**: fused ≡ pallas bitwise; vs the stock sharded body the
-  combine einsum is phrased on (S, c, Dp) operands, which XLA lowers with
-  different rounding, so agreement is fp32-tolerance, not bitwise.
+* **Engine level**: the fused scan body equals the pytree body bitwise on
+  the trivial topology (the casts are no-ops); the Pallas body equals the
+  fused body bitwise for stateless/adagrad cells and to fp32 accumulation
+  tolerance on momentum cells (XLA forms FMAs differently per compiled
+  program at some ring depths — ~1 ulp/event).  The body a replay runs is
+  ``engine._event_body``'s; tests hold it to one body by patching that.
+* **Sharded**: fused ≡ pallas bitwise; against a per-event reference in
+  this file (gather per shard → ``apply_event_flat`` → row write) to fp32
+  tolerance.
 * **bf16 ring**: the fp32 master chain (bf16 row + error-feedback
   residue) reconstructs the exact fp32 weights per event; end-to-end
   drift vs an fp32 ring stays within the documented tolerance because
@@ -28,13 +29,14 @@ import numpy as np
 import pytest
 
 from repro.config import RunConfig
-from repro import telemetry
+from repro import optim, telemetry
 from repro.core import engine, replay, schedule
 from repro.core.engine import _materialize_batches, replay_batch
 from repro.core.trace import schedule_cached
 from repro.kernels import replay_ring
 from repro.membership import MembershipTimeline
-from repro.optim import UpdateSpec
+from repro.optim import UpdateSpec, apply_update_tree, init_state
+from repro.optim import flatten
 from repro.optim.backends import (apply_event_flat, apply_event_ring,
                                   apply_event_ring_whatif)
 
@@ -69,10 +71,18 @@ def _batch_fn(l, i):
     return X[idx], Y[idx]
 
 
-def _run(cfg, steps=24, **kw):
-    trace = schedule(cfg, steps)
-    return replay(trace, cfg, grad_fn=GRAD_FN, init_params=INIT,
-                  batch_fn=_batch_fn, **kw)
+def _replay_with(body, trace, cfg, **kw):
+    """``replay`` with the event body held to ``body`` (None: the body the
+    engine picks itself)."""
+    with pytest.MonkeyPatch.context() as mp:
+        if body is not None:
+            mp.setattr(engine, "_event_body", lambda spec: body)
+        return replay(trace, cfg, **kw)
+
+
+def _run(cfg, steps=24, body=None, **kw):
+    return _replay_with(body, schedule(cfg, steps), cfg, grad_fn=GRAD_FN,
+                        init_params=INIT, batch_fn=_batch_fn, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -119,7 +129,7 @@ def test_event_megakernel_bitwise_vs_twin(optimizer, mode, ring_dtype):
 @pytest.mark.parametrize("mode", ["combine"])
 @pytest.mark.parametrize("optimizer", ["sgd", "momentum"])
 def test_event_fp32_megakernel_bitwise_vs_flat_reference(optimizer, mode):
-    """With an fp32 ring the megakernel event IS the stock chain: gather
+    """With an fp32 ring the megakernel event IS the plain chain: gather
     row, ``apply_event_flat``, ``.at[slot].set`` — bitwise in combine mode
     (the engine's mode everywhere).  Sequential mode re-associates the
     per-slot FMA chain differently across the two program phrasings, so
@@ -263,26 +273,41 @@ def test_dispatch_counters_and_interpret_default():
 
 def test_engine_pallas_path_dispatches_kernel():
     cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=4,
-                    minibatch=8, base_lr=0.05, optimizer="sgd", seed=3,
-                    ring_impl="pallas")
+                    minibatch=8, base_lr=0.05, optimizer="sgd", seed=3)
     before = replay_ring.pallas_dispatches
-    _run(cfg, steps=6)
+    _run(cfg, steps=6, body="pallas")
     assert replay_ring.pallas_dispatches > before
 
 
 # ---------------------------------------------------------------------------
-# engine level: fused ≡ stock bitwise on the trivial topology
+# engine level: the body decision
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend,optimizer,want", [
+    ("tpu", "momentum", "pallas"), ("cpu", "momentum", "fused"),
+    ("tpu", "adamw", "pytree")])
+def test_event_body_decision(monkeypatch, backend, optimizer, want):
+    """The Pallas body on a TPU, its jnp twin elsewhere, the pytree body
+    for an optimizer with no flat event path whatever the backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert engine._event_body(UpdateSpec(optimizer=optimizer)) == want
+
+
+# ---------------------------------------------------------------------------
+# engine level: fused ≡ pytree bitwise on the trivial topology
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("protocol,n", [("async", 1), ("softsync", 2),
                                         ("hardsync", 1)])
 @pytest.mark.parametrize("optimizer", ["sgd", "momentum", "adagrad"])
 def test_engine_fused_bitwise_vs_stock(protocol, n, optimizer):
-    kw = dict(protocol=protocol, n_softsync=n, n_learners=8, minibatch=8,
-              base_lr=0.05, lr_policy="staleness_inverse",
-              optimizer=optimizer, seed=11)
-    fused = _run(RunConfig(ring_impl="fused", **kw))
-    stock = _run(RunConfig(ring_impl="stock", **kw))
-    _bw(fused.params["w"], stock.params["w"])
+    """The flat fused body against the pytree body, which applies
+    ``apply_update_tree`` to the params pytree."""
+    cfg = RunConfig(protocol=protocol, n_softsync=n, n_learners=8,
+                    minibatch=8, base_lr=0.05,
+                    lr_policy="staleness_inverse", optimizer=optimizer,
+                    seed=11)
+    fused = _run(cfg, body="fused")
+    pytree = _run(cfg, body="pytree")
+    _bw(fused.params["w"], pytree.params["w"])
 
 
 def test_engine_fused_bitwise_vs_stock_elastic_mask():
@@ -290,19 +315,62 @@ def test_engine_fused_bitwise_vs_stock_elastic_mask():
     both scan bodies."""
     churn = MembershipTimeline(((1.0, 3, "crash"), (2.5, 3, "join"),
                                 (4.0, 6, "leave")))
-    kw = dict(protocol="softsync", n_softsync=2, n_learners=8, minibatch=8,
-              base_lr=0.05, optimizer="momentum", seed=13, membership=churn)
-    fused = _run(RunConfig(ring_impl="fused", **kw))
-    stock = _run(RunConfig(ring_impl="stock", **kw))
-    _bw(fused.params["w"], stock.params["w"])
+    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer="momentum",
+                    seed=13, membership=churn)
+    _bw(_run(cfg, body="fused").params["w"],
+        _run(cfg, body="pytree").params["w"])
 
 
 def test_engine_fused_bitwise_vs_stock_grouped():
-    kw = dict(protocol="softsync", n_softsync=2, n_learners=8, minibatch=8,
-              base_lr=0.05, optimizer="momentum", seed=5, groups=4)
-    fused = _run(RunConfig(ring_impl="fused", **kw))
-    stock = _run(RunConfig(ring_impl="stock", **kw))
-    _bw(fused.params["w"], stock.params["w"])
+    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer="momentum", seed=5,
+                    groups=4)
+    _bw(_run(cfg, body="fused").params["w"],
+        _run(cfg, body="pytree").params["w"])
+
+
+def _adamw_reference(trace, cfg):
+    """adamw replayed event by event off the trace: each slot's gradient
+    at the snapshot it pulled, then one jitted ``apply_update_tree`` with
+    the trace's coefficients (zero on cancelled slots)."""
+    spec = optim.spec_from_run(cfg)
+    apply = jax.jit(functools.partial(apply_update_tree, spec,
+                                      mode=trace.mode))
+    coefs = (trace.event_coef() if trace.valid is not None
+             else np.full((trace.steps, trace.c), 1.0 / trace.c))
+    params, state = INIT, init_state(spec, INIT)
+    snaps = [params]
+    for j in range(trace.steps):
+        grads = [GRAD_FN(snaps[trace.pulled_ts[j, i]],
+                         _batch_fn(int(trace.learner[j, i]),
+                                   int(trace.mb_index[j, i])))
+                 for i in range(trace.c)]
+        params, state = apply(params, state, grads,
+                              coef=jnp.asarray(coefs[j], jnp.float32),
+                              lrs=jnp.asarray(trace.lrs[j], jnp.float32))
+        snaps.append(params)
+    return params
+
+
+@pytest.mark.parametrize("churn", [(), ((1.0, 3, "crash"), (2.5, 3, "join"),
+                                        (4.0, 6, "leave"))],
+                         ids=["plain", "elastic"])
+def test_engine_adamw_pytree_matches_event_reference(churn):
+    """adamw, which has no flat event path, replays through the pytree
+    body: the weights equal ``apply_update_tree`` applied event by event,
+    with cancelled slots folded at coefficient 0."""
+    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer="adamw", seed=13,
+                    membership=MembershipTimeline(churn))
+    trace = schedule(cfg, 24)
+    assert (trace.valid is not None) == bool(churn)
+    assert engine._event_body(optim.spec_from_run(cfg)) == "pytree"
+    got = replay(trace, cfg, grad_fn=GRAD_FN, init_params=INIT,
+                 batch_fn=_batch_fn)
+    np.testing.assert_allclose(np.asarray(got.params["w"]),
+                               np.asarray(_adamw_reference(trace, cfg)["w"]),
+                               rtol=0, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -310,11 +378,10 @@ def test_engine_fused_bitwise_vs_stock_grouped():
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("optimizer", ["sgd", "adagrad"])
 def test_engine_pallas_bitwise_vs_fused(optimizer):
-    kw = dict(protocol="softsync", n_softsync=2, n_learners=8, minibatch=8,
-              base_lr=0.05, optimizer=optimizer, seed=7)
-    pallas = _run(RunConfig(ring_impl="pallas", **kw))
-    fused = _run(RunConfig(ring_impl="fused", **kw))
-    _bw(pallas.params["w"], fused.params["w"])
+    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer=optimizer, seed=7)
+    _bw(_run(cfg, body="pallas").params["w"],
+        _run(cfg, body="fused").params["w"])
 
 
 def test_engine_pallas_vs_fused_momentum_tolerance():
@@ -322,10 +389,10 @@ def test_engine_pallas_vs_fused_momentum_tolerance():
     programs (XLA forms the v-update FMA differently at some ring
     depths); the event-level test above is bitwise, so pin the
     engine-level agreement at fp32 accumulation tolerance."""
-    kw = dict(protocol="softsync", n_softsync=4, n_learners=8, minibatch=8,
-              base_lr=0.05, optimizer="momentum", seed=7)
-    pallas = _run(RunConfig(ring_impl="pallas", **kw))
-    fused = _run(RunConfig(ring_impl="fused", **kw))
+    cfg = RunConfig(protocol="softsync", n_softsync=4, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer="momentum", seed=7)
+    pallas = _run(cfg, body="pallas")
+    fused = _run(cfg, body="fused")
     np.testing.assert_allclose(np.asarray(pallas.params["w"]),
                                np.asarray(fused.params["w"]),
                                rtol=0, atol=1e-5)
@@ -334,33 +401,75 @@ def test_engine_pallas_vs_fused_momentum_tolerance():
 # ---------------------------------------------------------------------------
 # sharded topology
 # ---------------------------------------------------------------------------
+def _flat_reference(trace, cfg):
+    """The replay of a (possibly sharded) trace as the plain per-event
+    chain in a jitted ``lax.scan``: each slot's weights gathered shard by
+    shard from the rows its shards pulled, the gradients, then gather the
+    newest row → ``apply_event_flat`` → write the next row."""
+    spec = optim.spec_from_run(cfg)
+    S, c = trace.topology.shards, trace.c
+    K = trace.max_staleness + 1
+    layout = flatten.layout_of(INIT)
+    D = layout.total
+    Dp = trace.topology.padded_width(D)
+    ts = (trace.pulled_ts[..., None] if trace.shard_pulled_ts is None
+          else trace.shard_pulled_ts)
+    steps = np.arange(trace.steps)
+    xs = {"ts": jnp.asarray(ts % K, jnp.int32),
+          "prev": jnp.asarray(steps % K, jnp.int32),
+          "slot": jnp.asarray((steps + 1) % K, jnp.int32),
+          "lrs": jnp.asarray(trace.lrs, jnp.float32),
+          "batch": _materialize_batches(trace, _batch_fn)}
+    coef = jnp.full((c,), 1.0 / c, jnp.float32)
+    w0 = flatten.pad_flat(flatten.tree_to_flat(INIT), S * Dp)
+    s0 = None if not spec.state_keys else jnp.zeros_like(w0)
+
+    def event(carry, x):
+        ring, s = carry
+        pulled = jnp.stack([jnp.concatenate(
+            [ring[x["ts"][i, j]][j * Dp:(j + 1) * Dp] for j in range(S)])
+            for i in range(c)])[:, :D]
+        g = jax.vmap(GRAD_FN)(flatten.batched_flat_to_tree(pulled, layout),
+                              x["batch"])
+        g = flatten.pad_flat(flatten.batched_tree_to_flat(g), S * Dp)
+        w, s = apply_event_flat(spec, ring[x["prev"]], s, g, coef,
+                                x["lrs"], trace.mode)
+        return (ring.at[x["slot"]].set(w), s), None
+
+    run = jax.jit(lambda carry, xs: jax.lax.scan(event, carry, xs)[0])
+    ring, _ = run((jnp.broadcast_to(w0, (K, S * Dp)), s0), xs)
+    return flatten.flat_to_tree(ring[trace.steps % K][:D], layout)
+
+
 def test_engine_sharded_fused_bitwise_vs_pallas_and_tol_vs_stock():
-    kw = dict(protocol="softsync", n_softsync=2, n_learners=8, minibatch=8,
-              base_lr=0.05, optimizer="momentum", seed=19, shards=2)
-    fused = _run(RunConfig(ring_impl="fused", **kw))
-    pallas = _run(RunConfig(ring_impl="pallas", **kw))
-    stock = _run(RunConfig(ring_impl="stock", **kw))
+    cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
+                    minibatch=8, base_lr=0.05, optimizer="momentum",
+                    seed=19, shards=2)
+    trace = schedule(cfg, 24)
+    assert trace.topology.shards == 2
+    fused = _run(cfg, body="fused")
+    pallas = _run(cfg, body="pallas")
     _bw(fused.params["w"], pallas.params["w"])
-    # stock shard body phrases the combine einsum on (S, c, Dp) operands —
-    # XLA lowers that with different rounding (~1 ulp/event), so the
-    # cross-body contract is fp32 tolerance, not bitwise (DESIGN.md §12)
+    # the fused body runs one event over the concatenated shard rows; the
+    # reference gathers per shard in its own program, which XLA may round
+    # differently (~1 ulp/event), so the contract is fp32 tolerance
     np.testing.assert_allclose(np.asarray(fused.params["w"]),
-                               np.asarray(stock.params["w"]),
+                               np.asarray(_flat_reference(trace, cfg)["w"]),
                                rtol=0, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
 # bf16 compressed ring, engine level
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("ring_impl", ["fused", "pallas"])
-def test_engine_bf16_ring_tolerance(ring_impl):
+@pytest.mark.parametrize("body", ["fused", "pallas"])
+def test_engine_bf16_ring_tolerance(body):
     """End-to-end bf16-ring drift vs the fp32 ring: gradients get
     evaluated at quantized snapshots, nothing else changes — documented
     tolerance ~1e-3 on O(1) weights over 24 steps."""
     kw = dict(protocol="softsync", n_softsync=2, n_learners=8, minibatch=8,
               base_lr=0.05, optimizer="momentum", seed=23)
-    bf = _run(RunConfig(ring_impl=ring_impl, ring_dtype="bf16", **kw))
-    fp = _run(RunConfig(ring_impl=ring_impl, ring_dtype="fp32", **kw))
+    bf = _run(RunConfig(ring_dtype="bf16", **kw), body=body)
+    fp = _run(RunConfig(ring_dtype="fp32", **kw), body=body)
     np.testing.assert_allclose(np.asarray(bf.params["w"]),
                                np.asarray(fp.params["w"]),
                                rtol=0, atol=5e-3)
@@ -379,19 +488,21 @@ def _whatif_operands(d=600, seed=0):
     return a, wstar
 
 
-def _whatif_run(cfg, steps=24, impl=None, d=600, **kw):
+def _whatif_run(cfg, steps=24, body=None, d=600, **kw):
+    """The what-if replay; the pytree body computes the same closed-form
+    gradients as staged ones (``grad_fn`` on dummy batches)."""
     a, wstar = _whatif_operands(d)
-    cfg = cfg if impl is None else cfg.replace(ring_impl=impl)
     trace = schedule(cfg, steps)
     init = {"w": jnp.zeros((a.shape[0],), jnp.float32)}
-    if cfg.ring_impl == "stock":
+    if body == "pytree":
         def grad_fn(p, b):
             return {"w": a * (p["w"] - wstar)}
-        return replay(trace, cfg, grad_fn=grad_fn, init_params=init,
-                      batch_fn=lambda l, i: np.zeros((1,), np.float32))
-    return replay(trace, cfg, init_params=init,
-                  flat_grad=("quadratic", lambda pos: (a[pos], wstar[pos])),
-                  **kw)
+        return _replay_with(body, trace, cfg, grad_fn=grad_fn,
+                            init_params=init,
+                            batch_fn=lambda l, i: np.zeros((1,), np.float32))
+    return _replay_with(body, trace, cfg, init_params=init,
+                        flat_grad=("quadratic",
+                                   lambda pos: (a[pos], wstar[pos])), **kw)
 
 
 def _whatif_cfg(c=4, ring_dtype="fp32", **kw):
@@ -408,8 +519,8 @@ def test_whatif_pallas_bitwise_vs_fused(ring_dtype, c):
     """The Pallas body, which carries the ring in the kernel's tiles and
     updates it in place, is bitwise the fused twin's flat carry."""
     cfg = _whatif_cfg(c, ring_dtype)
-    _bw(_whatif_run(cfg, impl="pallas").params["w"],
-        _whatif_run(cfg, impl="fused").params["w"])
+    _bw(_whatif_run(cfg, body="pallas").params["w"],
+        _whatif_run(cfg, body="fused").params["w"])
 
 
 def test_whatif_pallas_bitwise_vs_fused_across_segments():
@@ -418,8 +529,8 @@ def test_whatif_pallas_bitwise_vs_fused_across_segments():
     per ring row."""
     cfg = _whatif_cfg(4, "bf16")
 
-    def run(impl):
-        return _whatif_run(cfg, impl=impl, d=40_000, eval_every=8,
+    def run(body):
+        return _whatif_run(cfg, body=body, d=40_000, eval_every=8,
                            eval_fn=lambda p: {"w": np.asarray(p["w"])})
     pallas, fused = run("pallas"), run("fused")
     assert len(pallas.history) == len(fused.history) == 3
@@ -436,26 +547,26 @@ def test_whatif_tiled_carry_counter(protocol, tiled):
     not count."""
     cfg = RunConfig(protocol=protocol, n_softsync=1, n_learners=4,
                     minibatch=1, base_lr=0.02, optimizer="momentum",
-                    seed=29, ring_impl="pallas")
+                    seed=29)
     assert (schedule(cfg, 8).max_staleness + 1 >= 2) is tiled
     engine._make_scan_fn.cache_clear()          # trace the scan afresh
     before = telemetry.counters().get(engine.TILED_CARRY_BUILDS, 0)
-    _whatif_run(cfg, steps=8)
+    _whatif_run(cfg, steps=8, body="pallas")
     after = telemetry.counters().get(engine.TILED_CARRY_BUILDS, 0)
     assert after - before == int(tiled)
 
 
 def test_whatif_matches_staged_stock():
-    """The in-kernel closed-form gradients equal the staged twin to fp32
-    accumulation tolerance (the streamed fori accumulation orders the
-    c-sum differently from the einsum)."""
+    """The in-kernel closed-form gradients equal staged gradients through
+    the pytree body to fp32 accumulation tolerance (the streamed fori
+    accumulation orders the c-sum differently from the einsum)."""
     cfg = RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
                     minibatch=1, base_lr=0.02, optimizer="momentum",
                     seed=29)
-    whatif = _whatif_run(cfg, steps=64, impl="fused")
-    stock = _whatif_run(cfg, steps=64, impl="stock")
+    whatif = _whatif_run(cfg, steps=64, body="fused")
+    staged = _whatif_run(cfg, steps=64, body="pytree")
     np.testing.assert_allclose(np.asarray(whatif.params["w"]),
-                               np.asarray(stock.params["w"]),
+                               np.asarray(staged.params["w"]),
                                rtol=0, atol=1e-4)
 
 
@@ -478,7 +589,7 @@ def test_replay_batches_equals_batch_fn():
 def test_replay_batch_fused_matches_singles():
     cfgs = [RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
                       minibatch=8, base_lr=0.05, optimizer="momentum",
-                      seed=s, ring_impl="fused") for s in (41, 43)]
+                      seed=s) for s in (41, 43)]
     traces = [schedule(c, 16) for c in cfgs]
     batch = replay_batch(traces, cfgs, grad_fn=GRAD_FN, init_params=INIT,
                          batch_fns=[_batch_fn, _batch_fn])
@@ -492,9 +603,9 @@ def test_replay_batch_fused_matches_singles():
 
 def test_replay_batch_rejects_mixed_ring_config():
     cfgs = [RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
-                      minibatch=8, seed=41, ring_impl="fused"),
+                      minibatch=8, seed=41),
             RunConfig(protocol="softsync", n_softsync=2, n_learners=8,
-                      minibatch=8, seed=43, ring_impl="stock")]
+                      minibatch=8, seed=43, ring_dtype="bf16")]
     traces = [schedule(c, 8) for c in cfgs]
     with pytest.raises(ValueError, match="ring"):
         replay_batch(traces, cfgs, grad_fn=GRAD_FN, init_params=INIT,
@@ -502,8 +613,6 @@ def test_replay_batch_rejects_mixed_ring_config():
 
 
 @pytest.mark.parametrize("bad", [dict(ring_dtype="fp16"),
-                                 dict(ring_impl="xla"),
-                                 dict(ring_dtype="bf16", ring_impl="stock"),
                                  dict(ring_dtype="bf16", optimizer="adamw")])
 def test_ring_config_validation(bad):
     with pytest.raises(ValueError):

@@ -7,11 +7,10 @@ The exactly-testable contract of ``repro.serve``:
 * a ``staleness`` policy's budget is never exceeded at any request (the
   refresh-before-request tie rule makes this exact, not probabilistic);
 * attaching a fleet never perturbs training: the arrival schedule AND the
-  replayed parameters are bitwise-identical to a no-serving run, on every
-  ring impl, under learner churn and replica churn alike;
-* every guardrail (spmd, sharded stock ring, batched replay, the legacy
-  oracle, missing serve hooks) errors actionably instead of silently
-  degrading.
+  replayed parameters are bitwise-identical to a no-serving run, under
+  learner churn and replica churn alike;
+* every guardrail (spmd, batched replay, the legacy oracle, missing serve
+  hooks) errors actionably instead of silently degrading.
 """
 
 import dataclasses
@@ -110,9 +109,7 @@ def test_runconfig_serving_guardrails():
         RunConfig(serving="fleet")
     with pytest.raises(ValueError, match="placement='spmd'"):
         _run(placement="spmd")
-    with pytest.raises(ValueError, match="stock sharded"):
-        _run(shards=2, ring_impl="stock")
-    _run(shards=2)          # fused sharded ring serves fine
+    _run(shards=2)          # the sharded ring serves fine
 
 
 # ---------------------------------------------------------------------------
@@ -212,13 +209,20 @@ def test_replica_churn_drops_requests_only_while_fleet_dead():
 # ---------------------------------------------------------------------------
 # the replay serving lane
 # ---------------------------------------------------------------------------
-def test_published_row_bitwise_equals_trained_weights(prob):
+# the component exported: the model's first weight, and with 2 PS shards
+# its last, which the last shard holds
+@pytest.mark.parametrize("shards,leaf,at", [(1, "w1", (0, 0)),
+                                            (2, "w2", (-1, -1))])
+def test_published_row_bitwise_equals_trained_weights(shards, leaf, at,
+                                                      prob):
     """The tentpole contract: the snapshot serving version v is bit-for-bit
     the trained weights after v updates — checked by replaying each prefix
     of the (serving-free twin of the) trace and comparing a raw weight
-    component exported through serve_eval_fn."""
+    component exported through serve_eval_fn.  With PS shards a ring row
+    is the shard slices concatenated, and the published row is still the
+    whole model."""
     cfg = _run(PublicationPolicy(kind="every_n", every=1),
-               protocol="async", ring_impl="stock")
+               protocol="async", shards=shards)
     steps = 10
     trace = schedule(cfg, steps)
     sv = trace.serving
@@ -226,11 +230,11 @@ def test_published_row_bitwise_equals_trained_weights(prob):
     sim = replay(trace, cfg, grad_fn=prob.grad_fn, init_params=prob.init,
                  batch_fn=prob.batch_fn_for(cfg.minibatch),
                  serve_batches=prob.stage_requests(sv, cfg.serving),
-                 serve_eval_fn=lambda p, b: p["w1"][0, 0])
+                 serve_eval_fn=lambda p, b: p[leaf][at])
     got = sim.serving.request_metric
 
     bare = cfg.replace(serving=None)
-    by_version = {0: float(np.asarray(prob.init["w1"])[0, 0])}
+    by_version = {0: float(np.asarray(prob.init[leaf])[at])}
     for i in np.flatnonzero(sv.served):
         v = int(sv.version[i])
         if v not in by_version:
@@ -240,13 +244,12 @@ def test_published_row_bitwise_equals_trained_weights(prob):
             psim = replay(prefix, bare, grad_fn=prob.grad_fn,
                           init_params=prob.init,
                           batch_fn=prob.batch_fn_for(cfg.minibatch))
-            by_version[v] = float(np.asarray(psim.params["w1"])[0, 0])
+            by_version[v] = float(np.asarray(psim.params[leaf])[at])
         assert got[i] == np.float32(by_version[v]), (i, v)
 
 
-@pytest.mark.parametrize("impl", ["stock", "fused"])
-def test_serving_leaves_training_bitwise_unchanged(impl, prob):
-    cfg = _run(ring_impl=impl)
+def test_serving_leaves_training_bitwise_unchanged(prob):
+    cfg = _run()
     sim = _replay(schedule(cfg, 24), cfg, prob)
     bare = cfg.replace(serving=None)
     sim0 = _replay(schedule(bare, 24), bare, prob)

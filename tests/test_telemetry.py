@@ -19,19 +19,23 @@ from repro.kernels import replay_ring
 D = 600
 
 
-def _cfg(impl, ring_dtype="bf16"):
+def _cfg(ring_dtype="bf16"):
     # 1-softsync over 6 learners: c = 6 pulled slots per event
     return RunConfig(protocol="softsync", n_softsync=1, n_learners=6,
                      minibatch=1, base_lr=0.02, optimizer="momentum",
-                     ring_dtype=ring_dtype, seed=5, ring_impl=impl)
+                     ring_dtype=ring_dtype, seed=5)
 
 
-def _whatif(cfg, trace, **kw):
+def _whatif(cfg, trace, body, **kw):
+    """The what-if replay with its event body held to ``body``."""
     i = jnp.arange(D, dtype=jnp.float32)
     a, wstar = 0.5 + (i % 100.0) / 100.0, jnp.sin(0.01 * i)
-    return replay(trace, cfg, init_params={"w": jnp.zeros((D,), jnp.float32)},
-                  flat_grad=("quadratic", lambda pos: (a[pos], wstar[pos])),
-                  **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine, "_event_body", lambda spec: body)
+        return replay(trace, cfg,
+                      init_params={"w": jnp.zeros((D,), jnp.float32)},
+                      flat_grad=("quadratic",
+                                 lambda pos: (a[pos], wstar[pos])), **kw)
 
 
 def _delta(before, name):
@@ -59,12 +63,12 @@ def test_count_mark_and_span():
 @pytest.mark.parametrize("impl,every", [("pallas", 8), ("pallas", 0),
                                         ("fused", 8)])
 def test_pull_counters_match_an_independent_count(impl, every):
-    cfg = _cfg(impl)
+    cfg = _cfg()
     trace = schedule(cfg, 40)
     K = trace.max_staleness + 1
     assert trace.c == 6 and K >= 2
     before = telemetry.counters()
-    _whatif(cfg, trace, eval_fn=(lambda p: {}) if every else None,
+    _whatif(cfg, trace, impl, eval_fn=(lambda p: {}) if every else None,
             eval_every=every)
     rows = sum(len({int(t) % K for t in trace.pulled_ts[j]})
                for j in range(trace.steps))
@@ -77,7 +81,7 @@ def test_pull_counters_match_an_independent_count(impl, every):
 def test_staged_replay_counts_no_pulls():
     """Only the what-if events count: the staged-gradient scan pulls its
     rows through its own gather, which the counters do not describe."""
-    cfg = _cfg("stock", ring_dtype="fp32")
+    cfg = _cfg(ring_dtype="fp32")
     trace = schedule(cfg, 12)
     before = telemetry.counters()
     replay(trace, cfg, grad_fn=lambda p, b: {"w": p["w"] * 0.1},
@@ -102,11 +106,12 @@ def _host_spans(trace_dir):
 
 
 def test_segment_spans_in_a_profile(tmp_path):
-    cfg = _cfg("pallas")
+    cfg = _cfg()
     trace = schedule(cfg, 24)
-    _whatif(cfg, trace, eval_every=8, eval_fn=lambda p: {})   # compile
+    _whatif(cfg, trace, "pallas", eval_every=8,
+            eval_fn=lambda p: {})                               # compile
     with jax.profiler.trace(str(tmp_path)):
-        res = _whatif(cfg, trace, eval_every=8,
+        res = _whatif(cfg, trace, "pallas", eval_every=8,
                       eval_fn=lambda p: {"w0": float(p["w"][0])})
     assert len(res.history) == 3
     spans = _host_spans(str(tmp_path))

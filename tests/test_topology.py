@@ -185,7 +185,7 @@ def test_shard_partitioned_apply_equals_unsharded(optimizer, mode):
                                           np.asarray(s_full))
 
 
-def test_apply_event_sharded_matches_flat():
+def test_flat_event_on_shard_slices_matches_flat():
     rng = np.random.default_rng(5)
     D, c, S = 10, 2, 4
     spec = optim.UpdateSpec(optimizer="momentum")
@@ -195,14 +195,17 @@ def test_apply_event_sharded_matches_flat():
     g = jnp.asarray(rng.normal(size=(c, D)), jnp.float32)
     coef = jnp.full((c,), 0.5, jnp.float32)
     lrs = jnp.asarray([0.1, 0.3], jnp.float32)
-    ws, ss = optim.apply_event_sharded(
-        spec, flatten.shard_pack(w, S, dp), flatten.shard_pack(s, S, dp),
-        flatten.shard_pack_grads(g, S, dp), coef, lrs, "combine")
+    # the flat event on each shard's (Dp,) slice
+    w_rows, s_rows = flatten.shard_pack(w, S, dp), flatten.shard_pack(s, S, dp)
+    g_rows = flatten.shard_pack(g, S, dp)                    # (c, S, Dp)
+    ws, ss = (jnp.stack(v) for v in zip(*[
+        optim.apply_event_flat(spec, w_rows[j], s_rows[j], g_rows[:, j],
+                               coef, lrs, "combine") for j in range(S)]))
     w_full, s_full = optim.apply_event_flat(spec, w, s, g, coef, lrs,
                                             "combine")
-    np.testing.assert_allclose(np.asarray(flatten.shard_unpack(ws, D)),
+    np.testing.assert_allclose(np.asarray(ws.reshape(-1)[:D]),
                                np.asarray(w_full), atol=1e-7)
-    np.testing.assert_allclose(np.asarray(flatten.shard_unpack(ss, D)),
+    np.testing.assert_allclose(np.asarray(ss.reshape(-1)[:D]),
                                np.asarray(s_full), atol=1e-7)
     # padding rows stay identically zero through the event
     assert float(jnp.abs(ws.reshape(-1)[D:]).max()) == 0.0

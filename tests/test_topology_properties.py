@@ -63,8 +63,9 @@ def test_any_shard_boundary_partitions_apply_event(data, optimizer, mode):
 @settings(**SET)
 @given(st.data(), st.sampled_from(["sgd", "momentum", "adagrad"]))
 def test_equal_width_shard_pack_roundtrip_and_apply(data, optimizer):
-    """shard_pack/shard_unpack invert, and the vmapped sharded apply
-    reproduces the flat apply on the equal-width layout for any (D, S)."""
+    """shard_pack inverts by flattening its rows and dropping the pad, and
+    the flat event applied to each shard's slice reproduces the flat apply
+    on the equal-width layout for any (D, S)."""
     dim = data.draw(st.integers(1, 33))
     shards = data.draw(st.integers(1, 8))
     c = data.draw(st.integers(1, 3))
@@ -77,17 +78,17 @@ def test_equal_width_shard_pack_roundtrip_and_apply(data, optimizer):
     g = jnp.asarray(rng.normal(size=(c, dim)), jnp.float32)
     coef = jnp.full((c,), 1.0 / c, jnp.float32)
     lrs = jnp.asarray(rng.uniform(0.01, 0.5, size=c), jnp.float32)
-    np.testing.assert_array_equal(
-        np.asarray(flatten.shard_unpack(flatten.shard_pack(w, shards, dp),
-                                        dim)),
-        np.asarray(w))
-    ws, ss = optim.apply_event_sharded(
-        spec, flatten.shard_pack(w, shards, dp),
-        None if s is None else flatten.shard_pack(s, shards, dp),
-        flatten.shard_pack_grads(g, shards, dp), coef, lrs, "combine")
+    w_rows = flatten.shard_pack(w, shards, dp)
+    np.testing.assert_array_equal(np.asarray(w_rows.reshape(-1)[:dim]),
+                                  np.asarray(w))
+    s_rows = None if s is None else flatten.shard_pack(s, shards, dp)
+    g_rows = flatten.shard_pack(g, shards, dp)               # (c, S, Dp)
+    ws = jnp.stack([optim.apply_event_flat(
+        spec, w_rows[j], None if s_rows is None else s_rows[j],
+        g_rows[:, j], coef, lrs, "combine")[0] for j in range(shards)])
     w_full, _ = optim.apply_event_flat(spec, w, s, g, coef, lrs, "combine")
     np.testing.assert_allclose(
-        np.asarray(flatten.shard_unpack(ws, dim)), np.asarray(w_full),
+        np.asarray(ws.reshape(-1)[:dim]), np.asarray(w_full),
         atol=1e-6, rtol=1e-6)
 
 

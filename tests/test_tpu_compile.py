@@ -147,7 +147,7 @@ def test_whatif_scan_updates_ring_in_place(topo, one_chip, monkeypatch, K, c):
     # uncached: a scan traced for the chip stays out of the
     # cache the CPU tests use
     fn = engine._make_scan_fn.__wrapped__(
-        None, spec, "combine", c, K, layout, ring_impl="pallas",
+        None, spec, "combine", c, K, layout, "pallas",
         ring_dtype="bf16", whatif=True)
     f32 = functools.partial(_sds, dtype=jnp.float32, sharding=one_chip)
     i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
@@ -168,7 +168,7 @@ def test_whatif_scan_updates_ring_in_place(topo, one_chip, monkeypatch, K, c):
     keys = ("lrs", "prev", "slot", "ts")
     fn = engine._make_spmd_scan_fn.__wrapped__(
         None, spec, "combine", c, K, layout,
-        PlacementPlan(shards=S, learners=1, c=c), keys, ring_impl="pallas",
+        PlacementPlan(shards=S, learners=1, c=c), keys, "pallas",
         ring_dtype="bf16", whatif=True)
     ps, rep = NamedSharding(mesh, P("ps")), NamedSharding(mesh, P())
     carry = (_sds((S, K) + lanes, jnp.bfloat16, ps),
